@@ -169,8 +169,7 @@ def main():
     shutil.rmtree(os.path.join(args.model_dir, "ckpt"), ignore_errors=True)
     engine = LocalEngine(
         args.cluster_size,
-        env={"JAX_PLATFORMS": os.environ.get("TFOS_NODE_PLATFORM", "cpu"),
-             "PYTHONPATH": os.path.dirname(os.path.abspath(__file__)),
+        env={"PYTHONPATH": os.path.dirname(os.path.abspath(__file__)),
              "XLA_FLAGS": "--xla_force_host_platform_device_count=1"},
     )
     cluster = TFCluster.run(

@@ -92,7 +92,7 @@ def main():
 
         engine = SparkEngine(SparkContext.getOrCreate())
     except ImportError:
-        engine = LocalEngine(args.num_executors, env={"PYTHONPATH": ""})
+        engine = LocalEngine(args.num_executors)
     try:
         ds = engine.parallelize(jobs, min(len(jobs), args.num_executors * 2))
         results = ds.map_partitions(run_partition).collect()

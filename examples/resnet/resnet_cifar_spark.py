@@ -118,9 +118,9 @@ def main():
 
     engine = LocalEngine(
         args.cluster_size,
-        env={"JAX_PLATFORMS": os.environ.get("TFOS_NODE_PLATFORM", "cpu"),
-             "PYTHONPATH": "",
-             "XLA_FLAGS": "--xla_force_host_platform_device_count=1"},
+        # executors inherit the caller\'s platform; on the CPU each gets
+        # one host device
+        env={"XLA_FLAGS": "--xla_force_host_platform_device_count=1"},
     )
     cluster = TFCluster.run(
         engine, main_fun,

@@ -51,11 +51,17 @@ def main_fun(args, ctx):
     mesh = make_mesh({"data": -1})
     image = args["image_size"]
 
-    params, state = resnet.init(
-        jax.random.PRNGKey(0), depth=50, num_classes=args["num_classes"]
-    )
     opt = optax.sgd(args["lr"], momentum=0.9)
-    opt_state = opt.init(params)
+
+    # one jitted init program: eager init is hundreds of tiny dispatches,
+    # each compiled on its own
+    @jax.jit
+    def init_all(key):
+        params, state = resnet.init(key, depth=50,
+                                    num_classes=args["num_classes"])
+        return params, state, opt.init(params)
+
+    params, state, opt_state = init_all(jax.random.PRNGKey(0))
 
     ckpt_dir = os.path.join(args["model_dir"], "ckpt")
     restored, step = ckpt.restore_latest(ckpt_dir)
@@ -229,9 +235,9 @@ def main():
 
         engine = LocalEngine(
             args.cluster_size,
-            env={"JAX_PLATFORMS": os.environ.get("TFOS_NODE_PLATFORM", "cpu"),
-                 "PYTHONPATH": "",
-                 "XLA_FLAGS": "--xla_force_host_platform_device_count=1"},
+            # executors inherit the caller\'s platform; on the CPU each gets
+            # one host device
+            env={"XLA_FLAGS": "--xla_force_host_platform_device_count=1"},
         )
 
     cluster = TFCluster.run(

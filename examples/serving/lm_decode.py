@@ -19,7 +19,11 @@ then demonstrates
   TTFT p50/p99, per-token-gap p50/p99 and tokens/s — the same harness
   the ``TFOS_BENCH_DECODE`` lane runs.
 
-    JAX_PLATFORMS=cpu python examples/serving/lm_decode.py
+    python examples/serving/lm_decode.py --num_replicas 1
+
+Replicas inherit the caller's platform, and a chip belongs to one
+process: run one replica per chip (``JAX_PLATFORMS=cpu`` runs any number
+of them on the host instead).
 
 Add ``--http`` to also expose the HTTP frontend and issue one
 ``POST /v1/generate``.

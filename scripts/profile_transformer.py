@@ -66,6 +66,9 @@ def main():
     elif remat != "dots":
         raise SystemExit(f"bad --remat {remat!r}")
 
+    from tensorflowonspark_tpu.utils import compile_cache
+
+    compile_cache.export_env()  # before jax reads it at import
     import jax
     import jax.numpy as jnp
     import optax

@@ -125,8 +125,6 @@ def run_f784(mode, batch, width, steps):
               else _f784_feeder_main)
     fed = bench._fed_setup(batch, 0, steps, tag=f"-{mode}", target=target,
                            extra=(width,), rec_bytes=width * 4)
-    if fed is None:
-        return {"mode": mode, "error": "shm unavailable"}
     feed = DataFeed(fed["mgr"], train_mode=True,
                     input_mapping={"image": "image", "label": "label"})
     n_batches = 0
@@ -354,8 +352,6 @@ def run_mode(mode, batch, image, steps):
 
     fed = bench._fed_setup(batch, image, steps,
                            columnar=(mode == "columnar"), tag=f"-{mode}")
-    if fed is None:
-        return {"mode": mode, "error": "shm unavailable"}
     feed = DataFeed(fed["mgr"], train_mode=True,
                     input_mapping={"image": "image", "label": "label"})
     n_batches = 0

@@ -672,18 +672,44 @@ def prefill_extend(params, tokens, cfg: Config, pool_k, pool_v,
     return logits, k.transpose(1, 0, 2, 3, 4), v.transpose(1, 0, 2, 3, 4)
 
 
+@functools.lru_cache(maxsize=8)
+def _jitted_apply(cfg, attn_fn):
+    """One jit of ``apply`` per (config, attention) for the padded
+    oracle: a fresh wrapper per call would compile again each time."""
+    return jax.jit(functools.partial(apply, cfg=cfg, attn_fn=attn_fn))
+
+
 def greedy_decode_reference(params, prompt, cfg: Config, *, max_tokens,
-                            eos_id=None, attn_fn=None):
+                            eos_id=None, attn_fn=None, pad_to=None):
     """Full-recompute greedy decode — the KV-cache parity oracle
     (tests/test_decode.py): each step re-runs ``apply`` on the whole
-    growing sequence and argmaxes the final position.  O(T²) per token;
-    test-sized models only."""
+    growing sequence and argmaxes the final position.  O(T²) per token.
+
+    Every new length is a new shape, hence a new compile of the whole
+    forward: fine for test-sized models, minutes at real widths.
+    ``pad_to`` right-pads each sequence to that one length and runs the
+    forward under one jit instead — under a causal mask the logits at
+    the last REAL position do not see the padding, so the tokens are
+    the same and the oracle compiles once."""
     toks = [int(t) for t in prompt]
+    if pad_to is None:
+        def last_logits(seq):
+            return apply(params, jnp.asarray([seq], jnp.int32), cfg,
+                         attn_fn=attn_fn)[0, -1]
+    else:
+        if len(toks) + int(max_tokens) - 1 > pad_to:
+            raise ValueError(
+                f"pad_to={pad_to} is shorter than the longest sequence "
+                f"({len(toks) + int(max_tokens) - 1})")
+        fwd = _jitted_apply(cfg, attn_fn)
+
+        def last_logits(seq):
+            padded = jnp.asarray([seq + [0] * (pad_to - len(seq))],
+                                 jnp.int32)
+            return fwd(params, padded)[0, len(seq) - 1]
     out = []
     for _ in range(int(max_tokens)):
-        logits = apply(params, jnp.asarray([toks], jnp.int32), cfg,
-                       attn_fn=attn_fn)
-        nxt = int(jnp.argmax(logits[0, -1]))
+        nxt = int(jnp.argmax(last_logits(toks)))
         out.append(nxt)
         toks.append(nxt)
         if eos_id is not None and nxt == int(eos_id):

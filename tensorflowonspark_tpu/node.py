@@ -251,6 +251,7 @@ class TFNodeContext:
         if env["process_id"] is None:  # ps/evaluator: no accelerator claim
             return env
         if env["num_processes"] > 1:
+            self._export_tpu_process_group()
             import jax
 
             plat = (os.environ.get("JAX_PLATFORMS")
@@ -261,12 +262,8 @@ class TFNodeContext:
                 # computation fails with "Multiprocess computations
                 # aren't implemented on the CPU backend".  Must be set
                 # before the backend initializes.
-                try:
-                    jax.config.update(
-                        "jax_cpu_collectives_implementation", "gloo")
-                except Exception:  # noqa: BLE001 - option may move/vanish
-                    logger.warning("could not enable gloo cpu collectives",
-                                   exc_info=True)
+                jax.config.update(
+                    "jax_cpu_collectives_implementation", "gloo")
             jax.distributed.initialize(
                 coordinator_address=env["coordinator_address"],
                 num_processes=env["num_processes"],
@@ -288,8 +285,8 @@ class TFNodeContext:
             #   lenient (default) — definite findings (wrong device
             #     counts, CPU fallback, smoke failure) are fatal; a probe
             #     that merely TIMED OUT with nothing else found is
-            #     warn-only, because first TPU contact through a slow
-            #     pool/tunnel can exceed any fixed window (widen via
+            #     warn-only, because the first contact with a large
+            #     slice can exceed any fixed window (widen via
             #     TFOS_SLICE_HEALTH_TIMEOUT).
             #   strict — everything fatal, including probe timeouts:
             #     fail-fast for deployments that prefer a bring-up error
@@ -314,6 +311,32 @@ class TFNodeContext:
                 health["local_devices"], health["global_devices"],
                 health["platform"])
         return env
+
+    def _export_tpu_process_group(self):
+        """Processes of this job that share a host and each claimed
+        chips (``cluster.run(num_chips=N)``) must be described to the
+        TPU runtime as ONE job before it starts: each claim alone is a
+        complete one-process job, and ``jax.distributed`` cannot join
+        those afterwards.  The peers' order is the order of their chip
+        blocks (``_same_host_index``)."""
+        if not os.environ.get("TPU_VISIBLE_CHIPS"):
+            return  # natural visibility: one process owns the host
+        me = next(m for m in self.cluster_info
+                  if m["executor_id"] == self.executor_id)
+        compute = [m for m in self.cluster_info
+                   if m["job_name"] in COMPUTE_JOBS]
+        peers = sorted(m["executor_id"] for m in compute
+                       if m["host"] == me["host"])
+        if len(peers) < 2:
+            return
+        if len(peers) != len(compute):
+            raise RuntimeError(
+                f"{len(peers)} of this job's {len(compute)} processes "
+                f"share host {me['host']} and each claimed chips: one "
+                "TPU job across several hosts needs one process per host "
+                "(leave num_chips unset)")
+        tpu_info.export_process_group(
+            peers.index(self.executor_id), len(peers))
 
     def sync_exit_barrier(self):
         """Cross-process barrier run by the node wrapper after user code

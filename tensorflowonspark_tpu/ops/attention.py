@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tensorflowonspark_tpu.ops._pallas import resolve_interpret
+
 _NEG_INF = -1e30
 
 
@@ -469,7 +471,8 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=512,
     """Flash attention on [B, S, H, D]; differentiable.
 
     ``interpret=None`` auto-selects: compiled pallas on TPU, interpreter
-    mode elsewhere (CPU tests / virtual-device meshes).
+    mode on a CPU backend that was asked for (tests / virtual-device
+    meshes), an error anywhere else (``ops._pallas.resolve_interpret``).
 
     ``bwd_impl``: "xla" (default — blockwise scan, computes-then-masks
     the causal triangle) or "pallas" (dq/dkv kernels whose block loops
@@ -484,8 +487,7 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=512,
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if bwd_impl not in ("xla", "pallas"):
         raise ValueError(f"bwd_impl must be 'xla' or 'pallas', "
                          f"got {bwd_impl!r}")

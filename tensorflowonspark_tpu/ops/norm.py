@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tensorflowonspark_tpu.ops._pallas import resolve_interpret
+
 
 def rmsnorm_reference(x, scale, eps=1e-6):
     xf = x.astype(jnp.float32)
@@ -72,6 +74,4 @@ _rmsnorm.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
 
 def fused_rmsnorm(x, scale, eps=1e-6, block_rows=256, interpret=None):
     """RMSNorm over the last axis; any leading shape; differentiable."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _rmsnorm(x, scale, eps, block_rows, interpret)
+    return _rmsnorm(x, scale, eps, block_rows, resolve_interpret(interpret))

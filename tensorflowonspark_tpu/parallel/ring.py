@@ -32,23 +32,13 @@ import numpy as _onp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax>=0.8
-    from jax import shard_map as _shard_map_raw
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
-    _CHECK_KW = "check_rep"
-
-
 def shard_map(f, mesh, in_specs, out_specs):
-    """Version-stable shard_map with replication checking off (the ring
+    """``jax.shard_map`` with the varying-manual-axes check off (the ring
     primitives produce unreplicated outputs from psum-free math, which
     the checker cannot prove)."""
-    return _shard_map_raw(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
 
 

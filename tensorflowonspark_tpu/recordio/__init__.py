@@ -47,7 +47,7 @@ class TFRecordWriter:
                     raise IOError(f"cannot open {lp} for writing")
             else:
                 self._f = open(lp, "wb")
-        elif self._lib is not None and getattr(self._lib, "_tfos_mem_api", False):
+        elif self._lib is not None:
             self._mh = self._lib.tfr_mem_writer_new()
             self._remote_path = str(path)
         else:
@@ -123,7 +123,7 @@ class TFRecordReader:
 
     def _iter_remote(self):
         data = _fs.read_bytes(self._path)
-        if self._lib is not None and getattr(self._lib, "_tfos_mem_api", False):
+        if self._lib is not None:
             h = self._lib.tfr_mem_reader_new(data, len(data))
             try:
                 buf = ctypes.POINTER(ctypes.c_uint8)()
@@ -232,7 +232,7 @@ def load_columnar(path):
             "dfutil.load_tfrecords_columnar / iter_tfrecords_columnar "
             "for a shard dir)")
     lib = _native.load()
-    if lib is None or not getattr(lib, "_tfos_colb_api", False):
+    if lib is None:
         return _columnar_fallback(path)
     if _fs.is_local(path):
         h = lib.tfr_load_columnar(str(_fs.local_path(path)).encode())

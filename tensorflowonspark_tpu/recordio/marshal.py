@@ -52,9 +52,10 @@ def _load_ext():
     _ext_tried = True
     if os.environ.get("TFOS_NATIVE_MARSHAL", "1") == "0":
         return None
-    here = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    path = os.path.join(here, "native", "_tfos_marshal.so")
-    if not os.path.exists(path):
+    from tensorflowonspark_tpu.recordio import native
+
+    path = os.path.join(native._SRC, "_tfos_marshal.so")
+    if not native.build() or not os.path.exists(path):
         return None
     try:
         loader = importlib.machinery.ExtensionFileLoader("_tfos_marshal", path)

@@ -1,8 +1,12 @@
 """ctypes bindings for the native record-IO / shm-queue library.
 
-Loads ``libtfos_native.so`` (built from /native via ``make``); call sites
-fall back to the pure-Python implementation (pyimpl.py) when the library
-is unavailable — behavior is identical, speed is not.
+``libtfos_native.so`` is built from the checkout's ``native/`` sources by
+``make`` on first use — again whenever a source is newer than it — and
+loaded from there, nowhere else: what runs is what the sources say.  When there is no build (no sources beside
+the package, no toolchain, a compile error) :func:`load` returns None
+and call sites fall back to the pure-Python implementation (pyimpl.py) —
+behavior is identical, speed is not; the build's own error is logged.
+A run that needs the native path (the fed ring) checks for it and fails.
 """
 
 from __future__ import annotations
@@ -16,56 +20,75 @@ logger = logging.getLogger(__name__)
 
 _LIB = None
 _TRIED = False
+_BUILT = None
+
+_SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "native")
 
 
-def _candidates():
-    here = os.path.dirname(os.path.abspath(__file__))
-    repo = os.path.dirname(os.path.dirname(here))
-    env = os.environ.get("TFOS_NATIVE_LIB")
-    if env:
-        yield env
-    yield os.path.join(here, "libtfos_native.so")
-    yield os.path.join(repo, "native", "libtfos_native.so")
+def _stale():
+    """make's own rule, checked without starting make (every process
+    that loads the library would otherwise pay for a no-op run of it):
+    a library is missing, or older than a source."""
+    import glob
+
+    libs = [os.path.join(_SRC, n)
+            for n in ("libtfos_native.so", "_tfos_marshal.so")]
+    if not all(os.path.exists(p) for p in libs):
+        return True
+    srcs = glob.glob(os.path.join(_SRC, "*.c*")) + [
+        os.path.join(_SRC, "Makefile")]
+    return min(map(os.path.getmtime, libs)) < max(map(os.path.getmtime, srcs))
+
+
+def build():
+    """Bring ``native/``'s libraries up to date with its sources (once
+    per process); False when that is not possible here.  A library left
+    over from other sources never outlives them."""
+    global _BUILT
+    if _BUILT is not None:
+        return _BUILT
+    _BUILT = False
+    makefile = os.path.join(_SRC, "Makefile")
+    if not os.path.exists(makefile):
+        logger.warning("no native sources at %s", _SRC)
+        return False
+    try:
+        import fcntl
+
+        # an exclusive flock keeps N concurrently-starting executor
+        # processes from interleaving builds; the losers of the race
+        # find everything up to date
+        with open(makefile) as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            if _stale():
+                subprocess.run(["make", "-C", _SRC], check=True,
+                               capture_output=True, text=True)
+        _BUILT = True
+    except subprocess.CalledProcessError as e:
+        logger.warning("native build failed:\n%s", e.stderr[-2000:])
+    except OSError as e:  # no make, unwritable checkout
+        logger.warning("native build impossible here: %s", e)
+    return _BUILT
 
 
 def load():
-    """Load (and lazily build) the native library; None if unavailable."""
+    """Build and load the native library; None if unavailable (call
+    sites then use the pure-python IO)."""
     global _LIB, _TRIED
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
-    for path in _candidates():
-        if os.path.exists(path):
-            try:
-                _LIB = _bind(ctypes.CDLL(path))
-                logger.info("loaded native record-io: %s", path)
-                return _LIB
-            except OSError as e:  # half-written or foreign .so
-                logger.warning("cannot load %s: %s", path, e)
-    # try building once from the in-repo sources; an exclusive flock keeps
-    # N concurrently-starting executor processes from interleaving builds,
-    # and losers of the race load the winner's output
-    here = os.path.dirname(os.path.abspath(__file__))
-    src = os.path.join(os.path.dirname(os.path.dirname(here)), "native")
-    if os.path.exists(os.path.join(src, "Makefile")):
+    if build():
         try:
-            import fcntl
-            import tempfile
-
-            lock = open(os.path.join(tempfile.gettempdir(), ".tfos-native-build.lock"), "w")
-            with lock:
-                fcntl.flock(lock, fcntl.LOCK_EX)
-                path = os.path.join(src, "libtfos_native.so")
-                if not os.path.exists(path):
-                    subprocess.run(["make", "-C", src], check=True,
-                                   capture_output=True)
-                if os.path.exists(path):
-                    _LIB = _bind(ctypes.CDLL(path))
-                    logger.info("built+loaded native record-io: %s", path)
-                    return _LIB
-        except Exception as e:  # noqa: BLE001 - fall back to pure python
-            logger.warning("native build failed (%s); using pure-python IO", e)
-    return None
+            _LIB = _bind(ctypes.CDLL(
+                os.path.join(_SRC, "libtfos_native.so")))
+            logger.info("loaded native record-io from %s", _SRC)
+        except OSError as e:
+            logger.warning("cannot load the native library: %s", e)
+    if _LIB is None:
+        logger.warning("using pure-python record IO")
+    return _LIB
 
 
 def _bind(lib):
@@ -125,19 +148,13 @@ def _bind(lib):
     lib.shq_push.argtypes = [c.c_void_p, c.c_char_p, c.c_uint64, c.c_int]
     lib.shq_pop.restype = c.c_int64
     lib.shq_pop.argtypes = [c.c_void_p, c.c_int]
-    try:
-        lib.shq_push_iov.restype = c.c_int
-        lib.shq_push_iov.argtypes = [c.c_void_p, c.POINTER(c.c_void_p),
-                                     c.POINTER(c.c_uint64), c.c_int, c.c_int]
-        lib.shq_peek_len.restype = c.c_int64
-        lib.shq_peek_len.argtypes = [c.c_void_p, c.c_int]
-        lib.shq_pop_into.restype = c.c_int64
-        lib.shq_pop_into.argtypes = [c.c_void_p, c.c_void_p]
-        lib.tfos_has_iov = True
-    except AttributeError:
-        # pre-round-4 .so without the scatter-gather entry points: the
-        # queue layer checks tfos_has_iov and stays on the classic path
-        lib.tfos_has_iov = False
+    lib.shq_push_iov.restype = c.c_int
+    lib.shq_push_iov.argtypes = [c.c_void_p, c.POINTER(c.c_void_p),
+                                 c.POINTER(c.c_uint64), c.c_int, c.c_int]
+    lib.shq_peek_len.restype = c.c_int64
+    lib.shq_peek_len.argtypes = [c.c_void_p, c.c_int]
+    lib.shq_pop_into.restype = c.c_int64
+    lib.shq_pop_into.argtypes = [c.c_void_p, c.c_void_p]
     lib.shq_buffer.restype = u8p
     lib.shq_buffer.argtypes = [c.c_void_p]
     lib.shq_close_write.argtypes = [c.c_void_p]
@@ -148,60 +165,47 @@ def _bind(lib):
     lib.tfr_crc32c.restype = c.c_uint32
     lib.tfr_crc32c.argtypes = [c.c_char_p, c.c_uint64]
 
-    # columnar bulk loader (round 3+; callers check lib._tfos_colb_api)
-    try:
-        lib.tfr_load_columnar.restype = c.c_void_p
-        lib.tfr_load_columnar.argtypes = [c.c_char_p]
-        lib.tfr_load_columnar_mem.restype = c.c_void_p
-        lib.tfr_load_columnar_mem.argtypes = [c.c_char_p, c.c_uint64]
-        lib.colb_ok.restype = c.c_int
-        lib.colb_ok.argtypes = [c.c_void_p]
-        lib.colb_error.restype = c.c_char_p
-        lib.colb_error.argtypes = [c.c_void_p]
-        lib.colb_num_rows.restype = c.c_int64
-        lib.colb_num_rows.argtypes = [c.c_void_p]
-        lib.colb_num_features.restype = c.c_int
-        lib.colb_num_features.argtypes = [c.c_void_p]
-        lib.colb_name.restype = c.c_char_p
-        lib.colb_name.argtypes = [c.c_void_p, c.c_int]
-        lib.colb_kind.restype = c.c_int
-        lib.colb_kind.argtypes = [c.c_void_p, c.c_int]
-        lib.colb_width.restype = c.c_int64
-        lib.colb_width.argtypes = [c.c_void_p, c.c_int]
-        lib.colb_floats.restype = c.POINTER(c.c_float)
-        lib.colb_floats.argtypes = [c.c_void_p, c.c_int]
-        lib.colb_int64s.restype = c.POINTER(c.c_int64)
-        lib.colb_int64s.argtypes = [c.c_void_p, c.c_int]
-        lib.colb_bytes_blob.restype = u8p
-        lib.colb_bytes_blob.argtypes = [c.c_void_p, c.c_int]
-        lib.colb_bytes_offsets.restype = c.POINTER(c.c_uint64)
-        lib.colb_bytes_offsets.argtypes = [c.c_void_p, c.c_int]
-        lib.colb_free.argtypes = [c.c_void_p]
-        lib._tfos_colb_api = True
-    except AttributeError:
-        logger.warning("native lib lacks the columnar API (stale build); "
-                       "bulk TFRecord loads will decode per row")
-        lib._tfos_colb_api = False
+    # columnar bulk loader
+    lib.tfr_load_columnar.restype = c.c_void_p
+    lib.tfr_load_columnar.argtypes = [c.c_char_p]
+    lib.tfr_load_columnar_mem.restype = c.c_void_p
+    lib.tfr_load_columnar_mem.argtypes = [c.c_char_p, c.c_uint64]
+    lib.colb_ok.restype = c.c_int
+    lib.colb_ok.argtypes = [c.c_void_p]
+    lib.colb_error.restype = c.c_char_p
+    lib.colb_error.argtypes = [c.c_void_p]
+    lib.colb_num_rows.restype = c.c_int64
+    lib.colb_num_rows.argtypes = [c.c_void_p]
+    lib.colb_num_features.restype = c.c_int
+    lib.colb_num_features.argtypes = [c.c_void_p]
+    lib.colb_name.restype = c.c_char_p
+    lib.colb_name.argtypes = [c.c_void_p, c.c_int]
+    lib.colb_kind.restype = c.c_int
+    lib.colb_kind.argtypes = [c.c_void_p, c.c_int]
+    lib.colb_width.restype = c.c_int64
+    lib.colb_width.argtypes = [c.c_void_p, c.c_int]
+    lib.colb_floats.restype = c.POINTER(c.c_float)
+    lib.colb_floats.argtypes = [c.c_void_p, c.c_int]
+    lib.colb_int64s.restype = c.POINTER(c.c_int64)
+    lib.colb_int64s.argtypes = [c.c_void_p, c.c_int]
+    lib.colb_bytes_blob.restype = u8p
+    lib.colb_bytes_blob.argtypes = [c.c_void_p, c.c_int]
+    lib.colb_bytes_offsets.restype = c.POINTER(c.c_uint64)
+    lib.colb_bytes_offsets.argtypes = [c.c_void_p, c.c_int]
+    lib.colb_free.argtypes = [c.c_void_p]
 
     # memory-buffer framing (remote-FS path: fsspec moves the bytes,
-    # the C library still does framing + crc); absent in pre-round-3 .so
-    # builds — callers check lib._tfos_mem_api and fall back to pyimpl
-    try:
-        lib.tfr_mem_writer_new.restype = c.c_void_p
-        lib.tfr_mem_writer_write.restype = c.c_int
-        lib.tfr_mem_writer_write.argtypes = [c.c_void_p, c.c_char_p, c.c_uint64]
-        lib.tfr_mem_writer_data.restype = u8p
-        lib.tfr_mem_writer_data.argtypes = [c.c_void_p, c.POINTER(c.c_uint64)]
-        lib.tfr_mem_writer_clear.argtypes = [c.c_void_p]
-        lib.tfr_mem_writer_free.argtypes = [c.c_void_p]
-        lib.tfr_mem_reader_new.restype = c.c_void_p
-        lib.tfr_mem_reader_new.argtypes = [c.c_char_p, c.c_uint64]
-        lib.tfr_mem_reader_next.restype = c.c_int64
-        lib.tfr_mem_reader_next.argtypes = [c.c_void_p, c.POINTER(u8p)]
-        lib.tfr_mem_reader_free.argtypes = [c.c_void_p]
-        lib._tfos_mem_api = True
-    except AttributeError:
-        logger.warning("native lib lacks the mem-buffer API (stale build); "
-                       "remote-FS record IO will use the python codec")
-        lib._tfos_mem_api = False
+    # the C library still does framing + crc)
+    lib.tfr_mem_writer_new.restype = c.c_void_p
+    lib.tfr_mem_writer_write.restype = c.c_int
+    lib.tfr_mem_writer_write.argtypes = [c.c_void_p, c.c_char_p, c.c_uint64]
+    lib.tfr_mem_writer_data.restype = u8p
+    lib.tfr_mem_writer_data.argtypes = [c.c_void_p, c.POINTER(c.c_uint64)]
+    lib.tfr_mem_writer_clear.argtypes = [c.c_void_p]
+    lib.tfr_mem_writer_free.argtypes = [c.c_void_p]
+    lib.tfr_mem_reader_new.restype = c.c_void_p
+    lib.tfr_mem_reader_new.argtypes = [c.c_char_p, c.c_uint64]
+    lib.tfr_mem_reader_next.restype = c.c_int64
+    lib.tfr_mem_reader_next.argtypes = [c.c_void_p, c.POINTER(u8p)]
+    lib.tfr_mem_reader_free.argtypes = [c.c_void_p]
     return lib

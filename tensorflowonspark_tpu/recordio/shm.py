@@ -162,44 +162,35 @@ class ShmQueue:
         """Pop one object.  Fast-path messages are popped directly into a
         caller-owned buffer (one copy) and the columns come back as numpy
         VIEWS over it — no pickle, no further copies."""
-        if getattr(self._lib, "tfos_has_iov", False):
-            import numpy as np
+        import numpy as np
 
-            n = self._lib.shq_peek_len(self._h, timeout_ms)
-            if n == -1:
-                raise TimeoutError(f"shm queue {self.name} empty")
-            if n == -2:
-                return None  # closed and drained
-            # np.empty, NOT bytearray: bytearray(n) zero-fills, which is
-            # a full hidden extra write of the payload size per message
-            buf = np.empty(n, np.uint8)
-            if n:
-                got = self._lib.shq_pop_into(
-                    self._h, ctypes.c_void_p(buf.ctypes.data))
-            else:
-                got = self._lib.shq_pop_into(self._h, None)
-            if got != n:  # single-consumer contract violated
-                raise RuntimeError(
-                    f"shm queue {self.name}: peeked {n} bytes but popped "
-                    f"{got} (concurrent consumer?)")
-            if n >= 4 and bytes(buf[:4]) == _COLMAGIC:
-                return _decode_columnar(buf)
-            # loads() takes any bytes-like: no tobytes() copy of the
-            # whole payload just to unpickle a legacy message
-            return pickle.loads(memoryview(buf) if n else b"")
-        data = self.get_bytes(timeout_ms)
-        if data is None:
-            return None
-        if data[:4] == _COLMAGIC:
-            return _decode_columnar(bytearray(data))
-        return pickle.loads(data)
+        n = self._lib.shq_peek_len(self._h, timeout_ms)
+        if n == -1:
+            raise TimeoutError(f"shm queue {self.name} empty")
+        if n == -2:
+            return None  # closed and drained
+        # np.empty, NOT bytearray: bytearray(n) zero-fills, which is
+        # a full hidden extra write of the payload size per message
+        buf = np.empty(n, np.uint8)
+        if n:
+            got = self._lib.shq_pop_into(
+                self._h, ctypes.c_void_p(buf.ctypes.data))
+        else:
+            got = self._lib.shq_pop_into(self._h, None)
+        if got != n:  # single-consumer contract violated
+            raise RuntimeError(
+                f"shm queue {self.name}: peeked {n} bytes but popped "
+                f"{got} (concurrent consumer?)")
+        if n >= 4 and bytes(buf[:4]) == _COLMAGIC:
+            return _decode_columnar(buf)
+        # loads() takes any bytes-like: no tobytes() copy of the
+        # whole payload just to unpickle a row-list message
+        return pickle.loads(memoryview(buf) if n else b"")
 
     def _put_columnar(self, obj, timeout_ms):
         """Scatter-gather push of a ColumnChunk; False when not eligible
-        (no iov support, non-chunk payload, object/non-contiguous
-        columns) so put() falls back to pickle."""
-        if not getattr(self._lib, "tfos_has_iov", False):
-            return False
+        (non-chunk payload, object/non-contiguous columns) so put()
+        falls back to pickle."""
         from tensorflowonspark_tpu import marker as _marker
 
         if not isinstance(obj, _marker.ColumnChunk):

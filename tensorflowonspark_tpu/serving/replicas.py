@@ -177,8 +177,11 @@ class _Predictor:
         except Exception as e:  # noqa: BLE001 - non-jax-pure predict
             if self._jit is True:
                 raise
-            logger.info("predict not AOT-compilable (%s); serving eagerly",
-                        e)
+            # jit=None only: the caller left the choice to us, and an
+            # eager predict is slower by orders of magnitude — say so
+            # where an operator looks (jit=True makes this fatal)
+            logger.warning("predict not AOT-compilable (%s); serving "
+                           "EAGERLY", e)
             return None
 
     def __call__(self, inputs):

@@ -8,7 +8,7 @@ internally when handed a metrics object), read a structured summary with
 ``report()``.
 
 MFU convention: model FLOPs per step / (step time x peak FLOPs), peak
-resolved from the device kind like bench.py.  FLOPs estimators for the
+resolved from the device kind (PEAK_FLOPS below, the one table).  FLOPs estimators for the
 zoo's families are provided (6ND for transformers, 2 x MACs for convs is
 the caller's number).
 """
@@ -17,14 +17,16 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 import time
 
 from tensorflowonspark_tpu.utils import faults, metrics_registry, telemetry
 
 logger = logging.getLogger(__name__)
 
-# bf16 peak FLOP/s per chip by device-kind substring (same table as bench.py)
+# THE table of bf16 peak FLOP/s per chip, by device-kind substring
+# (vendor documentation: v5e 197, v4 275, v5p 459, v6e 918 TFLOP/s).
+# bench.py and the scripts read it through peak_flops(); no second table,
+# no override.
 PEAK_FLOPS = {
     "v5 lite": 197e12,
     "v5e": 197e12,
@@ -35,18 +37,24 @@ PEAK_FLOPS = {
 
 
 def peak_flops(device=None):
-    env = os.environ.get("TFOS_PEAK_FLOPS")
-    if env:
-        return float(env)
+    """Peak of ``device`` (default: the first jax device) from the table.
+    A CPU has no entry and gives None — MFU is then not reported.  Any
+    other device the table does not know is an error, never a default:
+    a utilization against the wrong peak reads as a measurement."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
+    kind = device.device_kind.lower()
     for k, v in PEAK_FLOPS.items():
         if k in kind:
             return v
-    return None  # unknown (CPU): MFU not reported
+    if device.platform == "cpu":
+        return None
+    raise ValueError(
+        f"no peak FLOP/s known for device kind {device.device_kind!r} "
+        f"(platform {device.platform!r}); add it to "
+        "utils.metrics.PEAK_FLOPS with its source")
 
 
 def transformer_flops_per_token(cfg, causal=False):

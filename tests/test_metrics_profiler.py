@@ -2,6 +2,7 @@
 
 import os
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -11,17 +12,14 @@ from tensorflowonspark_tpu.utils import profiler
 
 
 def test_train_metrics_rates_and_mfu():
-    os.environ["TFOS_PEAK_FLOPS"] = "1e9"
-    try:
-        m = M.TrainMetrics(flops_per_item=1e6)
-        m.step()  # arm
-        for _ in range(3):
-            time.sleep(0.01)
-            m.infeed_wait(0.002)
-            m.step(items=10)
-        rep = m.report()
-    finally:
-        del os.environ["TFOS_PEAK_FLOPS"]
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    m = M.TrainMetrics(flops_per_item=1e6, device=v5e)
+    m.step()  # arm
+    for _ in range(3):
+        time.sleep(0.01)
+        m.infeed_wait(0.002)
+        m.step(items=10)
+    rep = m.report()
     assert rep["steps"] == 4 and rep["items"] == 30
     assert rep["step_time_avg_s"] > 0
     assert 0 < rep["infeed_stall_frac"] < 1

@@ -17,6 +17,7 @@ import os
 import re
 import threading
 import time
+import types
 import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -213,15 +214,12 @@ def test_train_metrics_bridge():
     from tensorflowonspark_tpu.utils.metrics import TrainMetrics
 
     _enable()
-    os.environ["TFOS_PEAK_FLOPS"] = "1e12"
-    try:
-        tm = TrainMetrics(flops_per_item=1e9, device=object())
-        tm.step()  # arms the timer
-        for _ in range(3):
-            tm.infeed_wait(0.001)
-            tm.step(items=32)
-    finally:
-        del os.environ["TFOS_PEAK_FLOPS"]
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    tm = TrainMetrics(flops_per_item=1e9, device=v5e)
+    tm.step()  # arms the timer
+    for _ in range(3):
+        tm.infeed_wait(0.001)
+        tm.step(items=32)
     snap = reg.snapshot()
     assert obs_http._metric_total(snap, "tfos_train_steps_total") == 3
     assert obs_http._metric_hist(snap, "tfos_train_step_ms")["count"] == 3

@@ -55,8 +55,10 @@ def test_ring_attention_grads_match(eight_devices):
         in_specs=(P(None, "seq"), P(None, "seq"), P(None, "seq")),
         out_specs=P(None, "seq"),
     )
-    g1 = jax.grad(lambda q, k, v: jnp.sum(ring(q, k, v) ** 2),
-                  argnums=(0, 1, 2))(q, k, v)
+    # under jit, as the trainers run it: the eager gradient of a
+    # shard_map'd ring dispatches op by op and takes twenty times as long
+    g1 = jax.jit(jax.grad(lambda q, k, v: jnp.sum(ring(q, k, v) ** 2),
+                          argnums=(0, 1, 2)))(q, k, v)
     g2 = jax.grad(
         lambda q, k, v: jnp.sum(
             ops.mha_reference(q, k, v, causal=True) ** 2
@@ -143,7 +145,7 @@ def test_zigzag_ring_grads_match(eight_devices):
     def loss_ref(q, k, v):
         return jnp.sum(ops.mha_reference(q, k, v, causal=True) ** 2)
 
-    g1 = jax.grad(loss_zz, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(loss_zz, argnums=(0, 1, 2)))(q, k, v)
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
@@ -179,7 +181,7 @@ def test_zigzag_end_to_end_lm_training_matches(eight_devices):
             p, t, cfg, attn_fn=zz_attn, labels=labels_p,
             positions=positions)
 
-    zz_l, zz_grads = jax.value_and_grad(zz_loss)(params, toks_p)
+    zz_l, zz_grads = jax.jit(jax.value_and_grad(zz_loss))(params, toks_p)
     np.testing.assert_allclose(float(zz_l), float(base_loss), rtol=1e-5)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5),
