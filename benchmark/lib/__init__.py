@@ -1,0 +1,1 @@
+"""The repo's benchmark: see benchmark/README.md and BENCHMARK.json."""

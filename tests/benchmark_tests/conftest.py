@@ -1,0 +1,10 @@
+"""The benchmark's tests (BENCHMARK.json ``paths``): collected by the
+tier-1 command with the rest of tests/."""
+
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
