@@ -873,7 +873,7 @@ def _serve_bench(dev, on_tpu):
     Replicas are pinned to the CPU regardless of the bench device: a
     chip belongs to one process, and the bench parent holds it.  The
     result says ``"platform": "cpu"`` until the lane runs in a
-    chip-owning process of its own (ROADMAP Speed 0(b)).
+    chip-owning process of its own (ROADMAP Design 1).
     """
     import shutil
     import tempfile

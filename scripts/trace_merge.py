@@ -20,12 +20,21 @@ run dirs; scanned recursively).  Output:
       prefill / decode / other milliseconds (the span tree is minted by
       ``utils/telemetry.py`` "Causal tracing").
 
+  (d) with ``--xplane <dir>`` (a ``utils/profiler`` capture directory):
+      the capture's host spans (``tfos/*``, ``bench/*``) and device
+      operations join the Chrome trace as rows of their own, so a
+      feeder's spool (another process, wall-clock ``ts``) and the
+      trainer's capture land on ONE timeline.  The capture's timestamps
+      count from its start; the offset to the wall clock comes from the
+      ``tfos/clock`` annotation that ``utils/profiler.start_trace`` opens
+      every capture with.  Needs jax (``jax.profiler.ProfileData``).
+
 Parity: the reference has no timeline tooling at all — its observability
 is log lines (reference ``__init__.py:1-5``, SURVEY.md §5); this is the
 aggregation half the telemetry tentpole adds on top.
 
 Usage: python scripts/trace_merge.py DIR [--out trace.json]
-           [--summary-out summary.txt]
+           [--summary-out summary.txt] [--xplane CAPTURE_DIR]
 """
 
 from __future__ import annotations
@@ -37,6 +46,13 @@ import re
 import sys
 
 SCHEMA_KEYS = ("ts", "node_id", "role", "kind", "name", "dur_ms", "attrs")
+
+# utils/telemetry.py's names for the feed's consumer (kept in step by
+# tests/test_telemetry.py; this script imports nothing of the package)
+FEED_FETCH_SPANS = ("tfos/feed/ring_wait", "tfos/feed/ring_read")
+FEED_TO_COLUMNS = "tfos/feed/to_columns"
+CLOCK_SPAN = "tfos/clock"
+CAPTURE_SPAN_PREFIXES = ("tfos/", "bench/")
 
 _PID_RE = re.compile(r"-(\d+)\.jsonl$")
 
@@ -83,8 +99,51 @@ def _source_pid(src):
     return int(m.group(1)) if m else abs(hash(src)) % 100000
 
 
-def to_chrome_trace(pairs):
-    """Chrome ``trace_event`` dict from (record, source) pairs.
+def load_xplane(capture_dir):
+    """The capture under ``capture_dir`` as ``[(plane, line, name,
+    ts_epoch_s, dur_s, args)]``: the program's and the benchmark's host
+    spans, and every device operation.  Raises ValueError when the
+    capture has no ``tfos/clock`` annotation to place it on the wall
+    clock (a capture not started by ``utils/profiler.start_trace``)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(
+        capture_dir, "**", "*.xplane.pb"), recursive=True))
+    if not paths:
+        raise ValueError(f"no *.xplane.pb under {capture_dir}")
+    rows, offset_ns = [], None
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        device = plane.name.startswith("/device:")
+        for i, line in enumerate(plane.lines):
+            if device and line.name != "XLA Ops":
+                continue
+            for ev in line.events:
+                if not device and not ev.name.startswith(
+                        CAPTURE_SPAN_PREFIXES):
+                    continue
+                args = {} if device else {
+                    k: v for k, v in ev.stats
+                    if isinstance(v, (int, float, str))}
+                if ev.name == CLOCK_SPAN and "time_ns" in args:
+                    offset_ns = int(args["time_ns"]) - int(ev.start_ns)
+                name = ev.name.split(" = ", 1)[0][:80] if device \
+                    else ev.name
+                rows.append((plane.name, f"{line.name}#{i}", name,
+                             int(ev.start_ns), ev.duration_ns, args))
+    if offset_ns is None:
+        raise ValueError(
+            f"{paths[-1]} has no {CLOCK_SPAN} annotation: its timestamps "
+            "count from the capture's start and cannot be placed on the "
+            "spool's wall clock (take it with utils/profiler.start_trace)")
+    return [(pl, ln, name, (start + offset_ns) / 1e9, dur / 1e9, args)
+            for pl, ln, name, start, dur, args in rows]
+
+
+def to_chrome_trace(pairs, capture=()):
+    """Chrome ``trace_event`` dict from (record, source) pairs, and the
+    rows of ``load_xplane`` if a capture rides along.
 
     Mapping: node_id -> trace pid (one process row per node), source
     file's OS pid -> trace tid (the executor and its forked trainer
@@ -95,9 +154,22 @@ def to_chrome_trace(pairs):
     """
     nodes = sorted({rec["node_id"] for rec, _ in pairs})
     pid_of = {n: i + 1 for i, n in enumerate(nodes)}
-    t0 = min((rec["ts"] for rec, _ in pairs), default=0.0)
+    t0 = min([rec["ts"] for rec, _ in pairs]
+             + [row[3] for row in capture], default=0.0)
     events = []
     named_threads = set()
+    planes = sorted({row[0] for row in capture})
+    for k, plane in enumerate(planes):
+        pid = len(nodes) + 1 + k
+        events.append({"ph": "M", "name": "process_name", "pid": pid,
+                       "tid": 0, "args": {"name": f"capture {plane}"}})
+        lines = sorted({row[1] for row in capture if row[0] == plane})
+        for plane_, line, name, ts, dur, args in capture:
+            if plane_ == plane:
+                events.append({
+                    "name": name, "cat": "capture", "pid": pid,
+                    "tid": lines.index(line) + 1, "args": args, "ph": "X",
+                    "ts": (ts - t0) * 1e6, "dur": dur * 1e6})
     for node in nodes:
         role = next(rec["role"] for rec, _ in pairs if rec["node_id"] == node)
         events.append({
@@ -183,14 +255,22 @@ def summarize(pairs, skipped=0):
                 node["model_flops"] += items * float(attrs["flops_per_item"])
             if attrs.get("peak_flops"):
                 node["peak_flops"] = float(attrs["peak_flops"])
-        elif rec["name"] == "feed/wait":
+        elif rec["name"] in FEED_FETCH_SPANS:
+            # the consumer's wait for a chunk AND its read of it: the
+            # stall fraction reads what ``feed/wait`` read before them
             node["infeed_s"] += float(rec["dur_ms"]) / 1e3
-        elif rec["name"] == "data/stage":
+        elif rec["name"] in ("data/stage", FEED_TO_COLUMNS):
+            # the feed's consumer is the pipeline's last stage: its span
+            # covers the chunk fetches inside it, ``wait_ms`` of them
+            fed = rec["name"] == FEED_TO_COLUMNS
+            wait_ms = float(attrs.get("wait_ms") or 0.0)
             st = data_stages.setdefault(
-                str(attrs.get("stage") or "?"),
+                "to_columns" if fed else str(attrs.get("stage") or "?"),
                 {"self_ms": [], "wait_ms": [], "records": 0})
-            st["self_ms"].append(float(rec["dur_ms"]))
-            st["wait_ms"].append(float(attrs.get("wait_ms") or 0.0))
+            st["self_ms"].append(
+                max(float(rec["dur_ms"]) - wait_ms, 0.0) if fed
+                else float(rec["dur_ms"]))
+            st["wait_ms"].append(wait_ms)
             st["records"] += int(attrs.get("records") or 0)
         elif rec["name"] == "actor/message":
             key = (str(attrs.get("group") or "?"),
@@ -538,6 +618,10 @@ def main(argv=None):
                     help="render one request's causal waterfall + "
                          "critical path instead of the merged summary "
                          "(full trace_id or any unique prefix)")
+    ap.add_argument("--xplane", default=None, metavar="CAPTURE_DIR",
+                    help="a utils/profiler capture directory: its host "
+                         "spans and device operations join the Chrome "
+                         "trace on the spool's wall clock (needs jax)")
     args = ap.parse_args(argv)
 
     if not os.path.isdir(args.run_dir):
@@ -562,8 +646,15 @@ def main(argv=None):
         sys.stdout.write(text)
         return 0
 
+    capture = ()
+    if args.xplane:
+        try:
+            capture = load_xplane(args.xplane)
+        except ValueError as e:
+            print(f"trace_merge: {e}", file=sys.stderr)
+            return 1
     out = args.out or os.path.join(args.run_dir, "trace.json")
-    trace = to_chrome_trace(pairs)
+    trace = to_chrome_trace(pairs, capture)
     with open(out, "w", encoding="utf-8") as f:
         json.dump(trace, f)
     text, stats = summarize(pairs, skipped)

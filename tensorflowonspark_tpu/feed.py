@@ -94,6 +94,39 @@ def _sliced_column(chunk, i, off, take, shapes):
     return col
 
 
+class _ChunkClock:
+    """THE one timing of a chunk fetch.  It alternates between "the
+    transport is empty" (``tfos/feed/ring_wait``: the producer's side of
+    the ring is the wall) and "copying a chunk out and decoding it"
+    (``tfos/feed/ring_read``: the consumer's side); each ``switch``
+    closes one span and opens the other on the same clock read, so the
+    spans, ``TrainMetrics`` and the registry counters cannot disagree."""
+
+    __slots__ = ("wait_s", "read_s", "_t", "_reading", "_span")
+
+    def __init__(self):
+        self.wait_s = self.read_s = 0.0
+        self._reading = False
+        self._span = telemetry.span(telemetry.FEED_RING_WAIT).__enter__()
+        self._t = time.perf_counter()
+
+    def switch(self, reopen=True, **attrs):
+        """End the current phase (``attrs`` go on its span) and, unless
+        this is the end of the fetch, begin the other one."""
+        now = time.perf_counter()
+        if self._reading:
+            self.read_s += now - self._t
+        else:
+            self.wait_s += now - self._t
+        self._t = now
+        self._span.add(**attrs).__exit__(None, None, None)
+        self._reading = not self._reading
+        if reopen:
+            self._span = telemetry.span(
+                telemetry.FEED_RING_READ if self._reading
+                else telemetry.FEED_RING_WAIT).__enter__()
+
+
 class DataFeed:
     """Consumer side of the executor feed queues (TFNode.py:221-329)."""
 
@@ -139,8 +172,10 @@ class DataFeed:
         # producer closures so both sides always agree on the transport
         self._ring = open_feed_ring(mgr, qname_in, producer=False)
 
-    def _get_once(self, timeout_ms, honor_stop=False):
+    def _get_once(self, timeout_ms, honor_stop=False, available=None):
         """One bounded pop attempt; raises TimeoutError when empty.
+        ``available()`` is called where the wait for a chunk ends and
+        the read of it begins (``_ChunkClock.switch``).
 
         ``honor_stop`` (the consumer path): re-check the stop flag AFTER
         acquiring the lock — a consumer that queued on the lock behind
@@ -152,13 +187,15 @@ class DataFeed:
             if honor_stop and self._stop_requested:
                 raise TimeoutError("feed terminating")
             if self._ring is not None:
-                return self._ring.get(timeout_ms)
+                return self._ring.get(timeout_ms, available)
             if self._queue is None:  # resolve the manager proxy once
                 self._queue = self.mgr.get_queue(self.qname_in)
             try:
                 chunk = self._queue.get(block=True, timeout=timeout_ms / 1000.0)
             except _queue.Empty:
                 raise TimeoutError("feed queue empty") from None
+            if available is not None:
+                available()  # the proxy hands the chunk over decoded
             self._queue.task_done()
             return chunk
 
@@ -169,16 +206,18 @@ class DataFeed:
         local check), 1s on the manager-queue compat path where every
         attempt is a proxy RPC — the stop flag only needs sub-second
         responsiveness, not a 10Hz round-trip load on the manager."""
-        timed = (self.metrics is not None or telemetry.enabled()
-                 or metrics_registry.enabled())
-        t0 = time.perf_counter() if timed else None
+        clock = (_ChunkClock() if self.metrics is not None
+                 or telemetry.active() or metrics_registry.enabled()
+                 else None)
+        available = None if clock is None else clock.switch
         slice_ms = 100 if self._ring is not None else 1000
         while True:
             if self._stop_requested:
                 chunk = None  # terminate(): consume no further data
                 break
             try:
-                chunk = self._get_once(timeout_ms=slice_ms, honor_stop=True)
+                chunk = self._get_once(timeout_ms=slice_ms, honor_stop=True,
+                                       available=available)
             except TimeoutError:
                 continue
             faults.check("feed.get", eof=chunk is None)
@@ -189,65 +228,66 @@ class DataFeed:
                 if seq < expected:  # re-served prefix: already consumed
                     metrics_registry.inc(
                         "tfos_data_split_dup_chunks_total")
+                    if clock is not None:
+                        clock.switch(dup=1)  # back to waiting
                     continue
                 self._split_next[sid] = seq + 1
             break
-        if t0 is not None:
-            # ONE measurement feeds both layers (TrainMetrics.infeed_wait
-            # and the telemetry span), so the stall fractions they report
-            # agree by construction.
-            dt = time.perf_counter() - t0
-            self._wait_acc += dt
-            if self.metrics is not None:
-                self.metrics.infeed_wait(dt)
-            # depth read once, shared by telemetry and the live plane
-            qbytes = qchunks = None
-            if telemetry.enabled() or metrics_registry.enabled():
-                try:
-                    if self._ring is not None:
-                        qbytes = self._ring.qsize_bytes()
-                    elif self._queue is not None:
-                        qchunks = self._queue.qsize()
-                except Exception:  # noqa: BLE001 - depth is best-effort
-                    pass
-            if telemetry.enabled():
-                attrs = {"eof": chunk is None}
-                if qbytes is not None:
-                    attrs["queue_bytes"] = qbytes
-                elif qchunks is not None:
-                    attrs["queue_chunks"] = qchunks
-                telemetry.record_span("feed/wait", dt, **attrs)
-            if metrics_registry.enabled():
-                metrics_registry.inc("tfos_feed_wait_seconds_total", dt)
-                metrics_registry.inc("tfos_feed_chunks_total")
-                try:
-                    metrics_registry.inc("tfos_feed_records_total",
-                                         len(chunk))
-                except TypeError:  # None (eof) or a length-less marker
-                    pass
-                if qbytes is not None:
-                    metrics_registry.set_gauge("tfos_feed_ring_bytes",
-                                               qbytes)
-                elif qchunks is not None:
-                    metrics_registry.set_gauge("tfos_feed_queue_depth",
-                                               qchunks)
+        if clock is not None:
+            self._account_chunk(clock, chunk)
         return chunk
 
-    def _consumer_span(self, t0, out):
-        """Per-pull ``data/stage`` span (stage ``fed_consumer``): the
-        pull's wall time minus the transport wait accumulated by
-        ``_get_chunk`` is the consumer's own assembly (slice/concat/
-        stack) cost — the decomposition ``trace_merge``'s ``-- data --``
-        section reports alongside the pipeline stages."""
-        dur = time.perf_counter() - t0
-        wait = min(self._wait_acc, dur)
-        if isinstance(out, dict):
-            n = len(next(iter(out.values()))) if out else 0
-        else:
-            n = len(out)
-        telemetry.record_span("data/stage", max(dur - wait, 0.0),
-                              stage="fed_consumer",
-                              wait_ms=round(wait * 1e3, 3), records=n)
+    def _account_chunk(self, clock, chunk):
+        """Close the fetch's clock and hand its ONE measurement to every
+        reader: the two spans, ``TrainMetrics`` (``infeed_wait`` stays
+        the sum, ``ring_wait_time`` the empty-transport part) and the
+        live plane's counters."""
+        try:
+            records = len(chunk)
+        except TypeError:  # None (eof) or a length-less marker
+            records = 0
+        attrs = {}
+        columns = getattr(chunk, "columns", None)
+        if columns is not None:
+            attrs["bytes"] = sum(c.nbytes for c in columns)
+        try:  # depth after the get, read once for every reader
+            if self._ring is not None:
+                attrs["depth_bytes"] = self._ring.qsize_bytes()
+            elif self._queue is not None:
+                attrs["depth_chunks"] = self._queue.qsize()
+        except Exception:  # noqa: BLE001 - depth is best-effort
+            pass
+        clock.switch(reopen=False, records=records, eof=chunk is None,
+                     **attrs)
+        dt = clock.wait_s + clock.read_s
+        self._wait_acc += dt
+        if self.metrics is not None:
+            self.metrics.infeed_wait(dt, ring_wait=clock.wait_s)
+        metrics_registry.inc("tfos_feed_wait_seconds_total", dt)
+        metrics_registry.inc("tfos_feed_chunks_total")
+        metrics_registry.inc("tfos_feed_records_total", records)
+        if "depth_bytes" in attrs:
+            metrics_registry.set_gauge("tfos_feed_ring_bytes",
+                                       attrs["depth_bytes"])
+        elif "depth_chunks" in attrs:
+            metrics_registry.set_gauge("tfos_feed_queue_depth",
+                                       attrs["depth_chunks"])
+
+    def _assemble(self, pull, batch_size):
+        """One pull under its ``tfos/feed/to_columns`` span: the span's
+        time minus the chunk fetches inside it (``wait_ms``, accumulated
+        by ``_account_chunk``) is the consumer's own assembly (slice /
+        concat / stack) cost — the row ``trace_merge``'s ``-- data --``
+        section shows beside the pipeline stages."""
+        self._wait_acc = 0.0
+        with telemetry.span(telemetry.FEED_TO_COLUMNS) as span:
+            out = pull(batch_size)
+            if isinstance(out, dict):
+                n = len(next(iter(out.values()))) if out else 0
+            else:
+                n = len(out)
+            span.add(records=n, wait_ms=round(self._wait_acc * 1e3, 3))
+        return out
 
     def next_batch(self, batch_size):
         """Gather up to ``batch_size`` records (TFNode.py:243-288).
@@ -257,13 +297,7 @@ class DataFeed:
         queue means end-of-feed; an ``EndPartition`` marker ends the batch
         early in inference mode so results stay partition-aligned.
         """
-        if telemetry.enabled():
-            t0 = time.perf_counter()
-            self._wait_acc = 0.0
-            out = self._next_batch(batch_size)
-            self._consumer_span(t0, out)
-            return out
-        return self._next_batch(batch_size)
+        return self._assemble(self._next_batch, batch_size)
 
     def _next_batch(self, batch_size):
         logger.debug("next_batch(%d) invoked", batch_size)
@@ -377,13 +411,7 @@ class DataFeed:
         """
         if self.input_tensors is None:
             raise ValueError("next_batch_columns requires input_mapping")
-        if telemetry.enabled():
-            t0 = time.perf_counter()
-            self._wait_acc = 0.0
-            out = self._next_batch_columns(batch_size)
-            self._consumer_span(t0, out)
-            return out
-        return self._next_batch_columns(batch_size)
+        return self._assemble(self._next_batch_columns, batch_size)
 
     def _next_batch_columns(self, batch_size):
         import numpy as np

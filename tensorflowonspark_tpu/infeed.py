@@ -18,6 +18,8 @@ import queue as _queue
 import threading
 import time as _time
 
+from tensorflowonspark_tpu.utils import telemetry
+
 logger = logging.getLogger(__name__)
 
 _END = object()
@@ -45,7 +47,10 @@ def batch_iterator(feed, batch_size, collate=None, min_batch=None,
             else len(records)
         if n < min_batch:
             continue
-        yield collate(records) if collate is not None else records
+        if collate is not None:
+            with telemetry.span(telemetry.FEED_COLLATE):
+                records = collate(records)
+        yield records
 
 
 def prefetch_to_device(it, depth=2, placement=None, on_abandon=None):
@@ -82,7 +87,8 @@ def prefetch_to_device(it, depth=2, placement=None, on_abandon=None):
                 # one more batch into HBM just for the drain to discard it
                 if cancelled.is_set():
                     break
-                staged = place(batch)
+                with telemetry.span(telemetry.FEED_H2D):
+                    staged = place(batch)
                 # re-check after place(): the consumer may have abandoned
                 # the stream during a long transfer — dropping the local
                 # reference frees the device buffer, whereas enqueueing it
@@ -90,7 +96,9 @@ def prefetch_to_device(it, depth=2, placement=None, on_abandon=None):
                 if cancelled.is_set():
                     del staged
                     break
-                q.put(staged)
+                # a full queue means the device is the wall
+                with telemetry.span(telemetry.FEED_STAGE_FULL):
+                    q.put(staged)
         except Exception as e:  # noqa: BLE001 - forwarded to consumer
             q.put(("__prefetch_error__", e))
         finally:
@@ -102,7 +110,9 @@ def prefetch_to_device(it, depth=2, placement=None, on_abandon=None):
     finished = False
     try:
         while True:
-            item = q.get()
+            # an empty queue means the feed is the wall
+            with telemetry.span(telemetry.FEED_NEXT):
+                item = q.get()
             if item is _END:
                 finished = True
                 return
@@ -196,8 +206,13 @@ def synchronized(it, feed=None):
     while True:
         item = next(it, None)
         mine = item is not None
-        flags = multihost_utils.process_allgather(np.asarray(mine))
-        if not bool(np.asarray(flags).all()):
+        # the all-gather AND its fetch: the fetch queues behind every
+        # step already dispatched, which is what this span shows
+        with telemetry.span(telemetry.FEED_SYNC) as span:
+            flags = multihost_utils.process_allgather(np.asarray(mine))
+            ok = bool(np.asarray(flags).all())
+            span.add(ok=ok)
+        if not ok:
             if mine:
                 logger.info(
                     "synchronized: a peer's feed ended; draining local "
@@ -227,7 +242,6 @@ def tfrecord_device_feed(source, batch_size, *, collate=None, depth=2,
     shapes.  ``source`` is a dir, file, or this worker's shard subset.
     """
     from tensorflowonspark_tpu import dfutil
-    from tensorflowonspark_tpu.utils import telemetry
 
     it = dfutil.iter_tfrecords_columnar(source, batch_size,
                                         drop_remainder=drop_remainder)

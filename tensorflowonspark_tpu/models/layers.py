@@ -112,8 +112,8 @@ def _bn_train_fused(x, scale, bias, eps):
 
     The autodiff backward of the folded form materializes several
     standalone activation-sized multiplies (x̂ recompute, dvar/dmean
-    broadcasts) that XLA:TPU does not fuse — measured ~37ms of a 97ms
-    ResNet-50/b256 step on v5e (PERF_BREAKDOWN.md).  The custom VJP
+    broadcasts) that XLA:TPU does not fuse (an earlier session's profile,
+    which summed per-op durations: a hypothesis, PERF.md).  The custom VJP
     expresses the whole backward as one reduction pass over (g, x) and
     one elementwise pass dx = a·g + b·x + c, each a single fusion.
     """
@@ -373,11 +373,13 @@ def relu(x):
 
 def softmax_cross_entropy(logits, labels, num_classes=None):
     """Mean CE; integer labels.  Stable log-softmax in float32."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
-    logp = logits - logz
-    nll = -jnp.take_along_axis(logp, labels[..., None].astype(jnp.int32), axis=-1)
-    return jnp.mean(nll)
+    with jax.named_scope("loss"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
+        logp = logits - logz
+        nll = -jnp.take_along_axis(
+            logp, labels[..., None].astype(jnp.int32), axis=-1)
+        return jnp.mean(nll)
 
 
 def accuracy(logits, labels):

@@ -147,29 +147,35 @@ def apply(params, state, images, depth=50, train=True, small_inputs=False,
           compute_dtype=jnp.bfloat16, stem_s2d=True, bn_fused=True):
     """images [N,H,W,3] → logits [N,num_classes]; returns (logits, new_state)."""
     kind, counts = _PLANS[depth]
-    x = images.astype(compute_dtype)
     new_state = {}
-    if small_inputs:
-        x = L.conv(params["stem"], x)
-    elif stem_s2d and x.shape[1] % 2 == 0 and x.shape[2] % 2 == 0:
-        x = _stem_space_to_depth(params["stem"]["w"], x)
-    else:
-        x = L.conv(params["stem"], x, stride=2)
-    x, new_state["bn_stem"] = L.batchnorm_relu(
-        params["bn_stem"], state["bn_stem"], x, train, fused=bn_fused)
-    if not small_inputs:
-        # SAME padding: 112 -> 56 (the standard ResNet stem; VALID's 55
-        # also breaks the TPU's (8,128) tiling on every stage-1 tensor)
-        x = L.max_pool(x, window=3, stride=2, padding="SAME")
+    # the scopes (stem, stage1..stage4, head) are the stable names a
+    # device trace is reduced by; metadata only
+    with jax.named_scope("stem"):
+        x = images.astype(compute_dtype)
+        if small_inputs:
+            x = L.conv(params["stem"], x)
+        elif stem_s2d and x.shape[1] % 2 == 0 and x.shape[2] % 2 == 0:
+            x = _stem_space_to_depth(params["stem"]["w"], x)
+        else:
+            x = L.conv(params["stem"], x, stride=2)
+        x, new_state["bn_stem"] = L.batchnorm_relu(
+            params["bn_stem"], state["bn_stem"], x, train, fused=bn_fused)
+        if not small_inputs:
+            # SAME padding: 112 -> 56 (the standard ResNet stem; VALID's
+            # 55 also breaks the TPU's (8,128) tiling on every stage-1
+            # tensor)
+            x = L.max_pool(x, window=3, stride=2, padding="SAME")
     for stage, nblocks in enumerate(counts):
-        for b in range(nblocks):
-            stride = 2 if (b == 0 and stage > 0) else 1
-            name = f"s{stage}b{b}"
-            x, new_state[name] = _block_apply(
-                params[name], state[name], x, kind, stride, train, bn_fused
-            )
-    x = L.avg_pool_global(x).astype(jnp.float32)
-    return L.dense(params["fc"], x), new_state
+        with jax.named_scope(f"stage{stage + 1}"):
+            for b in range(nblocks):
+                stride = 2 if (b == 0 and stage > 0) else 1
+                name = f"s{stage}b{b}"
+                x, new_state[name] = _block_apply(
+                    params[name], state[name], x, kind, stride, train,
+                    bn_fused)
+    with jax.named_scope("head"):
+        x = L.avg_pool_global(x).astype(jnp.float32)
+        return L.dense(params["fc"], x), new_state
 
 
 def make_train_step(optimizer, depth=50, small_inputs=False,

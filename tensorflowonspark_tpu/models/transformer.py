@@ -127,17 +127,24 @@ def _layer_apply(p, x, cfg, rope, attn_fn):
     h, hd = cfg.n_heads, cfg.head_dim
     cos, sin, positions = rope
 
-    y = ops.rmsnorm_reference(x, p["ln1"])
-    qkv = _matmul(y, p["wqkv"]).reshape(b, s, 3, h, hd)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = ops.apply_rope(q, cos, sin, positions=positions)
-    k = ops.apply_rope(k, cos, sin, positions=positions)
-    attn = attn_fn(q, k, v).reshape(b, s, dim)
-    x = x + _matmul(attn, p["wo"])
+    # the scopes are the stable names a device trace is reduced by
+    # (benchmark/lib/program_trace.py); metadata only
+    with jax.named_scope("block/attn"):
+        y = ops.rmsnorm_reference(x, p["ln1"])
+        qkv = _matmul(y, p["wqkv"]).reshape(b, s, 3, h, hd)
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q = ops.apply_rope(q, cos, sin, positions=positions)
+        k = ops.apply_rope(k, cos, sin, positions=positions)
+        attn = attn_fn(q, k, v).reshape(b, s, dim)
+        x = x + _matmul(attn, p["wo"])
 
-    y = ops.rmsnorm_reference(x, p["ln2"])
-    y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
-    return x + y
+    return x + _mlp(p, x)
+
+
+def _mlp(p, x):
+    with jax.named_scope("block/mlp"):
+        y = ops.rmsnorm_reference(x, p["ln2"])
+        return _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
 
 
 def apply(params, tokens, cfg: Config, *, attn_fn=None,
@@ -187,7 +194,8 @@ def apply(params, tokens, cfg: Config, *, attn_fn=None,
                 else ops.mha_reference)
         attn_fn = functools.partial(base, causal=True)
     dtype = cfg.compute_dtype
-    x = params["embed"].astype(dtype)[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dtype)[tokens]
     if positions is None:
         rope_len = tokens.shape[1]
         pos2d = None
@@ -218,11 +226,13 @@ def apply(params, tokens, cfg: Config, *, attn_fn=None,
         return layer_fn(layer_params, x, cfg, rope, attn_fn), None
 
     x, _ = lax.scan(body, x, params["layers"])
-    x = ops.rmsnorm_reference(x, params["ln_f"])
-    if return_hidden:
-        return x
-    logits = _matmul(x, params["head"])
-    return logits if logits_dtype is None else logits.astype(logits_dtype)
+    with jax.named_scope("lm_head"):
+        x = ops.rmsnorm_reference(x, params["ln_f"])
+        if return_hidden:
+            return x
+        logits = _matmul(x, params["head"])
+        return (logits if logits_dtype is None
+                else logits.astype(logits_dtype))
 
 
 def _blockwise_nll(x, head, labels, block_v):
@@ -312,9 +322,10 @@ def loss_fn(params, tokens, cfg: Config, *, attn_fn=None, remat=False,
             valid = labels >= 0
             labels = jnp.maximum(labels, 0)
         b, s, d = x.shape
-        nll = _blockwise_nll(
-            x.reshape(b * s, d), params["head"],
-            labels.reshape(b * s), ce_block).reshape(b, s)
+        with jax.named_scope("loss"):
+            nll = _blockwise_nll(
+                x.reshape(b * s, d), params["head"],
+                labels.reshape(b * s), ce_block).reshape(b, s)
     else:
         logits = apply(params, tokens, cfg, attn_fn=attn_fn,
                        logits_dtype=None, remat=remat, positions=positions)
@@ -325,15 +336,17 @@ def loss_fn(params, tokens, cfg: Config, *, attn_fn=None, remat=False,
         else:
             valid = labels >= 0
             labels = jnp.maximum(labels, 0)
-        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-        gold = jnp.take_along_axis(
-            logits, labels[..., None].astype(jnp.int32), axis=-1
-        )[..., 0].astype(jnp.float32)
-        nll = lse - gold
-    if valid is None:
-        return jnp.mean(nll)
-    vf = valid.astype(jnp.float32)
-    return jnp.sum(nll * vf) / jnp.maximum(jnp.sum(vf), 1.0)
+        with jax.named_scope("loss"):
+            lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+            gold = jnp.take_along_axis(
+                logits, labels[..., None].astype(jnp.int32), axis=-1
+            )[..., 0].astype(jnp.float32)
+            nll = lse - gold
+    with jax.named_scope("loss"):
+        if valid is None:
+            return jnp.mean(nll)
+        vf = valid.astype(jnp.float32)
+        return jnp.sum(nll * vf) / jnp.maximum(jnp.sum(vf), 1.0)
 
 
 # ---------------------------------------------------------------------------
@@ -361,17 +374,19 @@ def _layer_apply_kv(p, x, cfg, rope, attn_fn):
     h, hd = cfg.n_heads, cfg.head_dim
     cos, sin, positions = rope
 
-    y = ops.rmsnorm_reference(x, p["ln1"])
-    qkv = _matmul(y, p["wqkv"]).reshape(b, s, 3, h, hd)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = ops.apply_rope(q, cos, sin, positions=positions)
-    k = ops.apply_rope(k, cos, sin, positions=positions)
-    attn = attn_fn(q, k, v).reshape(b, s, dim)
-    x = x + _matmul(attn, p["wo"])
-
-    y = ops.rmsnorm_reference(x, p["ln2"])
-    y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
-    return x + y, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    with jax.named_scope("attn"):
+        y = ops.rmsnorm_reference(x, p["ln1"])
+        qkv = _matmul(y, p["wqkv"]).reshape(b, s, 3, h, hd)
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q = ops.apply_rope(q, cos, sin, positions=positions)
+        k = ops.apply_rope(k, cos, sin, positions=positions)
+        attn = attn_fn(q, k, v).reshape(b, s, dim)
+        x = x + _matmul(attn, p["wo"])
+    with jax.named_scope("mlp"):
+        y = ops.rmsnorm_reference(x, p["ln2"])
+        y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
+    with jax.named_scope("write_kv"):
+        return x + y, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
 
 def prefill(params, tokens, cfg: Config, *, lengths=None, attn_fn=None):
@@ -548,29 +563,35 @@ def decode_step_paged(params, tokens, cfg: Config, pool_k, pool_v,
     def body(carry, inp):
         x, = carry
         p, pk_l, pv_l = inp             # pk_l/pv_l: [NB, H, bs, D]
-        y = ops.rmsnorm_reference(x, p["ln1"])
-        qkv = _matmul(y, p["wqkv"]).reshape(s_slots, w, 3, h, hd)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        q = ops.apply_rope(q, cos, sin, positions=posc)
-        k = ops.apply_rope(k, cos, sin, positions=posc)
-        # flatten pool block axis with its in-block axis: [NB*bs, H, D]
-        pk_f = pk_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
-        pv_f = pv_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
-        pk_f = pk_f.at[widx].set(k.reshape(-1, h, hd))
-        pv_f = pv_f.at[widx].set(v.reshape(-1, h, hd))
-        kg = pk_f[gidx].astype(jnp.float32)          # [S, cap, H, D]
-        vg = pv_f[gidx].astype(jnp.float32)
-        qf = q.astype(jnp.float32)                   # [S, W, H, D]
-        scores = jnp.einsum("swhd,smhd->shwm", qf, kg) * scale
-        scores = jnp.where(kv_mask, scores, _NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("shwm,smhd->swhd", probs, vg)
-        attn = attn.astype(dtype).reshape(s_slots, w, h * hd)
-        x = x + _matmul(attn, p["wo"])
-        y = ops.rmsnorm_reference(x, p["ln2"])
-        y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
-        pk_l = pk_f.reshape(nb, bs, h, hd).transpose(0, 2, 1, 3)
-        pv_l = pv_f.reshape(nb, bs, h, hd).transpose(0, 2, 1, 3)
+        with jax.named_scope("attn"):
+            y = ops.rmsnorm_reference(x, p["ln1"])
+            qkv = _matmul(y, p["wqkv"]).reshape(s_slots, w, 3, h, hd)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            q = ops.apply_rope(q, cos, sin, positions=posc)
+            k = ops.apply_rope(k, cos, sin, positions=posc)
+        with jax.named_scope("write_kv"):
+            # flatten pool block axis with its in-block axis: [NB*bs, H, D]
+            pk_f = pk_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
+            pv_f = pv_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
+            pk_f = pk_f.at[widx].set(k.reshape(-1, h, hd))
+            pv_f = pv_f.at[widx].set(v.reshape(-1, h, hd))
+        with jax.named_scope("gather_kv"):
+            kg = pk_f[gidx].astype(jnp.float32)          # [S, cap, H, D]
+            vg = pv_f[gidx].astype(jnp.float32)
+        with jax.named_scope("attn"):
+            qf = q.astype(jnp.float32)                   # [S, W, H, D]
+            scores = jnp.einsum("swhd,smhd->shwm", qf, kg) * scale
+            scores = jnp.where(kv_mask, scores, _NEG_INF)
+            probs = jax.nn.softmax(scores, axis=-1)
+            attn = jnp.einsum("shwm,smhd->swhd", probs, vg)
+            attn = attn.astype(dtype).reshape(s_slots, w, h * hd)
+            x = x + _matmul(attn, p["wo"])
+        with jax.named_scope("mlp"):
+            y = ops.rmsnorm_reference(x, p["ln2"])
+            y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
+        with jax.named_scope("write_kv"):
+            pk_l = pk_f.reshape(nb, bs, h, hd).transpose(0, 2, 1, 3)
+            pv_l = pv_f.reshape(nb, bs, h, hd).transpose(0, 2, 1, 3)
         return (x + y,), (pk_l, pv_l)
 
     # scan over layers: pools arrive [NB, L, ...] -> scan axis leading
@@ -630,32 +651,38 @@ def prefill_extend(params, tokens, cfg: Config, pool_k, pool_v,
     def layer(carry, inp):
         x, = carry
         p, pk_l, pv_l = inp
-        y = ops.rmsnorm_reference(x, p["ln1"])
-        qkv = _matmul(y, p["wqkv"]).reshape(b, t, 3, h, hd)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        q = ops.apply_rope(q, cos, sin, positions=pos)
-        k = ops.apply_rope(k, cos, sin, positions=pos)
-        pk_f = pk_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
-        pv_f = pv_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
-        kp = pk_f[gidx].astype(jnp.float32)          # [B, P, H, D]
-        vp = pv_f[gidx].astype(jnp.float32)
-        qf = q.astype(jnp.float32)
-        sp = jnp.einsum("bthd,bphd->bhtp", qf, kp) * scale
-        st = jnp.einsum("bthd,bshd->bhts", qf,
-                        k.astype(jnp.float32)) * scale
-        sp = jnp.where(pmask, sp, _NEG_INF)
-        st = jnp.where(cmask, st, _NEG_INF)
-        probs = jax.nn.softmax(
-            jnp.concatenate([sp, st], axis=-1), axis=-1)
-        pp, pt = probs[..., :pcap], probs[..., pcap:]
-        attn = (jnp.einsum("bhtp,bphd->bthd", pp, vp)
-                + jnp.einsum("bhts,bshd->bthd", pt,
-                             v.astype(jnp.float32)))
-        attn = attn.astype(dtype).reshape(b, t, h * hd)
-        x = x + _matmul(attn, p["wo"])
-        y = ops.rmsnorm_reference(x, p["ln2"])
-        y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
-        return (x + y,), (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+        with jax.named_scope("attn"):
+            y = ops.rmsnorm_reference(x, p["ln1"])
+            qkv = _matmul(y, p["wqkv"]).reshape(b, t, 3, h, hd)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            q = ops.apply_rope(q, cos, sin, positions=pos)
+            k = ops.apply_rope(k, cos, sin, positions=pos)
+        with jax.named_scope("gather_kv"):
+            pk_f = pk_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
+            pv_f = pv_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
+            kp = pk_f[gidx].astype(jnp.float32)          # [B, P, H, D]
+            vp = pv_f[gidx].astype(jnp.float32)
+        with jax.named_scope("attn"):
+            qf = q.astype(jnp.float32)
+            sp = jnp.einsum("bthd,bphd->bhtp", qf, kp) * scale
+            st = jnp.einsum("bthd,bshd->bhts", qf,
+                            k.astype(jnp.float32)) * scale
+            sp = jnp.where(pmask, sp, _NEG_INF)
+            st = jnp.where(cmask, st, _NEG_INF)
+            probs = jax.nn.softmax(
+                jnp.concatenate([sp, st], axis=-1), axis=-1)
+            pp, pt = probs[..., :pcap], probs[..., pcap:]
+            attn = (jnp.einsum("bhtp,bphd->bthd", pp, vp)
+                    + jnp.einsum("bhts,bshd->bthd", pt,
+                                 v.astype(jnp.float32)))
+            attn = attn.astype(dtype).reshape(b, t, h * hd)
+            x = x + _matmul(attn, p["wo"])
+        with jax.named_scope("mlp"):
+            y = ops.rmsnorm_reference(x, p["ln2"])
+            y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
+        with jax.named_scope("write_kv"):
+            return (x + y,), (k.transpose(0, 2, 1, 3),
+                              v.transpose(0, 2, 1, 3))
 
     (x,), (k, v) = lax.scan(
         layer, (x,),

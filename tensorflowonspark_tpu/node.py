@@ -863,26 +863,48 @@ def train(cluster_info, cluster_meta, feed_timeout=600, qname="input",
         equeue = mgr.get_queue("error")
         encode = _make_chunk_encoder()
 
+        # one tfos/feeder/chunk span per chunk (never per record): where
+        # THIS side of the ring spends its time — inside the partition
+        # iterator, encoding, or blocked on a full ring.  Another process
+        # than the trainer's, so it reaches the spool only.
+        timed = telemetry.active()
+        t_src = time.perf_counter() if timed else None
+
         def put(chunk):
             """False once the consumer requested termination mid-feed: a
             put blocked on a full ring re-checks state each second, so a
             feeder never deadlocks against a consumer that stopped
             draining (and fails fast when the consumer errored or its
             heartbeat went stale)."""
+            nonlocal t_src
             faults.check("feed.put", part=pidx)
+            t0 = time.perf_counter() if timed else None
+            records = len(chunk)
             chunk = encode(chunk)
+            t1 = time.perf_counter() if timed else None
+            sent = True
             if ring is not None:
                 while True:
                     try:
                         ring.put(chunk, timeout_ms=1000)
-                        return True
+                        break
                     except TimeoutError:
                         if str(mgr.get("state")) == "terminating":
-                            return False
+                            sent = False
+                            break
                         _raise_if_consumer_lost(mgr, equeue)
             else:
                 queue.put(chunk, block=True)
-                return True
+            if timed:
+                now = time.perf_counter()
+                telemetry.record_span(
+                    telemetry.FEEDER_CHUNK, now - t_src, part=pidx,
+                    records=records,
+                    source_ms=round((t0 - t_src) * 1e3, 3),
+                    encode_ms=round((t1 - t0) * 1e3, 3),
+                    put_wait_ms=round((now - t1) * 1e3, 3))
+                t_src = now
+            return sent
 
         total = 0
         terminated = False
@@ -917,18 +939,22 @@ def train(cluster_info, cluster_meta, feed_timeout=600, qname="input",
                         path="shm" if ring is not None else "manager",
                         terminated=terminated)
 
-        if ring is not None:
-            if not terminated:
-                # terminate()'s drain loop keeps reading while we hold the
-                # producer flock, so outstanding bytes always reach zero
-                _await_consumption(
-                    mgr, lambda: ring.qsize_bytes() > 0, feed_timeout, poll=0.2
-                )
-            ring.close()
-        else:
-            joining = threading.Thread(target=queue.join, daemon=True)
-            joining.start()
-            _await_consumption(mgr, joining.is_alive, feed_timeout)
+        # the hand-over: this task holds the ring until the consumer has
+        # emptied it, polling; the next partition's feeder waits behind it
+        with telemetry.span(telemetry.FEEDER_HANDOFF, part=pidx):
+            if ring is not None:
+                if not terminated:
+                    # terminate()'s drain loop keeps reading while we hold
+                    # the producer flock, so outstanding bytes always reach
+                    # zero
+                    _await_consumption(
+                        mgr, lambda: ring.qsize_bytes() > 0, feed_timeout,
+                        poll=0.2)
+                ring.close()
+            else:
+                joining = threading.Thread(target=queue.join, daemon=True)
+                joining.start()
+                _await_consumption(mgr, joining.is_alive, feed_timeout)
 
         # fully consumed, not cut short: record it in the driver's feed
         # ledger so a post-recovery relaunch of this feed job skips it.

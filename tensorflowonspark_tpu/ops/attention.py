@@ -188,6 +188,7 @@ def _flash_forward(q, k, v, *, causal, scale, block_q, block_kv, interpret):
             jax.ShapeDtypeStruct((b * h, sq + pad_q, 1), jnp.float32),
         ),
         interpret=interpret,
+        name="tfos_flash_fwd",
     )(qf, kf, vf)
     out = out[:, :sq].reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     lse = lse[:, :sq, 0].reshape(b, h, sq)  # [B, H, Sq]
@@ -339,6 +340,7 @@ def _flash_backward_pallas(q, k, v, out, lse, g, *, causal, scale, block_q,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
         interpret=interpret,
+        name="tfos_flash_bwd_dq",
     )(qf, kf, vf, gf, lsef, delta)
 
     dk, dv = pl.pallas_call(
@@ -361,6 +363,7 @@ def _flash_backward_pallas(q, k, v, out, lse, g, *, causal, scale, block_q,
             jax.ShapeDtypeStruct((b * h, skv_p, d), v.dtype),
         ),
         interpret=interpret,
+        name="tfos_flash_bwd_dkv",
     )(qf, kf, vf, gf, lsef, delta)
 
     def unflat(x, s):  # [B*H, S, D] -> [B, S, H, D]

@@ -50,6 +50,7 @@ def _rmsnorm_forward(x, scale, eps, block_rows, interpret):
         out_specs=pl.BlockSpec((block_rows, dim), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="tfos_rmsnorm",
     )(x2, scale)
     return out[:rows].reshape(shape)
 

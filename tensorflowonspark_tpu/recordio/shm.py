@@ -158,17 +158,32 @@ class ShmQueue:
                 pickle.dumps(obj, protocol=pickle.HIGHEST_PROTOCOL),
                 timeout_ms)
 
-    def get(self, timeout_ms=-1):
-        """Pop one object.  Fast-path messages are popped directly into a
-        caller-owned buffer (one copy) and the columns come back as numpy
-        VIEWS over it — no pickle, no further copies."""
-        import numpy as np
+    def get(self, timeout_ms=-1, available=None):
+        """Pop one object: ``wait`` until one is there, then ``read`` it.
+        ``available()`` is called between the two, which is where the
+        feed splits its timing: an empty ring is the producer's time,
+        the copy and decode are the consumer's."""
+        n = self.wait(timeout_ms)
+        if available is not None:
+            available()
+        return None if n is None else self.read(n)
 
+    def wait(self, timeout_ms=-1):
+        """Block until a message is AVAILABLE, without consuming it:
+        its length in bytes, or None once the ring is closed and
+        drained; TimeoutError when it stays empty."""
         n = self._lib.shq_peek_len(self._h, timeout_ms)
         if n == -1:
             raise TimeoutError(f"shm queue {self.name} empty")
-        if n == -2:
-            return None  # closed and drained
+        return None if n == -2 else n
+
+    def read(self, n):
+        """Consume the message ``wait`` announced.  Fast-path messages
+        are popped directly into a caller-owned buffer (one copy) and
+        the columns come back as numpy VIEWS over it — no pickle, no
+        further copies."""
+        import numpy as np
+
         # np.empty, NOT bytearray: bytearray(n) zero-fills, which is
         # a full hidden extra write of the payload size per message
         buf = np.empty(n, np.uint8)

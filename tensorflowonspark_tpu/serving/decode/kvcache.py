@@ -38,7 +38,25 @@ jax is imported lazily: the classes are instantiated replica-side only
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
+
+
+@functools.lru_cache(maxsize=1)
+def _kv_insert():
+    """The paged insert's scatter as ONE named program: a device trace
+    shows it as ``jit_tfos_kv_insert`` under the scope ``kv_insert``,
+    where the eager ``pool.at[blocks].set(...)`` was the library's
+    anonymous ``jit_scatter``.  The same scatter, one program per number
+    of blocks as before."""
+    import jax
+
+    def tfos_kv_insert(pool, blocks, values):
+        with jax.named_scope("kv_insert"):
+            return pool.at[blocks].set(values)
+
+    return jax.jit(tfos_kv_insert)
 
 
 class CacheOOM(RuntimeError):
@@ -380,8 +398,9 @@ class PagedKVCache:
         ll, hh, _, dd = kk.shape
         kk = kk.reshape(ll, hh, nch, bs, dd).transpose(2, 0, 1, 3, 4)
         vv = vv.reshape(ll, hh, nch, bs, dd).transpose(2, 0, 1, 3, 4)
-        self.k = self.k.at[phys].set(kk.astype(self.dtype))
-        self.v = self.v.at[phys].set(vv.astype(self.dtype))
+        insert = _kv_insert()
+        self.k = insert(self.k, phys, kk.astype(self.dtype))
+        self.v = insert(self.v, phys, vv.astype(self.dtype))
 
     # -- introspection ------------------------------------------------------
     @property

@@ -284,6 +284,22 @@ class DecodeEngine:
         self.prefix_tokens_saved = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
+        # always-on: tokens emitted by decode iterations (a session's
+        # first token comes from its prefill and is not among them),
+        # prompt tokens admitted, and where the engine thread's time
+        # went.  The phases are chained clock reads (``_mark``), so they
+        # sum to the thread's wall time by construction.
+        self.tokens = 0
+        self.prompt_tokens = 0
+        self._phase_s = {"idle": 0.0, "admit": 0.0, "step": 0.0,
+                         "fetch": 0.0, "host": 0.0}
+        self._t_mark = self._t_started = None
+
+    def _mark(self, phase):
+        """Everything since the last mark was ``phase``."""
+        now = time.perf_counter()
+        self._phase_s[phase] += now - self._t_mark
+        self._t_mark = now
 
     # -- lifecycle ----------------------------------------------------------
     def start(self, timeout=120.0):
@@ -349,6 +365,9 @@ class DecodeEngine:
             "iterations": self.iterations,
             "prefills": self.prefills,
             "retired": self.retired,
+            "tokens": self.tokens,
+            "prompt_tokens": self.prompt_tokens,
+            "phase_s": {k: round(v, 6) for k, v in self._phase_s.items()},
             "active": len(self._active),
             "queued": queued,
             "slots": self._spec.slots,
@@ -399,53 +418,58 @@ class DecodeEngine:
             self._device = tpu_info.device_facts()
             self.set_params(self._params)
 
-            def _prefill(p, toks, lens):
+            # the closures' names are the programs' names in a device
+            # trace (``jit_tfos_decode_step_paged``): say what they are
+            def tfos_prefill(p, toks, lens):
                 return transformer.prefill(p, toks, cfg, lengths=lens)
 
-            self._prefill_jit = jax.jit(_prefill)
+            self._prefill_jit = jax.jit(tfos_prefill)
             if spec.paged:
-                def _extend(p, toks, pk, pv, ptab, plens, lens):
+                def tfos_prefill_extend(p, toks, pk, pv, ptab, plens, lens):
                     return transformer.prefill_extend(
                         p, toks, cfg, pk, pv, ptab, plens, lengths=lens)
 
-                def _pstep(p, toks, pk, pv, tables, lens):
+                def tfos_decode_step_paged(p, toks, pk, pv, tables, lens):
                     return transformer.decode_step_paged(
                         p, toks, cfg, pk, pv, tables, lens)
 
-                self._extend_jit = jax.jit(_extend)
-                self._pstep_jit = jax.jit(_pstep)
+                self._extend_jit = jax.jit(tfos_prefill_extend)
+                self._pstep_jit = jax.jit(tfos_decode_step_paged)
             else:
-                def _step(p, toks, ck, cv, lens):
+                def tfos_decode_step(p, toks, ck, cv, lens):
                     return transformer.decode_step(
                         p, toks, cfg, ck, cv, lens)
 
-                self._step_jit = jax.jit(_step)
+                self._step_jit = jax.jit(tfos_decode_step)
             if spec.speculative:
                 dcfg = spec.draft_cfg
 
-                def _dprefill(p, toks, lens):
+                def tfos_draft_prefill(p, toks, lens):
                     return transformer.prefill(p, toks, dcfg, lengths=lens)
 
-                def _dstep(p, toks, ck, cv, lens):
+                def tfos_draft_step(p, toks, ck, cv, lens):
                     return transformer.decode_step(
                         p, toks, dcfg, ck, cv, lens)
 
-                self._dprefill_jit = jax.jit(_dprefill)
-                self._dstep_jit = jax.jit(_dstep)
+                self._dprefill_jit = jax.jit(tfos_draft_prefill)
+                self._dstep_jit = jax.jit(tfos_draft_step)
             self._kvcache_mod = kvcache
             cache, dcache = self._build_caches()
         except BaseException as e:  # noqa: BLE001 - surface via start()
             self._init_error = e
             self._started.set()
             return
+        self._t_mark = self._t_started = time.perf_counter()
         self._started.set()
         while not self._stop.is_set():
             try:
                 faults.check("decode.step", replica=self._replica)
                 self._admit(cache, dcache)
                 if not self._active:
-                    self._wake.wait(0.02)
-                    self._wake.clear()
+                    with telemetry.span(telemetry.DECODE_IDLE):
+                        self._wake.wait(0.02)
+                        self._wake.clear()
+                    self._mark("idle")
                     continue
                 if self._spec.paged:
                     self._iterate_paged(cache, dcache)
@@ -477,16 +501,28 @@ class DecodeEngine:
                 batch.append(self._q.popleft())
         if not batch:
             return
+        self._mark("host")
+        n_prompt = sum(len(req["prompt"]) for req in batch)
+        with telemetry.span(telemetry.DECODE_ADMIT_SPAN,
+                            sessions=len(batch), prompt_tokens=n_prompt):
+            self._admit_batch(batch, cache, dcache)
+        self.prompt_tokens += n_prompt
+        self._mark("admit")
+
+    def _admit_batch(self, batch, cache, dcache):
+        """The admission itself, under ``_admit``'s span: trie match,
+        bucketed prefills, slot installation, first tokens."""
         cfg = self._spec.cfg
         paged = self._spec.paged
         plain, matched = [], []
-        for req in batch:
-            shared, mlen = (cache.match_prefix(req["prompt"])
-                            if paged else ([], 0))
-            if mlen > 0:
-                matched.append((req, shared, mlen))
-            else:
-                plain.append(req)
+        with telemetry.span(telemetry.DECODE_TRIE_MATCH):
+            for req in batch:
+                shared, mlen = (cache.match_prefix(req["prompt"])
+                                if paged else ([], 0))
+                if mlen > 0:
+                    matched.append((req, shared, mlen))
+                else:
+                    plain.append(req)
 
         admitted = []  # (req, logits_row [vocab], k_i, v_i, shared, mlen)
         # -- plain bucketed prefill (whole prompt) --------------------------
@@ -502,8 +538,11 @@ class DecodeEngine:
             lens = np.asarray([len(m["prompt"]) for m in members], np.int32)
             toks = _batcher.pad_rows(toks, rows)
             lens = _batcher.pad_rows(lens, rows)
-            logits, k, v = self._prefill_jit(self._params, toks, lens)
-            logits = np.asarray(logits)
+            # dispatch to first-token logits on the host
+            with telemetry.span(telemetry.DECODE_PREFILL, bucket=t,
+                                rows=rows):
+                logits, k, v = self._prefill_jit(self._params, toks, lens)
+                logits = np.asarray(logits)
             self.prefills += 1
             for i, req in enumerate(members):
                 admitted.append((req, logits[i], k[i], v[i], [], 0))
@@ -531,9 +570,12 @@ class DecodeEngine:
             lens = _batcher.pad_rows(lens, rows)
             ptab = _batcher.pad_rows(ptab, rows)
             plens = _batcher.pad_rows(plens, rows)
-            logits, k, v = self._extend_jit(
-                self._params, toks, cache.k, cache.v, ptab, plens, lens)
-            logits = np.asarray(logits)
+            with telemetry.span(telemetry.DECODE_PREFILL, bucket=t,
+                                rows=rows, prefix_blocks=nbp):
+                logits, k, v = self._extend_jit(
+                    self._params, toks, cache.k, cache.v, ptab, plens,
+                    lens)
+                logits = np.asarray(logits)
             self.prefills += 1
             for i, (req, shared, mlen) in enumerate(members):
                 admitted.append((req, logits[i], k[i], v[i], shared, mlen))
@@ -571,10 +613,14 @@ class DecodeEngine:
                 bs = cache.block_size
                 own = cache.alloc_blocks(-(-(plen - mlen) // bs))
                 cache.map_session(slot, shared, own, plen)
-                cache.insert_tail(slot, k_i, v_i, mlen, plen - mlen)
+                with telemetry.span(telemetry.DECODE_KV_INSERT,
+                                    tokens=plen - mlen):
+                    cache.insert_tail(slot, k_i, v_i, mlen, plen - mlen)
                 cache.register_prompt(slot, req["prompt"])
             else:
-                cache.insert(slot, k_i, v_i, plen)
+                with telemetry.span(telemetry.DECODE_KV_INSERT,
+                                    tokens=plen):
+                    cache.insert(slot, k_i, v_i, plen)
             if dcache is not None:
                 dk, dv = draft_kv[req["sid"]]
                 dcache.insert(slot, dk, dv, plen)
@@ -603,27 +649,42 @@ class DecodeEngine:
     # -- iteration: legacy slot-paged path ----------------------------------
     def _iterate(self, cache):
         """One fused decode step over every occupied slot."""
-        tokens = np.zeros((cache.slots,), np.int32)
-        for slot, st in self._active.items():
-            tokens[slot] = st.last
-        logits, cache.k, cache.v = self._step_jit(
-            self._params, tokens, cache.k, cache.v, cache.lengths)
-        logits = np.asarray(logits)
-        self.iterations += 1
-        for slot in list(self._active):
-            st = self._active[slot]
-            cache.lengths[slot] += 1
-            tok = _sampling.sample_token(logits[slot], st.sampling,
-                                         len(st.generated))
-            st.generated.append(tok)
-            st.last = tok
-            self._emit("token", st.sid, len(st.generated) - 1, tok)
-            if (st.eos_id is not None and tok == st.eos_id) \
-                    or len(st.generated) >= st.max_tokens \
-                    or cache.lengths[slot] >= cache.max_seq:
-                self._retire(cache, slot)
+        with telemetry.span(telemetry.DECODE_ITERATE,
+                            active=len(self._active)) as span:
+            with telemetry.span(telemetry.DECODE_BUILD_WINDOW):
+                tokens = np.zeros((cache.slots,), np.int32)
+                for slot, st in self._active.items():
+                    tokens[slot] = st.last
+            self._mark("host")
+            with telemetry.span(telemetry.DECODE_STEP_DISPATCH):
+                logits, cache.k, cache.v = self._step_jit(
+                    self._params, tokens, cache.k, cache.v, cache.lengths)
+            self._mark("step")
+            with telemetry.span(telemetry.DECODE_LOGITS_FETCH):
+                logits = np.asarray(logits)   # device wait + D2H
+            self._mark("fetch")
+            self.iterations += 1
+            with telemetry.span(telemetry.DECODE_SAMPLE):
+                sampled = {
+                    slot: _sampling.sample_token(
+                        logits[slot], st.sampling, len(st.generated))
+                    for slot, st in self._active.items()}
+            with telemetry.span(telemetry.DECODE_EMIT):
+                for slot, tok in sampled.items():
+                    st = self._active[slot]
+                    cache.lengths[slot] += 1
+                    st.generated.append(tok)
+                    st.last = tok
+                    self._emit("token", st.sid, len(st.generated) - 1, tok)
+                    if (st.eos_id is not None and tok == st.eos_id) \
+                            or len(st.generated) >= st.max_tokens \
+                            or cache.lengths[slot] >= cache.max_seq:
+                        self._retire(cache, slot)
+            self.tokens += len(sampled)
+            span.add(tokens=len(sampled))
         metrics_registry.set_gauge("tfos_decode_slot_occupancy",
                                    cache.occupancy)
+        self._mark("host")
 
     # -- iteration: paged path (plain W=1 or speculative W=K) ---------------
     def _iterate_paged(self, cache, dcache):
@@ -642,64 +703,9 @@ class DecodeEngine:
         the cursor is unreachable (masked) until a later correct write
         lands on it.
         """
-        spec = self._spec
-        k_win = spec.spec_window if dcache is not None else 1
-        window = np.zeros((cache.slots, k_win), np.int32)
-        for slot, st in self._active.items():
-            window[slot, 0] = st.last
-        n0 = cache.lengths.copy()
-        if dcache is not None:
-            for j in range(k_win):
-                dlogits, dcache.k, dcache.v = self._dstep_jit(
-                    spec.draft_params, window[:, j], dcache.k, dcache.v,
-                    dcache.lengths)
-                for slot in self._active:
-                    dcache.lengths[slot] += 1
-                if j < k_win - 1:
-                    dlogits = np.asarray(dlogits)
-                    for slot, st in self._active.items():
-                        window[slot, j + 1] = _sampling.sample_token(
-                            dlogits[slot], st.sampling,
-                            len(st.generated) + j)
-        for slot in self._active:
-            cache.ensure_capacity(slot, int(n0[slot]) + k_win)
-        logits, cache.k, cache.v = self._pstep_jit(
-            self._params, window, cache.k, cache.v,
-            cache.block_tables, n0)
-        logits = np.asarray(logits)           # [slots, K, vocab]
-        self.iterations += 1
-        for slot in list(self._active):
-            st = self._active[slot]
-            n = int(n0[slot])
-            base = len(st.generated)
-            # rows past max_seq wrote their token's k/v to the sentinel,
-            # so their logits miss history — never emit from them
-            valid = min(k_win, cache.max_seq - n)
-            emitted = []
-            for j in range(valid):
-                if j > 0 and int(window[slot, j]) != emitted[j - 1]:
-                    break               # draft diverged; later rows stale
-                if j > 0:
-                    self.spec_accepted += 1
-                emitted.append(_sampling.sample_token(
-                    logits[slot, j], st.sampling, base + j))
-            if dcache is not None:
-                self.spec_proposed += k_win - 1
-            done = False
-            for tok in emitted:
-                st.generated.append(tok)
-                st.last = tok
-                cache.lengths[slot] += 1
-                self._emit("token", st.sid, len(st.generated) - 1, tok)
-                if (st.eos_id is not None and tok == st.eos_id) \
-                        or len(st.generated) >= st.max_tokens:
-                    done = True
-                    break
-            if dcache is not None:
-                # roll the draft cursor back onto the accepted prefix
-                dcache.lengths[slot] = cache.lengths[slot]
-            if done or cache.lengths[slot] >= cache.max_seq:
-                self._retire(cache, slot)
+        with telemetry.span(telemetry.DECODE_ITERATE,
+                            active=len(self._active)) as span:
+            span.add(tokens=self._iterate_window(cache, dcache))
         metrics_registry.set_gauge("tfos_decode_slot_occupancy",
                                    cache.occupancy)
         metrics_registry.set_gauge("tfos_decode_blocks_in_use",
@@ -708,6 +714,87 @@ class DecodeEngine:
             metrics_registry.set_gauge(
                 "tfos_decode_spec_accept",
                 round(self.spec_accepted / max(1, self.spec_proposed), 4))
+        self._mark("host")
+
+    def _iterate_window(self, cache, dcache):
+        """``_iterate_paged``'s body in its phases, each under its span
+        and closed by a ``_mark``: build the window (the draft's
+        proposals included), dispatch the step, fetch the logits (device
+        wait + D2H), sample every slot, then emit and retire.  Returns
+        the number of tokens emitted."""
+        spec = self._spec
+        k_win = spec.spec_window if dcache is not None else 1
+        with telemetry.span(telemetry.DECODE_BUILD_WINDOW):
+            window = np.zeros((cache.slots, k_win), np.int32)
+            for slot, st in self._active.items():
+                window[slot, 0] = st.last
+            n0 = cache.lengths.copy()
+            if dcache is not None:
+                for j in range(k_win):
+                    dlogits, dcache.k, dcache.v = self._dstep_jit(
+                        spec.draft_params, window[:, j], dcache.k,
+                        dcache.v, dcache.lengths)
+                    for slot in self._active:
+                        dcache.lengths[slot] += 1
+                    if j < k_win - 1:
+                        dlogits = np.asarray(dlogits)
+                        for slot, st in self._active.items():
+                            window[slot, j + 1] = _sampling.sample_token(
+                                dlogits[slot], st.sampling,
+                                len(st.generated) + j)
+            for slot in self._active:
+                cache.ensure_capacity(slot, int(n0[slot]) + k_win)
+        self._mark("host")
+        with telemetry.span(telemetry.DECODE_STEP_DISPATCH):
+            logits, cache.k, cache.v = self._pstep_jit(
+                self._params, window, cache.k, cache.v,
+                cache.block_tables, n0)
+        self._mark("step")
+        with telemetry.span(telemetry.DECODE_LOGITS_FETCH):
+            logits = np.asarray(logits)           # [slots, K, vocab]
+        self._mark("fetch")
+        self.iterations += 1
+        sampled = {}
+        with telemetry.span(telemetry.DECODE_SAMPLE):
+            for slot, st in self._active.items():
+                base = len(st.generated)
+                # rows past max_seq wrote their token's k/v to the
+                # sentinel, so their logits miss history — never emit
+                # from them
+                valid = min(k_win, cache.max_seq - int(n0[slot]))
+                emitted = []
+                for j in range(valid):
+                    if j > 0 and int(window[slot, j]) != emitted[j - 1]:
+                        break           # draft diverged; later rows stale
+                    if j > 0:
+                        self.spec_accepted += 1
+                    emitted.append(_sampling.sample_token(
+                        logits[slot, j], st.sampling, base + j))
+                if dcache is not None:
+                    self.spec_proposed += k_win - 1
+                sampled[slot] = emitted
+        n_emitted = 0
+        with telemetry.span(telemetry.DECODE_EMIT):
+            for slot, emitted in sampled.items():
+                st = self._active[slot]
+                done = False
+                for tok in emitted:
+                    st.generated.append(tok)
+                    st.last = tok
+                    cache.lengths[slot] += 1
+                    n_emitted += 1
+                    self._emit("token", st.sid, len(st.generated) - 1, tok)
+                    if (st.eos_id is not None and tok == st.eos_id) \
+                            or len(st.generated) >= st.max_tokens:
+                        done = True
+                        break
+                if dcache is not None:
+                    # roll the draft cursor back onto the accepted prefix
+                    dcache.lengths[slot] = cache.lengths[slot]
+                if done or cache.lengths[slot] >= cache.max_seq:
+                    self._retire(cache, slot)
+        self.tokens += n_emitted
+        return n_emitted
 
     def _retire(self, cache, slot):
         st = self._active.pop(slot)

@@ -303,6 +303,20 @@ def canary_arm(route_id, pct):
     return (zlib.crc32(str(route_id).encode()) % 100) < float(pct)
 
 
+def _profile_capture(outq, idx, seq, seconds, out_dir):
+    """The replica's answer to a ``("profile", ...)`` directive, on a
+    thread of its own so the message loop keeps admitting sessions: a
+    ``utils.profiler`` capture of ``seconds`` into ``out_dir``, then the
+    ack.  The same capture trainers take for ``POST /profilez``."""
+    from tensorflowonspark_tpu.utils import profiler
+
+    ok = profiler.start_trace(out_dir)
+    if ok:
+        time.sleep(seconds)
+        ok = profiler.stop_trace()
+    outq.put(("profiled", idx, seq, bool(ok), out_dir))
+
+
 def _make_replica_task(payload_blob, mgr_addr, mgr_authkey):
     """The engine task every replica runs.  A real module-level factory
     (not a heredoc/driver lambda): the closure is cloudpickled into the
@@ -415,6 +429,11 @@ def _make_replica_task(payload_blob, mgr_addr, mgr_authkey):
                     if elastic_cfg:
                         st["elastic"] = dict(el_state)
                     outq.put(("stats", idx, st))
+                elif kind == "profile":
+                    threading.Thread(
+                        target=_profile_capture, name="tfos-profile",
+                        args=(outq, idx) + tuple(msg[1:]),
+                        daemon=True).start()
                 elif kind == "gen":
                     _, sid, blob = msg
                     if engine is None:
@@ -490,6 +509,8 @@ class ReplicaPool:
         self._arm_stats = None       # arm -> {"n", "errors", "ms": [...]}
         self._stats_replies = {}
         self._stats_event = threading.Event()
+        self._profile_acks = {}      # seq -> (ok, out_dir), by _collect
+        self._profile_event = threading.Event()
         self._registered = threading.Event()
         self._job_error = None
         self._stop = threading.Event()
@@ -933,6 +954,9 @@ class ReplicaPool:
             elif kind == "stats":
                 self._stats_replies[msg[1]] = msg[2]
                 self._stats_event.set()
+            elif kind == "profiled":
+                self._profile_acks[msg[2]] = (msg[3], msg[4])
+                self._profile_event.set()
             elif kind in ("init_error", "reload_error"):
                 logger.warning("replica %s reported %s: %s",
                                msg[1], kind, msg[2])
@@ -1089,6 +1113,25 @@ class ReplicaPool:
     def versions(self):
         with self._lock:
             return dict(self._versions)
+
+    def profile(self, seconds, out_dir, replica=0, timeout=60.0):
+        """Have one replica take a profiler capture of ``seconds`` into
+        ``out_dir`` (``utils.profiler``: Python tracer off, the
+        program's ``tfos/*`` spans in it) while it keeps serving.
+        Returns ``out_dir`` once the replica acked; False where capture
+        is unavailable in its build; TimeoutError if it never acks."""
+        seq = time.monotonic_ns()
+        self._inqs[replica].put(
+            ("profile", seq, float(seconds), str(out_dir)))
+        deadline = time.monotonic() + float(seconds) + timeout
+        while seq not in self._profile_acks:
+            if time.monotonic() > deadline:
+                raise TimeoutError(
+                    f"replica {replica} did not ack the profile directive")
+            self._profile_event.wait(0.1)
+            self._profile_event.clear()
+        ok, where = self._profile_acks.pop(seq)
+        return where if ok else False
 
     def stats(self, timeout=10.0):
         """Broadcast a stats request; gather per-replica predictor stats

@@ -424,6 +424,14 @@ class Server:
             ttft_ms=out.get("ttft_ms"), replica=out.get("replica"))
         return out
 
+    def profile(self, seconds, out_dir, replica=0):
+        """A profiler capture of ``seconds`` taken INSIDE one replica
+        (the process that owns the chip) while it serves: device
+        operations under their ``tfos_*`` names and the engine's
+        ``tfos/decode/*`` spans on one clock (docs/serving.md).  Returns
+        the capture directory, or False where capture is unavailable."""
+        return self.pool.profile(seconds, out_dir, replica=replica)
+
     def client(self):
         return Client(self)
 

@@ -146,12 +146,17 @@ class TrainMetrics:
         self.items = 0
         self.step_time = 0.0
         self.infeed_time = 0.0
+        self.ring_wait_time = 0.0
         self._last = None
 
     # -- recording ----------------------------------------------------------
 
-    def infeed_wait(self, seconds):
+    def infeed_wait(self, seconds, ring_wait=0.0):
+        """``seconds`` the feed's consumer spent fetching a chunk, of
+        which ``ring_wait`` with the transport EMPTY (the producer's
+        side of the ring); the rest was reading the chunk out of it."""
         self.infeed_time += seconds
+        self.ring_wait_time += ring_wait
 
     def step(self, items=0, loss=None, grad_norm=None, grad_finite=None):
         """Call once per completed train step with the item count.
@@ -228,6 +233,7 @@ class TrainMetrics:
             "items": self.items,
             "step_time_avg_s": self.step_time / max(self.steps - 1, 1),
             "infeed_wait_s": self.infeed_time,
+            "ring_wait_s": self.ring_wait_time,
             "infeed_stall_frac": (
                 self.infeed_time / self.step_time if self.step_time else 0.0
             ),

@@ -21,6 +21,8 @@ import subprocess
 import sys
 import time
 
+from tensorflowonspark_tpu.utils import telemetry
+
 logger = logging.getLogger(__name__)
 
 
@@ -109,12 +111,27 @@ def _warn_unavailable(err):
 def start_trace(log_dir):
     """Begin an XLA device trace (viewable in TensorBoard's profile tab).
 
+    THE one place the program starts a capture.  The Python tracer is
+    off: it hooks every call of every thread and slowed the feed's
+    consumer thirteenfold (PERF.md, PR23); the program's own spans reach
+    the capture as TraceMe events (``telemetry.span``), which the host
+    tracer records.  Every capture opens with one ``tfos/clock``
+    annotation carrying the wall clock, because the capture's own
+    timestamps count from its start (PERF.md, PR25): that is what puts a
+    feeder's spool and the capture on one timeline
+    (``scripts/trace_merge.py --xplane``).
+
     Returns True when a capture actually started; False when capture is
     unavailable in this build (warned once, never raises)."""
     try:
         import jax
 
-        jax.profiler.start_trace(log_dir)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        with telemetry.span(telemetry.CLOCK, time_ns=time.time_ns()):
+            pass
         return True
     except Exception as e:  # noqa: BLE001 - capture is best-effort
         _warn_unavailable(e)

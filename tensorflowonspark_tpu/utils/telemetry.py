@@ -15,8 +15,15 @@ Design constraints (all load-bearing):
 
 - **Zero-dep / stdlib-only** — imported by engine executors, feeder
   tasks, forked trainers and the driver; must never pull jax/numpy.
-- **Opt-in via env** — enabled iff ``TFOS_TELEMETRY_DIR`` is set; when
-  unset every call is a cached no-op (no files, no measurable cost).
+- **Two sinks, one call** — ``span`` / ``record_span`` write to the
+  JSONL spool iff ``TFOS_TELEMETRY_DIR`` is set, and to a
+  ``jax.profiler.TraceAnnotation`` iff jax is ALREADY imported in this
+  process (``sys.modules``; this module never imports it).  Outside a
+  profiler session an annotation is a no-op in the runtime, so no
+  caller needs to know whether a capture is running.  With both sinks
+  off every call is a cached no-op: no clock read, no files.
+- **Never per record, never per token** — spans sit at chunk, batch,
+  iteration and admission boundaries (docs/telemetry.md).
 - **Monotonic durations** — ``dur_ms`` comes from ``perf_counter``
   deltas; ``ts`` is wall-clock (``time.time``) only to *anchor* spans
   on a shared timeline across processes of one host/run.
@@ -59,6 +66,7 @@ import logging
 import os
 import re
 import socket
+import sys
 import threading
 import time
 
@@ -98,6 +106,31 @@ DEPLOY_BLESS = "deploy/bless"         # checkpoint passed gate, manifest out
 DEPLOY_CANARY = "deploy/canary"       # canary arm opened on a candidate
 DEPLOY_PROMOTE = "deploy/promote"     # candidate promoted fleet-wide
 DEPLOY_ROLLBACK = "deploy/rollback"   # candidate rejected, blessed re-pinned
+
+# -- hot-path spans: ``tfos/<layer>/<phase>`` (docs/telemetry.md) ------------
+# The names benchmark/lib/program_trace.py and scripts/trace_merge.py read.
+CLOCK = "tfos/clock"                        # opens a capture: time_ns arg
+FEED_RING_WAIT = "tfos/feed/ring_wait"      # DataFeed: until a chunk is there
+FEED_RING_READ = "tfos/feed/ring_read"      # copy out of the ring + decode
+FEED_TO_COLUMNS = "tfos/feed/to_columns"    # next_batch(_columns) assembly
+FEED_COLLATE = "tfos/feed/collate"          # the caller's collate
+FEED_H2D = "tfos/feed/h2d"                  # dispatch of the transfer
+FEED_STAGE_FULL = "tfos/feed/stage_full"    # prefetch worker blocked in put
+FEED_NEXT = "tfos/feed/next"                # consumer blocked in get
+FEED_SYNC = "tfos/feed/sync"                # synchronized(): flag all-gather
+FEEDER_CHUNK = "tfos/feeder/chunk"          # feeder task, one per chunk
+FEEDER_HANDOFF = "tfos/feeder/handoff"      # feeder waits for an empty ring
+DECODE_IDLE = "tfos/decode/idle"
+DECODE_ADMIT_SPAN = "tfos/decode/admit"
+DECODE_TRIE_MATCH = "tfos/decode/trie_match"
+DECODE_PREFILL = "tfos/decode/prefill"
+DECODE_KV_INSERT = "tfos/decode/kv_insert"
+DECODE_ITERATE = "tfos/decode/iterate"
+DECODE_BUILD_WINDOW = "tfos/decode/build_window"
+DECODE_STEP_DISPATCH = "tfos/decode/step_dispatch"
+DECODE_LOGITS_FETCH = "tfos/decode/logits_fetch"
+DECODE_SAMPLE = "tfos/decode/sample"
+DECODE_EMIT = "tfos/decode/emit"
 
 
 # -- causal trace context (W3C-traceparent-shaped) -------------------------
@@ -358,6 +391,32 @@ def configure(node_id=None, role=None, spool=None):
     return _get()
 
 
+# The profiler sink: jax.profiler.TraceAnnotation, found in sys.modules
+# and never imported.  Cached once found (a process does not un-import).
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        # a jax that is only half imported has no ``profiler`` yet: None
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        _ANNOTATION = getattr(prof, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
+def _scalars(attrs):
+    """The attrs an annotation can carry as the event's stats."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (int, float, str))}
+
+
+def active():
+    """True when a span would reach a sink (spool or profiler): what a
+    call site asks before it reads a clock of its own."""
+    return _get() is not None or _annotation() is not None
+
+
 class _NullSpan:
     """Shared no-op span: the disabled path allocates nothing."""
 
@@ -386,15 +445,23 @@ class Span:
     record is byte-identical to the pre-trace schema (attrs
     untouched)."""
 
-    __slots__ = ("_rec", "name", "attrs", "_ts", "_t0", "_ctx")
+    __slots__ = ("_rec", "name", "attrs", "_ts", "_t0", "_ctx",
+                 "_ann_cls", "_ann")
 
-    def __init__(self, rec, name, attrs, ctx=None):
-        self._rec = rec
+    def __init__(self, rec, name, attrs, ctx=None, ann=None):
+        self._rec = rec       # the spool, or None
         self.name = name
         self.attrs = attrs
         self._ctx = ctx
+        self._ann_cls = ann   # the TraceAnnotation class, or None
+        self._ann = None      # the live annotation, inside the body
 
     def __enter__(self):
+        if self._ann_cls is not None:
+            self._ann = self._ann_cls(self.name, **_scalars(self.attrs))
+            self._ann.__enter__()
+        if self._rec is None:
+            return self       # profiler sink only: it has its own clock
         self._ts = time.time()
         self._t0 = time.perf_counter()
         if self._ctx is None:
@@ -407,6 +474,8 @@ class Span:
 
     def add(self, **attrs):
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**_scalars(attrs))
         return self
 
     @property
@@ -415,6 +484,10 @@ class Span:
         return self._ctx
 
     def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self._rec is None:
+            return False
         dur_ms = (time.perf_counter() - self._t0) * 1000.0
         if self._ctx is not None:
             _pop(self._ctx)
@@ -429,11 +502,13 @@ class Span:
 
 def span(name, **attrs):
     """``with telemetry.span("phase/name", k=v) as s: ...`` — records a
-    span on exit (exceptions annotate ``attrs.error`` and propagate)."""
-    rec = _get()
-    if rec is None:
+    span on exit (exceptions annotate ``attrs.error`` and propagate).
+    One call, two sinks: the spool and the profiler (module docstring);
+    scalar attrs travel with the annotation as the event's stats."""
+    rec, ann = _get(), _annotation()
+    if rec is None and ann is None:
         return _NULL
-    return Span(rec, name, attrs)
+    return Span(rec, name, attrs, ann=ann)
 
 
 def trace_span(name, header=None, **attrs):
@@ -442,12 +517,12 @@ def trace_span(name, header=None, **attrs):
     TraceContext) when given, else the thread's active context, else
     mints a fresh root.  Returns the no-op span when telemetry is
     disabled (the overhead contract)."""
-    rec = _get()
+    rec, ann = _get(), _annotation()
     if rec is None:
-        return _NULL
+        return _NULL if ann is None else Span(None, name, attrs, ann=ann)
     parent = TraceContext.from_header(header) if header else current()
     ctx = parent.child() if parent is not None else TraceContext()
-    return Span(rec, name, attrs, ctx=ctx)
+    return Span(rec, name, attrs, ctx=ctx, ann=ann)
 
 
 def event(name, **attrs):
@@ -466,7 +541,12 @@ def record_span(name, dur_s, **attrs):
     """Record an already-measured duration as a span whose start is
     back-dated by ``dur_s`` — for call sites that time themselves (the
     feed wait path, TrainMetrics.step) so telemetry and the counters
-    report the SAME number."""
+    report the SAME number.  The profiler cannot back-date: there the
+    span is an instant annotation at its END carrying ``dur_ms``."""
+    ann = _annotation()
+    if ann is not None:
+        with ann(name, dur_ms=dur_s * 1000.0, **_scalars(attrs)):
+            pass
     rec = _get()
     if rec is not None:
         ctx = current()

@@ -359,13 +359,17 @@ def test_data_stage_spans_and_trace_merge(tmp_path, monkeypatch, mgr):
     assert proc.returncode == 0, proc.stdout + proc.stderr
     # the per-stage stall table (ISSUE satellite: `-- data --` section)
     assert "-- data (data/stage spans) --" in proc.stdout
-    for stage in ("arrays", "map", "batch", "prefetch", "fed_consumer"):
+    # the feed's consumer is the last row: its tfos/feed/to_columns span
+    for stage in ("arrays", "map", "batch", "prefetch", "to_columns"):
         assert stage in proc.stdout, proc.stdout
     trace = json.loads((tdir / "trace.json").read_text())
     spans = [e for e in trace["traceEvents"]
              if e.get("name") == "data/stage"]
     stages = {e["args"]["stage"] for e in spans}
-    assert {"arrays", "map", "batch", "prefetch", "fed_consumer"} <= stages
+    assert {"arrays", "map", "batch", "prefetch"} <= stages
+    fed = [e for e in trace["traceEvents"]
+           if e.get("name") == "tfos/feed/to_columns"]
+    assert fed and all(e["args"]["wait_ms"] >= 0 for e in fed)
     # prefetch accounts its block time as WAIT (it only stalls, never
     # computes), so downstream stall attribution stays truthful
     pre = [e for e in spans if e["args"]["stage"] == "prefetch"]

@@ -376,6 +376,37 @@ def _run_sessions(params, spec, jobs, timeout=300):
     return {sid: ev["done"] for sid, ev in events.items()}, stats, eng
 
 
+@pytest.mark.parametrize("paged", [True, False])
+def test_engine_counts_tokens_and_where_its_time_went(paged):
+    """``stats()`` always carries ``tokens`` (emitted by decode
+    iterations: every token but each session's first, which its prefill
+    produced), ``prompt_tokens``, and ``phase_s``, whose phases are
+    chained clock reads and so sum to the engine thread's wall time."""
+    cfg = _cfg()
+    params = _params(cfg)
+    spec = D.DecodeSpec(cfg, slots=4, max_tokens=8, paged=paged)
+    jobs = [(i, [2 + i, 3, 5, 7][: 2 + i % 3], {"max_tokens": 4 + i})
+            for i in range(6)]
+    t0 = time.perf_counter()
+    out, stats, eng = _run_sessions(params, spec, jobs)
+    wall = time.perf_counter() - t0
+    emitted = sum(len(toks) for toks in out.values())
+    assert emitted == sum(4 + i for i in range(6))
+    assert stats["tokens"] == emitted - len(jobs)
+    assert stats["prompt_tokens"] == sum(len(p) for _s, p, _k in jobs)
+    phases = stats["phase_s"]
+    assert set(phases) == {"idle", "admit", "step", "fetch", "host"}
+    assert all(v >= 0 for v in phases.values()) and phases["admit"] > 0
+    # the engine thread's own wall time: from its first mark to its
+    # last (the stats were taken while it ran; it stopped since)
+    lived = eng._t_mark - eng._t_started
+    assert lived <= wall + 1.0
+    assert sum(eng._phase_s.values()) == pytest.approx(lived, rel=0.05)
+    # the snapshot was within one 20 ms idle wait of the final tally
+    assert sum(phases.values()) == pytest.approx(
+        sum(eng._phase_s.values()), abs=0.5)
+
+
 def test_parity_paged_equals_slot_equals_oracle_with_prefix_hits():
     """Gate (a): block-paged greedy decode — including trie-matched
     admissions that skip the shared prefill — is token-identical to the
@@ -513,6 +544,23 @@ def test_server_generate_and_http_roundtrip(tmp_path):
         s2 = srv.generate(prompt, max_tokens=6, timeout=300,
                           temperature=0.9, top_k=8, seed=5)
         assert s1["tokens"] == s2["tokens"]
+        # the replica answers the profile directive while it serves: a
+        # capture taken INSIDE the process that owns the device
+        cap = str(tmp_path / "capture")
+        got = {}
+        th = threading.Thread(target=lambda: got.update(
+            out=srv.generate(prompt, max_tokens=6, timeout=300)))
+        th.start()
+        assert srv.profile(0.3, cap) == cap
+        th.join()
+        assert got["out"]["tokens"] == ref
+        assert [f for _r, _d, fs in os.walk(cap) for f in fs
+                if f.endswith(".xplane.pb")]
+        st = next(iter(srv.pool.stats().values()))["decode"]
+        assert st["tokens"] >= 3 * (len(ref) - 1)
+        assert st["prompt_tokens"] >= 3 * len(prompt)
+        assert set(st["phase_s"]) == {"idle", "admit", "step", "fetch",
+                                      "host"}
         httpd = S.serve_http(srv, port=0, block=False)
         try:
             host, port = httpd.server_address

@@ -76,8 +76,8 @@ def test_terminate_waits_for_slow_producer():
         feed = DataFeed(mgr)
         orig_get = feed._ring.get
 
-        def spy_get(timeout_ms=-1):
-            v = orig_get(timeout_ms)
+        def spy_get(timeout_ms=-1, available=None):
+            v = orig_get(timeout_ms, available)
             drained.append(v)
             return v
 

@@ -45,7 +45,7 @@ def mnist_main(args, ctx):
     step_fn = jax.jit(mnist.make_train_step(opt))
 
     # metrics feed both report() and (when TFOS_TELEMETRY_DIR is set)
-    # the train/step + feed/wait spans that trace_merge aggregates
+    # the train/step + tfos/feed/ring_wait spans that trace_merge aggregates
     metrics = TrainMetrics()
     feed = ctx.get_data_feed(train_mode=True, metrics=metrics)
     losses = []
@@ -142,7 +142,7 @@ def test_mnist_spark_mode_e2e(tmp_path, monkeypatch):
             (telemetry_dir / "trace.json").read_text())
         names = {e["name"] for e in trace["traceEvents"]}
         assert {"cluster/start", "node/boot", "train/step",
-                "feed/wait", "checkpoint/export"} <= names
+                "tfos/feed/ring_wait", "checkpoint/export"} <= names
         # per-node step percentiles + infeed-stall fraction made it into
         # the summary for both training nodes (master_node="chief")
         assert "chief-0" in proc.stdout and "worker-0" in proc.stdout

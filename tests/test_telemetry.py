@@ -69,7 +69,121 @@ def _all_records(root):
 
 # --- recorder core ----------------------------------------------------------
 
-def test_disabled_is_noop(tmp_path):
+@pytest.fixture
+def no_profiler_sink(monkeypatch):
+    """The process of these tests has jax imported, so the profiler sink
+    is on by default; a test of "both sinks off" turns it off."""
+    monkeypatch.setattr(telemetry, "_annotation", lambda: None)
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what one
+    span call hands the profiler."""
+
+    seen = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs, self.state = name, dict(kwargs), "new"
+        _FakeAnnotation.seen.append(self)
+
+    def __enter__(self):
+        self.state = "open"
+        return self
+
+    def __exit__(self, *exc):
+        self.state = "closed"
+        return False
+
+    def set_metadata(self, **kwargs):
+        self.kwargs.update(kwargs)
+
+
+@pytest.fixture
+def fake_profiler(monkeypatch):
+    import types
+
+    _FakeAnnotation.seen = []
+    fake_jax = types.SimpleNamespace(profiler=types.SimpleNamespace(
+        TraceAnnotation=_FakeAnnotation))
+    monkeypatch.setitem(sys.modules, "jax", fake_jax)
+    monkeypatch.setattr(telemetry, "_ANNOTATION", None)
+    return _FakeAnnotation.seen
+
+
+def test_span_reaches_the_profiler_with_its_scalar_args(fake_profiler):
+    """One call, the profiler as a sink: spool off, jax in sys.modules."""
+    assert not telemetry.enabled() and telemetry.active()
+    with telemetry.span("tfos/feed/ring_read", records=3, note="x",
+                        blob=[1, 2]) as sp:
+        assert fake_profiler[-1].state == "open"
+        sp.add(bytes=4096, more={"not": "scalar"})
+    ann = fake_profiler[-1]
+    assert ann.name == "tfos/feed/ring_read" and ann.state == "closed"
+    # scalars travel as the event's stats; lists and dicts do not
+    assert ann.kwargs == {"records": 3, "note": "x", "bytes": 4096}
+    telemetry.record_span("tfos/feeder/chunk", 0.25, records=7)
+    assert fake_profiler[-1].kwargs == {"dur_ms": 250.0, "records": 7}
+    assert fake_profiler[-1].state == "closed"
+
+
+def test_span_writes_both_sinks_from_one_call(fake_profiler, tmp_path):
+    os.environ[telemetry.DIR_ENV] = str(tmp_path)
+    telemetry.configure(node_id="t-0", role="test")
+    with telemetry.span("tfos/feed/h2d", n=1):
+        pass
+    telemetry.flush()
+    assert [a.name for a in fake_profiler] == ["tfos/feed/h2d"]
+    recs = _records(telemetry.sink_path())
+    assert [(r["name"], r["attrs"]) for r in recs] \
+        == [("tfos/feed/h2d", {"n": 1})]
+
+
+def test_telemetry_never_imports_jax():
+    """In a process without jax the span call imports nothing: the
+    profiler sink is found in sys.modules or not at all."""
+    code = (
+        "import sys\n"
+        "from tensorflowonspark_tpu.utils import telemetry\n"
+        "assert 'jax' not in sys.modules\n"
+        "s = telemetry.span('tfos/x', a=1)\n"
+        "assert s is telemetry._NULL and not telemetry.active()\n"
+        "with s: pass\n"
+        "telemetry.record_span('tfos/y', 0.1)\n"
+        "assert 'jax' not in sys.modules and 'numpy' not in sys.modules\n"
+        "print('ok')\n")
+    env = {k: v for k, v in os.environ.items() if k not in _ENV_KEYS}
+    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0 and proc.stdout.strip() == "ok", proc.stderr
+
+
+def test_both_sinks_off_reads_no_clock(no_profiler_sink, monkeypatch):
+    def boom(*_a):
+        raise AssertionError("a clock was read with both sinks off")
+
+    monkeypatch.setattr(telemetry.time, "time", boom)
+    monkeypatch.setattr(telemetry.time, "perf_counter", boom)
+    monkeypatch.setattr(telemetry.time, "time_ns", boom, raising=False)
+    assert not telemetry.active()
+    with telemetry.span("tfos/feed/next", n=1) as sp:
+        sp.add(k=2)
+    telemetry.record_span("tfos/feeder/chunk", 0.1)
+    assert telemetry.span("x") is telemetry._NULL
+
+
+def test_trace_merge_names_follow_telemetry():
+    tm = _load_trace_merge()
+    assert tm.FEED_FETCH_SPANS == (telemetry.FEED_RING_WAIT,
+                                   telemetry.FEED_RING_READ)
+    assert tm.FEED_TO_COLUMNS == telemetry.FEED_TO_COLUMNS
+    assert tm.CLOCK_SPAN == telemetry.CLOCK
+    names = [v for k, v in vars(telemetry).items()
+             if k.isupper() and isinstance(v, str) and v.startswith("tfos/")]
+    assert len(names) == len(set(names)) >= 20
+    assert all(len(n.split("/")) == 3 for n in names if n != telemetry.CLOCK)
+
+
+def test_disabled_is_noop(tmp_path, no_profiler_sink):
     assert not telemetry.enabled()
     assert telemetry.sink_path() is None
     assert telemetry.span("x") is telemetry._NULL
@@ -256,7 +370,7 @@ def test_trace_inherited_across_spawn(tmp_path):
     assert anchor["attrs"]["span_id"] == ctx.span_id
 
 
-def test_trace_disabled_is_noop(tmp_path):
+def test_trace_disabled_is_noop(tmp_path, no_profiler_sink):
     assert not telemetry.enabled()
     assert telemetry.trace_root("cluster/run") is None
     assert telemetry.trace_span("serve/request") is telemetry._NULL
@@ -407,7 +521,8 @@ def _synthesize(tmp_path):
             telemetry.record_span(
                 "train/step", 0.010 + 0.001 * i, items=32,
                 flops_per_item=2.0e9, peak_flops=197e12)
-            telemetry.record_span("feed/wait", 0.002, eof=False)
+            telemetry.record_span(telemetry.FEED_RING_WAIT, 0.002,
+                                  eof=False)
         telemetry.event("node/tb_spawn", port=6006)
         telemetry.flush()
 
@@ -535,3 +650,50 @@ def test_trace_merge_cli(tmp_path):
         capture_output=True, text=True, env=env, timeout=60)
     assert proc.returncode == 1
     assert "no telemetry records" in proc.stderr
+
+
+def test_trace_merge_lands_a_capture_on_the_spools_clock(tmp_path):
+    """``--xplane``: a capture's timestamps count from its start; the
+    ``tfos/clock`` annotation that opens it carries the wall clock, so
+    the SAME span, written to both sinks by one call, lands at the same
+    place on the merged timeline from either source."""
+    import jax.numpy as jnp
+
+    from tensorflowonspark_tpu.utils import profiler
+
+    tel, cap = tmp_path / "tel", tmp_path / "cap"
+    os.environ[telemetry.DIR_ENV] = str(tel)
+    telemetry.configure(node_id="trainer-0", role="worker")
+    assert profiler.start_trace(str(cap))
+    try:
+        with telemetry.span(telemetry.FEED_RING_READ, records=4):
+            jnp.ones(8).block_until_ready()
+            time.sleep(0.02)
+    finally:
+        assert profiler.stop_trace()
+    telemetry.flush()
+    out = tmp_path / "trace.json"
+    proc = subprocess.run(
+        [sys.executable, TRACE_MERGE, str(tel), "--xplane", str(cap),
+         "--out", str(out)], capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    events = [e for e in json.loads(out.read_text())["traceEvents"]
+              if e.get("name") == telemetry.FEED_RING_READ]
+    spool = [e for e in events if e["cat"] == "worker"]
+    capture = [e for e in events if e["cat"] == "capture"]
+    assert len(spool) == 1 and len(capture) == 1
+    assert capture[0]["args"] == {"records": 4}
+    assert abs(spool[0]["ts"] - capture[0]["ts"]) < 5e3      # microseconds
+    assert abs(spool[0]["dur"] - capture[0]["dur"]) < 5e3
+    # a capture that no tfos/clock opens cannot be placed: say so
+    bare = tmp_path / "bare"
+    import jax
+
+    jax.profiler.start_trace(str(bare))
+    jax.profiler.stop_trace()
+    proc = subprocess.run(
+        [sys.executable, TRACE_MERGE, str(tel), "--xplane", str(bare)],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode == 1 and "tfos/clock" in proc.stderr
