@@ -38,7 +38,7 @@ def _feeder_main(ring_name, mgr_addr, authkey_hex, total_records, image,
         # (inherited through the spawn env)
         telemetry.configure(node_id=f"feeder-{os.getpid()}", role="feeder")
     if columnar:
-        encode = tfnode._make_chunk_encoder()
+        encode = tfnode._ChunkEncoder()
     else:
         def encode(chunk):
             return chunk

@@ -6,8 +6,10 @@
  * per-column buffers runs in compiled code, not the Python interpreter.
  *
  * Exposed as module `_tfos_marshal`:
- *   rows_to_columns(rows, spec) -> tuple of numpy arrays
+ *   rows_to_columns(rows, spec, out=None) -> tuple of numpy arrays
  *     rows: sequence of row tuples/lists (all the same arity)
+ *     out: the arrays to fill, one per column and of that column's size
+ *       (e.g. views of the feed ring), instead of fresh ones
  *     spec: sequence of (dtype_char, width) per column:
  *       '?' bool, 'i' int32, 'l' int64, 'f' float32, 'd' float64
  *       width 0 -> scalar column (result shape [n]);
@@ -17,9 +19,9 @@
  *     row) or 2-D (python list per row) — mirroring tensors2batch's
  *     "size>1 becomes a Seq" rule.
  *
- * Arrays are allocated by calling back into numpy (np.empty) and filled
- * through the buffer protocol, so no numpy C headers are needed at
- * build time.
+ * Arrays are allocated by calling back into numpy (np.empty) unless the
+ * caller brought them, and filled through the buffer protocol, so no
+ * numpy C headers are needed at build time.
  */
 
 #define PY_SSIZE_T_CLEAN
@@ -46,6 +48,14 @@ static PyObject *make_array(Py_ssize_t n, Py_ssize_t width, char code) {
   arr = PyObject_Call(np_empty, args, kw);
   Py_DECREF(args);
   return arr;
+}
+
+static Py_ssize_t item_size(char code) {
+  switch (code) {
+    case '?': return 1;
+    case 'i': case 'f': return 4;
+    default: return 8; /* 'l', 'd'; fill_value refuses the rest */
+  }
 }
 
 static int fill_value(char code, char *dst, Py_ssize_t idx, PyObject *v) {
@@ -101,8 +111,9 @@ static int fill_value(char code, char *dst, Py_ssize_t idx, PyObject *v) {
 }
 
 static PyObject *rows_to_columns(PyObject *self, PyObject *args) {
-  PyObject *rows_obj, *spec_obj;
-  if (!PyArg_ParseTuple(args, "OO", &rows_obj, &spec_obj)) return NULL;
+  PyObject *rows_obj, *spec_obj, *out_obj = Py_None;
+  if (!PyArg_ParseTuple(args, "OO|O", &rows_obj, &spec_obj, &out_obj))
+    return NULL;
 
   PyObject *rows = PySequence_Fast(rows_obj, "rows must be a sequence");
   if (!rows) return NULL;
@@ -111,6 +122,16 @@ static PyObject *rows_to_columns(PyObject *self, PyObject *args) {
 
   Py_ssize_t n = PySequence_Fast_GET_SIZE(rows);
   Py_ssize_t ncols = PySequence_Fast_GET_SIZE(spec);
+  PyObject *given = NULL; /* the caller's arrays, when it brought them */
+  if (out_obj != Py_None) {
+    given = PySequence_Fast(out_obj, "out must be a sequence");
+    if (given && PySequence_Fast_GET_SIZE(given) != ncols) {
+      PyErr_Format(PyExc_ValueError, "out has %zd arrays, spec has %zd columns",
+                   PySequence_Fast_GET_SIZE(given), ncols);
+      Py_CLEAR(given);
+    }
+    if (!given) { Py_DECREF(rows); Py_DECREF(spec); return NULL; }
+  }
 
   PyObject *out = PyTuple_New(ncols);
   Py_buffer *bufs = PyMem_Calloc(ncols, sizeof(Py_buffer));
@@ -125,10 +146,24 @@ static PyObject *rows_to_columns(PyObject *self, PyObject *args) {
     if (!PyArg_ParseTuple(entry, "sn", &code_s, &w)) { ok = 0; break; }
     codes[c] = code_s[0];
     widths[c] = w;
-    PyObject *arr = make_array(n, w, codes[c]);
+    PyObject *arr;
+    if (given) {
+      arr = PySequence_Fast_GET_ITEM(given, c);
+      Py_INCREF(arr);
+    } else {
+      arr = make_array(n, w, codes[c]);
+    }
     if (!arr) { ok = 0; break; }
     PyTuple_SET_ITEM(out, c, arr); /* steals ref */
     if (PyObject_GetBuffer(arr, &bufs[c], PyBUF_WRITABLE | PyBUF_C_CONTIGUOUS) < 0) {
+      ok = 0;
+      break;
+    }
+    Py_ssize_t want = n * (w > 0 ? w : 1) * item_size(codes[c]);
+    if (bufs[c].len != want) {
+      PyErr_Format(PyExc_ValueError,
+                   "column %zd: out holds %zd bytes, %zd rows of spec ('%c', "
+                   "%zd) need %zd", c, bufs[c].len, n, codes[c], w, want);
       ok = 0;
       break;
     }
@@ -181,6 +216,7 @@ static PyObject *rows_to_columns(PyObject *self, PyObject *args) {
   PyMem_Free(widths);
   Py_DECREF(rows);
   Py_DECREF(spec);
+  Py_XDECREF(given);
   if (!ok) {
     Py_XDECREF(out);
     return NULL;
@@ -358,7 +394,7 @@ static PyObject *columns_to_rows(PyObject *self, PyObject *args) {
 
 static PyMethodDef methods[] = {
     {"rows_to_columns", rows_to_columns, METH_VARARGS,
-     "rows_to_columns(rows, spec) -> tuple of numpy arrays"},
+     "rows_to_columns(rows, spec, out=None) -> tuple of numpy arrays"},
     {"columns_to_rows", columns_to_rows, METH_VARARGS,
      "columns_to_rows(columns) -> list of row tuples"},
     {NULL, NULL, 0, NULL},

@@ -56,7 +56,7 @@ def _f784_feeder_main(ring_name, mgr_addr, authkey_hex, total, width):
 
     if telemetry.enabled():
         telemetry.configure(node_id=f"feeder-{os.getpid()}", role="feeder")
-    encode = tfnode._make_chunk_encoder()
+    encode = tfnode._ChunkEncoder()
     mgr = tfmanager.connect(tuple(mgr_addr), bytes.fromhex(authkey_hex))
     ring = shmq.ShmQueue(ring_name, create=False, producer=True)
     rng = np.random.default_rng(0)

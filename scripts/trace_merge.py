@@ -51,6 +51,9 @@ SCHEMA_KEYS = ("ts", "node_id", "role", "kind", "name", "dur_ms", "attrs")
 # tests/test_telemetry.py; this script imports nothing of the package)
 FEED_FETCH_SPANS = ("tfos/feed/ring_wait", "tfos/feed/ring_read")
 FEED_TO_COLUMNS = "tfos/feed/to_columns"
+FEEDER_CHUNK = "tfos/feeder/chunk"
+FEEDER_HANDOFF = "tfos/feeder/handoff"
+FEEDER_PARTS = ("source_ms", "room_wait_ms", "write_ms", "encode_ms")
 CLOCK_SPAN = "tfos/clock"
 CAPTURE_SPAN_PREFIXES = ("tfos/", "bench/")
 
@@ -223,6 +226,7 @@ def summarize(pairs, skipped=0):
     serve = {"totals_ms": [], "queue_ms": [], "device_ms": [],
              "batches": [], "shed": 0}
     data_stages = {}
+    feeders = {}
     actors = {"msgs": {}, "respawns": 0, "lost": 0, "redispatched": 0}
     for rec in recs:
         node = per_node.setdefault(
@@ -272,6 +276,23 @@ def summarize(pairs, skipped=0):
                 else float(rec["dur_ms"]))
             st["wait_ms"].append(wait_ms)
             st["records"] += int(attrs.get("records") or 0)
+        elif rec["name"] in (FEEDER_CHUNK, FEEDER_HANDOFF):
+            # the producer's side of the ring, one row per node: where a
+            # frame's time goes, and how many were encoded in place
+            fd = feeders.setdefault(rec["node_id"], {
+                "frames": 0, "inplace": 0, "records": 0, "busy_ms": 0.0,
+                "handoffs": 0, "handoff_ms": 0.0,
+                **{k: 0.0 for k in FEEDER_PARTS}})
+            fd["busy_ms"] += float(rec["dur_ms"])
+            if rec["name"] == FEEDER_HANDOFF:
+                fd["handoffs"] += 1
+                fd["handoff_ms"] += float(rec["dur_ms"])
+            else:
+                fd["frames"] += 1
+                fd["inplace"] += int(attrs.get("inplace") or 0)
+                fd["records"] += int(attrs.get("records") or 0)
+                for k in FEEDER_PARTS:
+                    fd[k] += float(attrs.get(k) or 0.0)
         elif rec["name"] == "actor/message":
             key = (str(attrs.get("group") or "?"),
                    str(attrs.get("kind") or "?"))
@@ -397,6 +418,36 @@ def summarize(pairs, skipped=0):
                 f"{d['self_p50_ms']:>9.2f} {d['self_p95_ms']:>9.2f} "
                 f"{d['wait_p50_ms']:>9.2f} {d['wait_p95_ms']:>9.2f} "
                 f"{d['stall_frac']:>6.2f}")
+
+    if feeders:
+        # tfos/feeder/chunk + handoff (node.train): means per frame, the
+        # hand-over per partition, and the rate the feeder task sustains
+        # while it runs (records / time inside these spans)
+        stats["feeder"] = {}
+        lines.append("")
+        lines.append("-- feeder (tfos/feeder/chunk, handoff) --")
+        lines.append(
+            f"{'node':<16} {'frames':>7} {'inplace':>8} {'records':>9} "
+            f"{'source':>8} {'room_wait':>9} {'write':>8} {'encode':>8} "
+            f"{'handoff':>8} {'rec/s':>8}")
+        for name in sorted(feeders):
+            fd = feeders[name]
+            n = max(fd["frames"], 1)
+            d = stats["feeder"][name] = {
+                "frames": fd["frames"], "inplace": fd["inplace"],
+                "copied": fd["frames"] - fd["inplace"],
+                "records": fd["records"],
+                **{k: fd[k] / n for k in FEEDER_PARTS},
+                "handoff_ms": fd["handoff_ms"] / max(fd["handoffs"], 1),
+                "records_per_s": (fd["records"] / fd["busy_ms"] * 1e3
+                                  if fd["busy_ms"] else 0.0),
+            }
+            lines.append(
+                f"{name:<16} {d['frames']:>7} {d['inplace']:>8} "
+                f"{d['records']:>9} {d['source_ms']:>8.2f} "
+                f"{d['room_wait_ms']:>9.2f} {d['write_ms']:>8.2f} "
+                f"{d['encode_ms']:>8.2f} {d['handoff_ms']:>8.2f} "
+                f"{d['records_per_s']:>8.0f}")
 
     lines.append("")
     lines.append("-- per-node train steps --")

@@ -509,7 +509,7 @@ class DataFeed:
         Ring path: "drained" is decided by the producer flock, not a
         timeout — an empty ring only ends the drain once no feeder holds
         the producer lock, so a slow producer mid-partition cannot strand
-        data (and its _await_consumption) behind a 5s guess.
+        data (and its hand-over wait) behind a 5s guess.
         """
         logger.info("terminate() invoked")
         self._stop_requested = True

@@ -21,6 +21,7 @@ move data in **chunks** (lists of records), not per-record.
 
 from __future__ import annotations
 
+import itertools
 import json
 import logging
 import multiprocessing
@@ -38,6 +39,7 @@ from tensorflowonspark_tpu import marker, rendezvous, tpu_info
 from tensorflowonspark_tpu.utils import (
     faults,
     get_ip_address,
+    metrics_registry,
     read_executor_id,
     reap_child,
     telemetry,
@@ -729,6 +731,30 @@ def _raise_if_consumer_lost(mgr, equeue):
             f"TFOS_HEARTBEAT_STALE)")
 
 
+class _Terminating(Exception):
+    """The consumer asked for termination while the feeder waited for
+    room in the ring."""
+
+
+def _await_ring_consumption(mgr, ring, pos, feed_timeout):
+    """Wait until the consumer has taken everything up to ``pos``, the
+    ring's position behind the feeder's last byte (what its last commit
+    returned) — in the ring's own back-off, not in a poll, and whatever a
+    later producer has written behind it.  The error queue and the
+    consumer's heartbeat are checked once a second."""
+    equeue = mgr.get_queue("error")
+    deadline = time.monotonic() + feed_timeout
+    while True:
+        try:
+            return ring.wait_consumed(pos, timeout_ms=1000)
+        except TimeoutError:
+            _raise_if_consumer_lost(mgr, equeue)
+            if time.monotonic() > deadline:
+                raise TimeoutError(
+                    "timed out waiting for consumption of partition"
+                ) from None
+
+
 def _await_consumption(mgr, waiter, feed_timeout, poll=1.0):
     """Wait for the consumer to drain what we queued, polling the error
     queue and the consumer heartbeat (parity: TFSparkNode.py:484-497).
@@ -743,7 +769,7 @@ def _await_consumption(mgr, waiter, feed_timeout, poll=1.0):
             raise TimeoutError("timed out waiting for consumption of partition")
 
 
-def _make_chunk_encoder():
+class _ChunkEncoder:
     """Per-partition chunk encoder: all-numeric row chunks go columnar
     (marker.ColumnChunk via marshal.rows_to_columns — ~10x cheaper to
     serialize, ~2x smaller on the wire than pickled row lists); chunks
@@ -753,63 +779,113 @@ def _make_chunk_encoder():
     H*W*C columns — reshape VIEWS, no copy — with the original trailing
     shape carried in ``ColumnChunk.shapes`` so the consumer can slice
     dense ``[n, H, W, C]`` batches with zero per-record python work
-    (``DataFeed.next_batch_columns``)."""
-    if os.environ.get("TFOS_COLUMNAR_FEED", "1") == "0":
-        return lambda chunk: chunk
-    import numpy as np
+    (``DataFeed.next_batch_columns``).
 
-    from tensorflowonspark_tpu.recordio import marshal
+    The first row fixes the spec; the first row that breaks it (shape
+    drift, an object column) turns the encoder ``off`` for good and that
+    chunk, like every later one, travels as the row list it was."""
 
-    state = {"spec": None, "off": False, "shapes": None}
+    def __init__(self):
+        self.off = os.environ.get("TFOS_COLUMNAR_FEED", "1") == "0"
+        self.spec = None
+        self.shapes = None
 
-    def flatten(row):
-        shapes = state["shapes"]
+    def _flatten(self, row):
+        import numpy as np
+
         out = []
         for i, v in enumerate(row):
-            if shapes[i] is not None:
-                if not (isinstance(v, np.ndarray) and v.shape == shapes[i]):
+            if self.shapes[i] is not None:
+                if not (isinstance(v, np.ndarray)
+                        and v.shape == self.shapes[i]):
                     raise TypeError(
-                        f"field {i} shape drift: expected {shapes[i]}, "
+                        f"field {i} shape drift: expected {self.shapes[i]}, "
                         f"got {getattr(v, 'shape', type(v).__name__)}")
                 v = v.reshape(-1)
             out.append(v)
         return tuple(out)
 
-    def encode(chunk):
-        if state["off"]:
+    def _fix_spec(self, row):
+        import numpy as np
+
+        from tensorflowonspark_tpu.recordio import marshal
+
+        if not isinstance(row, (tuple, list)):
+            raise TypeError("non-tuple row")
+        shapes = tuple(
+            v.shape if isinstance(v, np.ndarray) and v.ndim > 1 else None
+            for v in row)
+        self.shapes = shapes if any(s is not None for s in shapes) else None
+        if self.shapes is not None:
+            row = self._flatten(row)
+        spec = marshal.infer_spec(row)
+        if any(c == "O" for c, _ in spec):
+            raise TypeError("object column")
+        self.spec = spec
+
+    def _give_up(self, e):
+        self.off = True
+        logger.info("feed: row-chunk path (columnar not applicable: %s)", e)
+
+    def record_bytes(self, row):
+        """Bytes a record takes in a columnar frame, from the partition's
+        first ``row`` (which fixes the spec); None on the row path."""
+        import numpy as np
+
+        from tensorflowonspark_tpu.recordio import marshal
+
+        if not self.off and self.spec is None:
+            try:
+                self._fix_spec(row)
+            except Exception as e:  # noqa: BLE001 - heterogeneous data
+                self._give_up(e)
+        if self.off:
+            return None
+        return sum(np.dtype(d).itemsize * int(np.prod(shape[1:]))
+                   for d, shape in marshal.column_descrs(self.spec, 1))
+
+    def __call__(self, chunk, alloc=None):
+        """The chunk as a ColumnChunk, or as it came on the row path.
+        ``alloc(spec, shapes, descrs)`` brings the column arrays to fill
+        (the feeder: views of a frame it reserved in the ring) instead of
+        fresh ones; it is not called for a chunk that stays rows, and
+        what it raises passes through."""
+        from tensorflowonspark_tpu.recordio import marshal
+
+        if self.off:
             return chunk
         try:
-            if state["spec"] is None:
-                row = chunk[0]
-                if not isinstance(row, (tuple, list)):
-                    raise TypeError("non-tuple row")
-                shapes = tuple(
-                    v.shape if isinstance(v, np.ndarray) and v.ndim > 1
-                    else None
-                    for v in row)
-                state["shapes"] = (shapes if any(s is not None
-                                                 for s in shapes) else None)
-                if state["shapes"] is not None:
-                    row = flatten(row)
-                spec = marshal.infer_spec(row)
-                if any(c == "O" for c, _ in spec):
-                    raise TypeError("object column")
-                state["spec"] = spec
-            rows = (chunk if state["shapes"] is None
-                    else [flatten(r) for r in chunk])
-            return marker.ColumnChunk(
-                state["spec"],
-                marshal.rows_to_columns(rows, state["spec"]),
-                shapes=state["shapes"],
-            )
+            if self.spec is None:
+                self._fix_spec(chunk[0])
+            rows = (chunk if self.shapes is None
+                    else [self._flatten(r) for r in chunk])
         except Exception as e:  # noqa: BLE001 - heterogeneous data: row path
-            state["off"] = True
-            logger.info(
-                "feed: row-chunk path (columnar not applicable: %s)", e
-            )
+            self._give_up(e)
             return chunk
+        out = None if alloc is None else alloc(
+            self.spec, self.shapes,
+            marshal.column_descrs(self.spec, len(rows)))
+        try:
+            columns = marshal.rows_to_columns(rows, self.spec, out=out)
+        except Exception as e:  # noqa: BLE001 - a row broke the spec
+            self._give_up(e)
+            return chunk
+        return marker.ColumnChunk(self.spec, columns, shapes=self.shapes)
 
-    return encode
+
+def _frame_records(record_bytes, capacity, limit):
+    """Records per frame the feeder encodes in place: the largest power
+    of two whose frame takes at most a quarter of the ring — so that the
+    consumer copies one frame out while the feeder fills the next, with
+    two more in between — capped at ``limit`` (the chunk size).  0 when
+    not even one record fits: such chunks are copied in and may wrap."""
+    room = capacity // 4 - 4096  # the frame's header and padding
+    if record_bytes > room:
+        return 0
+    n = 1
+    while 2 * n * record_bytes <= room:
+        n *= 2
+    return min(n, limit)
 
 
 def _partition_index():
@@ -861,58 +937,105 @@ def train(cluster_info, cluster_meta, feed_timeout=600, qname="input",
         ring = _open_feed_ring(mgr, qname)
         queue = None if ring is not None else mgr.get_queue(qname)
         equeue = mgr.get_queue("error")
-        encode = _make_chunk_encoder()
+        encode = _ChunkEncoder()
+        chunk_records = _feed_chunk_records()
+        # records per frame encoded straight into the ring, derived from
+        # the first record's size and the ring's; 0: chunks are copied in
+        frame_records = 0
+        iterator = iter(iterator)
+        first = next(iterator, None)
+        if first is not None:
+            iterator = itertools.chain((first,), iterator)
+            nbytes = None if ring is None else encode.record_bytes(first)
+            if nbytes:
+                frame_records = _frame_records(
+                    nbytes, ring.capacity, chunk_records)
+
+        def frame_size():
+            """Records of the next frame to encode in place; 0 where the
+            chunk is encoded apart and copied in (no ring, no spec, or a
+            row has broken it)."""
+            return 0 if encode.off else frame_records
+
+        def in_slices(op):
+            """``op(timeout_ms)`` waits for room in the ring: run it in
+            one-second slices and check between them, so a feeder never
+            deadlocks against a consumer that stopped draining, and
+            fails fast when the consumer errored or its heartbeat went
+            stale."""
+            while True:
+                try:
+                    return op(1000)
+                except TimeoutError:
+                    if str(mgr.get("state")) == "terminating":
+                        raise _Terminating from None
+                    _raise_if_consumer_lost(mgr, equeue)
 
         # one tfos/feeder/chunk span per chunk (never per record): where
         # THIS side of the ring spends its time — inside the partition
-        # iterator, encoding, or blocked on a full ring.  Another process
-        # than the trainer's, so it reaches the spool only.
+        # iterator, waiting for room in the ring, or writing.  Another
+        # process than the trainer's, so it reaches the spool only.
         timed = telemetry.active()
-        t_src = time.perf_counter() if timed else None
+        t_src = time.perf_counter()
+        last_pos = None  # the ring's position behind our last byte
+
+        def room_waited():
+            return ring.room_wait_s if ring is not None else 0.0
 
         def put(chunk):
-            """False once the consumer requested termination mid-feed: a
-            put blocked on a full ring re-checks state each second, so a
-            feeder never deadlocks against a consumer that stopped
-            draining (and fails fast when the consumer errored or its
-            heartbeat went stale)."""
-            nonlocal t_src
+            """False once the consumer requested termination mid-feed."""
+            nonlocal t_src, last_pos
             faults.check("feed.put", part=pidx)
-            t0 = time.perf_counter() if timed else None
-            records = len(chunk)
-            chunk = encode(chunk)
-            t1 = time.perf_counter() if timed else None
-            sent = True
-            if ring is not None:
-                while True:
-                    try:
-                        ring.put(chunk, timeout_ms=1000)
-                        break
-                    except TimeoutError:
-                        if str(mgr.get("state")) == "terminating":
-                            sent = False
-                            break
-                        _raise_if_consumer_lost(mgr, equeue)
+            t0, w0 = time.perf_counter(), room_waited()
+            frame = []
+
+            def alloc(spec, shapes, descrs):
+                frame.append(in_slices(lambda ms: ring.reserve_columns(
+                    spec, shapes, descrs, timeout_ms=ms)))
+                return frame[0]
+
+            try:
+                out = encode(chunk, alloc if frame_size() else None)
+                t1, w1 = time.perf_counter(), room_waited()
+                inplace = bool(frame) and isinstance(out, marker.ColumnChunk)
+                if inplace:
+                    last_pos = ring.commit()
+                elif ring is not None:
+                    # after a row that broke the spec mid-frame nothing
+                    # of the frame is published: the chunk goes as rows
+                    ring.drop()
+                    last_pos = in_slices(
+                        lambda ms: ring.put(out, timeout_ms=ms))
+                else:
+                    queue.put(out, block=True)
+            except _Terminating:
+                return False
+            if inplace:
+                metrics_registry.inc("tfos_feed_frames_inplace_total")
             else:
-                queue.put(chunk, block=True)
+                metrics_registry.inc("tfos_feed_frames_copied_total")
             if timed:
-                now = time.perf_counter()
+                now, w2 = time.perf_counter(), room_waited()
+                if inplace:  # one pass: the encoding IS the write
+                    times = {"write_ms": now - t0 - (w2 - w0)}
+                else:
+                    times = {"encode_ms": t1 - t0 - (w1 - w0),
+                             "write_ms": now - t1 - (w2 - w1)}
                 telemetry.record_span(
                     telemetry.FEEDER_CHUNK, now - t_src, part=pidx,
-                    records=records,
+                    records=len(chunk), inplace=int(inplace),
                     source_ms=round((t0 - t_src) * 1e3, 3),
-                    encode_ms=round((t1 - t0) * 1e3, 3),
-                    put_wait_ms=round((now - t1) * 1e3, 3))
+                    room_wait_ms=round((w2 - w0) * 1e3, 3),
+                    **{k: round(v * 1e3, 3) for k, v in times.items()})
                 t_src = now
-            return sent
+            return True
 
         total = 0
         terminated = False
         chunk = []
-        chunk_records = _feed_chunk_records()
         for item in iterator:
             chunk.append(item)
-            if len(chunk) >= chunk_records:
+            if len(chunk) >= (frame_size() or chunk_records):
                 if not put(chunk):
                     terminated = True
                     break
@@ -940,16 +1063,14 @@ def train(cluster_info, cluster_meta, feed_timeout=600, qname="input",
                         terminated=terminated)
 
         # the hand-over: this task holds the ring until the consumer has
-        # emptied it, polling; the next partition's feeder waits behind it
+        # taken its last byte; the next partition's feeder waits behind it
         with telemetry.span(telemetry.FEEDER_HANDOFF, part=pidx):
             if ring is not None:
-                if not terminated:
+                if not terminated and last_pos is not None:
                     # terminate()'s drain loop keeps reading while we hold
-                    # the producer flock, so outstanding bytes always reach
-                    # zero
-                    _await_consumption(
-                        mgr, lambda: ring.qsize_bytes() > 0, feed_timeout,
-                        poll=0.2)
+                    # the producer flock, so the consumer always gets there
+                    _await_ring_consumption(mgr, ring, last_pos,
+                                            feed_timeout)
                 ring.close()
             else:
                 joining = threading.Thread(target=queue.join, daemon=True)
@@ -987,7 +1108,7 @@ def inference(cluster_info, cluster_meta, feed_timeout=600, qname="input"):
         telemetry.register_with(mgr)
         ring = _open_feed_ring(mgr, qname)
         queue = None if ring is not None else mgr.get_queue(qname)
-        encode = _make_chunk_encoder()
+        encode = _ChunkEncoder()
 
         def put(item):
             if isinstance(item, list):

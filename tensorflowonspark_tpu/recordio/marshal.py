@@ -148,8 +148,39 @@ def schema_to_spec(fields, widths=None):
     return spec
 
 
-def rows_to_columns(rows, spec=None):
+def column_descrs(spec, n):
+    """``(dtype_str, shape)`` of each column that ``n`` rows of ``spec``
+    make: what ``rows_to_columns`` allocates, and what its ``out=`` has
+    to bring."""
+    return [(np.dtype(_CODE_TO_DTYPE.get(code, object)).str,
+             (n, width) if width else (n,))
+            for code, width in spec]
+
+
+def _check_out(out, spec, n):
+    if len(out) != len(spec):
+        raise ValueError(
+            f"out has {len(out)} arrays, spec has {len(spec)} columns")
+    for c, (a, (dtype_str, shape)) in enumerate(
+            zip(out, column_descrs(spec, n))):
+        if not (isinstance(a, np.ndarray) and a.dtype == np.dtype(dtype_str)
+                and a.shape == shape and a.flags.c_contiguous
+                and a.flags.writeable):
+            raise ValueError(
+                f"out[{c}]: need a writable C-contiguous {dtype_str} array "
+                f"of shape {shape}, got "
+                f"{getattr(a, 'dtype', type(a).__name__)} "
+                f"{getattr(a, 'shape', '')}")
+
+
+def rows_to_columns(rows, spec=None, out=None):
     """Batch of row tuples -> tuple of dense per-column arrays.
+
+    ``out`` brings the arrays to fill, one per column and exactly of the
+    column's dtype and shape (``column_descrs``) — the feeder passes
+    views of the feed ring, so a frame is encoded where it travels from —
+    instead of fresh ones; the same arrays come back.  After a row that
+    does not fit the spec they are partly written.
 
     Object ('O') columns always take the numpy path (the native layer
     handles the numeric matrix; strings/bytes stay python objects, like
@@ -159,18 +190,34 @@ def rows_to_columns(rows, spec=None):
         return ()
     if spec is None:
         spec = infer_spec(rows[0])
+    if out is not None:
+        _check_out(out, spec, len(rows))
     ext = _load_ext()
     if ext is not None and all(c in _EXT_CODES for c, _ in spec):
-        return ext.rows_to_columns(rows, [(c, int(w)) for c, w in spec])
+        return ext.rows_to_columns(rows, [(c, int(w)) for c, w in spec], out)
     # numpy fallback (identical semantics)
     for i, r in enumerate(rows):
         if len(r) != len(spec):
             raise ValueError(
                 f"row {i} has {len(r)} fields, spec has {len(spec)} columns"
             )
-    out = []
+    cols = []
     for c, (code, width) in enumerate(spec):
         vals = [r[c] for r in rows]
+        dst = None if out is None else out[c]
+        target = np.dtype(_CODE_TO_DTYPE.get(code, object))
+        if width and all(type(v) is np.ndarray and v.dtype == target
+                         and v.shape == (width,) for v in vals):
+            # rows that already hold the column's dtype (image bytes,
+            # token ids): one memcpy per row, straight into place —
+            # np.asarray over a list of 1-D arrays walks it element by
+            # element and is an order of magnitude slower
+            arr = (dst if dst is not None
+                   else np.empty((len(vals), width), target))
+            for i, v in enumerate(vals):
+                arr[i] = v
+            cols.append(arr)
+            continue
         if code == "O":
             arr = np.empty(len(vals), dtype=object)
             arr[:] = vals
@@ -187,25 +234,27 @@ def rows_to_columns(rows, spec=None):
                         f"column {c}: {natural.dtype} values under spec "
                         f"{code!r} (lossy cast refused)"
                     )
-                target = _CODE_TO_DTYPE[code]
-                if code != "?" and natural.dtype != np.dtype(target):
+                if code != "?" and natural.dtype != target:
                     # narrowing (or sign-crossing) casts are checked by
                     # VALUE range, like the C fill loop's int32 guard
                     info = np.iinfo(target)
                     if (natural > info.max).any() or (natural < info.min).any():
                         raise ValueError(
                             f"column {c}: values overflow the "
-                            f"{np.dtype(target).name} spec"
+                            f"{target.name} spec"
                         )
                 arr = natural.astype(target, copy=False)
             else:
-                arr = np.asarray(vals, dtype=_CODE_TO_DTYPE[code])
+                arr = np.asarray(vals, dtype=target)
             if width and arr.shape[1:] != (width,):
                 raise ValueError(
                     f"column {c}: shape {arr.shape[1:]} != width {width}"
                 )
-        out.append(arr)
-    return tuple(out)
+        if dst is not None:
+            dst[...] = arr
+            arr = dst
+        cols.append(arr)
+    return tuple(cols)
 
 
 def columns_to_rows(columns):

@@ -144,19 +144,23 @@ def _bind(lib):
     lib.shq_create.argtypes = [c.c_char_p, c.c_uint64]
     lib.shq_open.restype = c.c_void_p
     lib.shq_open.argtypes = [c.c_char_p, c.c_int]
-    lib.shq_push.restype = c.c_int
-    lib.shq_push.argtypes = [c.c_void_p, c.c_char_p, c.c_uint64, c.c_int]
-    lib.shq_pop.restype = c.c_int64
-    lib.shq_pop.argtypes = [c.c_void_p, c.c_int]
-    lib.shq_push_iov.restype = c.c_int
-    lib.shq_push_iov.argtypes = [c.c_void_p, c.POINTER(c.c_void_p),
-                                 c.POINTER(c.c_uint64), c.c_int, c.c_int]
+    lib.shq_reserve.restype = c.c_int64
+    lib.shq_reserve.argtypes = [c.c_void_p, c.c_uint64, c.c_int]
+    lib.shq_commit.restype = c.c_uint64
+    lib.shq_commit.argtypes = [c.c_void_p]
+    lib.shq_drop.argtypes = [c.c_void_p]
+    lib.shq_wait_ns.restype = c.c_uint64
+    lib.shq_wait_ns.argtypes = [c.c_void_p]
+    lib.shq_wait_tail.restype = c.c_int
+    lib.shq_wait_tail.argtypes = [c.c_void_p, c.c_uint64, c.c_int]
     lib.shq_peek_len.restype = c.c_int64
     lib.shq_peek_len.argtypes = [c.c_void_p, c.c_int]
     lib.shq_pop_into.restype = c.c_int64
     lib.shq_pop_into.argtypes = [c.c_void_p, c.c_void_p]
-    lib.shq_buffer.restype = u8p
-    lib.shq_buffer.argtypes = [c.c_void_p]
+    lib.shq_data.restype = c.c_void_p
+    lib.shq_data.argtypes = [c.c_void_p]
+    lib.shq_capacity.restype = c.c_uint64
+    lib.shq_capacity.argtypes = [c.c_void_p]
     lib.shq_close_write.argtypes = [c.c_void_p]
     lib.shq_size.restype = c.c_uint64
     lib.shq_size.argtypes = [c.c_void_p]

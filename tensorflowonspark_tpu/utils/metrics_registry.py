@@ -85,6 +85,12 @@ CATALOG = {
         "gauge", "Bytes resident in the shm feed ring after a pull."),
     "tfos_feed_queue_depth": (
         "gauge", "Chunks resident in the manager feed queue after a pull."),
+    # the feeder task (executor process, node.train)
+    "tfos_feed_frames_inplace_total": (
+        "counter", "Feeder frames encoded straight into the shm ring."),
+    "tfos_feed_frames_copied_total": (
+        "counter", "Feeder chunks encoded apart and copied into the "
+                   "transport (pickled rows, manager queue, no spec)."),
     # train step (trainer process, utils/metrics.py)
     "tfos_train_steps_total": (
         "counter", "Timed train steps completed."),

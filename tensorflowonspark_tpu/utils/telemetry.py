@@ -118,8 +118,8 @@ FEED_H2D = "tfos/feed/h2d"                  # dispatch of the transfer
 FEED_STAGE_FULL = "tfos/feed/stage_full"    # prefetch worker blocked in put
 FEED_NEXT = "tfos/feed/next"                # consumer blocked in get
 FEED_SYNC = "tfos/feed/sync"                # synchronized(): flag all-gather
-FEEDER_CHUNK = "tfos/feeder/chunk"          # feeder task, one per chunk
-FEEDER_HANDOFF = "tfos/feeder/handoff"      # feeder waits for an empty ring
+FEEDER_CHUNK = "tfos/feeder/chunk"          # feeder task, one per frame
+FEEDER_HANDOFF = "tfos/feeder/handoff"      # until its last byte is taken
 DECODE_IDLE = "tfos/decode/idle"
 DECODE_ADMIT_SPAN = "tfos/decode/admit"
 DECODE_TRIE_MATCH = "tfos/decode/trie_match"

@@ -10,33 +10,11 @@ import threading
 import time
 
 import pytest
+from feed_helpers import FakeMgr, patch_feeder, start_feeder
 
 from tensorflowonspark_tpu.recordio import shm
 
 pytestmark = pytest.mark.skipif(not shm.available(), reason="no native lib")
-
-
-class FakeMgr:
-    """KV + queue stub speaking the manager protocol DataFeed/node use."""
-
-    def __init__(self, kv=None):
-        self.kv = dict(kv or {})
-
-    def get(self, key):
-        return self.kv.get(key)
-
-    def set(self, key, value):
-        self.kv[key] = value
-
-    def get_queue(self, name):
-        if name == "error":  # _await_consumption polls this
-            class _Empty:
-                @staticmethod
-                def empty():
-                    return True
-
-            return _Empty()
-        raise AssertionError("ring path must not touch manager data queues")
 
 
 def test_producer_active_tracks_flock():
@@ -156,21 +134,7 @@ def test_feeder_put_bails_on_termination(monkeypatch):
     name = f"/tfosq-term-{os.getpid()}-c"
     ring = shm.ShmQueue(name, capacity=1 << 12, create=True)
     mgr = FakeMgr({"shm_input": name, "state": "running"})
-
-    stops = []
-
-    class FakeClient:
-        def __init__(self, addr):
-            pass
-
-        def request_stop(self):
-            stops.append(True)
-
-    monkeypatch.setattr(node, "FEED_CHUNK_RECORDS", 4)
-    monkeypatch.setattr(node, "_get_manager", lambda *a, **kw: mgr)
-    monkeypatch.setattr(node, "read_executor_id", lambda *a, **kw: 0)
-    monkeypatch.setattr(node, "get_ip_address", lambda: "127.0.0.1")
-    monkeypatch.setattr(node.rendezvous, "Client", FakeClient)
+    calls = patch_feeder(monkeypatch, mgr, chunk_records=4)
 
     feeder = node.train({}, {"server_addr": ("127.0.0.1", 0)}, feed_timeout=30)
     records = [b"x" * 256] * 200  # far more than the 4KiB ring holds
@@ -187,5 +151,123 @@ def test_feeder_put_bails_on_termination(monkeypatch):
     assert not done.is_set()
     mgr.kv["state"] = "terminating"
     assert done.wait(15), "feeder did not bail after termination"
-    assert stops, "feeder skipped the STOP handshake"
+    assert calls["stops"], "feeder skipped the STOP handshake"
     ring.close()
+
+
+# -- the hand-over: the feeder waits for its own last byte -------------------
+
+def _rows(n):
+    return [([float(i), float(2 * i)], i) for i in range(n)]
+
+
+def test_handover_returns_once_tail_passes_its_last_byte():
+    """Not "ring empty": a later producer's bytes behind the feeder's
+    last one do not hold its hand-over up."""
+    from tensorflowonspark_tpu import node
+
+    name = f"/tfosq-term-{os.getpid()}-e"
+    ring = shm.ShmQueue(name, capacity=1 << 14, create=True)
+    mgr = FakeMgr({"shm_input": name, "state": "running"})
+    prod = shm.ShmQueue(name, create=False, producer=True)
+    later = shm.ShmQueue(name, create=False)  # no flock: writes at once
+    try:
+        pos = prod.put(["mine"])
+        later.put(["later", "producer"])
+        done = threading.Event()
+
+        def wait():
+            node._await_ring_consumption(mgr, prod, pos, 30)
+            done.set()
+
+        threading.Thread(target=wait, daemon=True).start()
+        assert not done.wait(0.3), "returned before its bytes were taken"
+        assert ring.get(timeout_ms=1000) == ["mine"]
+        assert done.wait(2), "still waiting although tail passed its byte"
+        assert ring.qsize_bytes() > 0  # the later message is untouched
+        assert ring.get(timeout_ms=1000) == ["later", "producer"]
+    finally:
+        prod.close()
+        later.close()
+        ring.close()
+
+
+def test_partition_done_only_after_the_last_byte_was_taken(monkeypatch):
+    """The feed ledger's exactly-once contract: the partition is
+    reported consumed after the consumer took its last frame, never
+    while frames are still in the ring."""
+    name = f"/tfosq-term-{os.getpid()}-f"
+    ring = shm.ShmQueue(name, capacity=1 << 16, create=True)
+    mgr = FakeMgr({"shm_input": name, "state": "running"})
+    calls = patch_feeder(monkeypatch, mgr, chunk_records=8, partition=7)
+    try:
+        t, box = start_feeder(_rows(20))
+        deadline = time.time() + 10
+        while ring.qsize_bytes() == 0 and time.time() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.5)  # all three frames queued; nobody consumes
+        assert not box["done"].is_set() and calls["done"] == []
+        got = []
+        for _ in range(2):
+            got.append(ring.get(timeout_ms=1000))
+        time.sleep(0.3)  # one frame left: still not done
+        assert not box["done"].is_set() and calls["done"] == []
+        got.append(ring.get(timeout_ms=1000))
+        assert box["done"].wait(5) and box["error"] is None
+        assert calls["done"] == [("input", 7)]
+        assert [len(c) for c in got] == [8, 8, 4]
+        assert sum((c.columns[1].tolist() for c in got), []) == list(range(20))
+    finally:
+        ring.close()
+
+
+def test_terminate_during_handover_drains_and_feeder_returns(monkeypatch):
+    """terminate() while the feeder waits in its hand-over: the drain
+    takes what is queued, the feeder's wait ends, and it asks the driver
+    to stop."""
+    from tensorflowonspark_tpu.feed import DataFeed
+
+    name = f"/tfosq-term-{os.getpid()}-g"
+    ring = shm.ShmQueue(name, capacity=1 << 16, create=True)
+    mgr = FakeMgr({"shm_input": name, "state": "running"})
+    calls = patch_feeder(monkeypatch, mgr, chunk_records=8, partition=2)
+    try:
+        feed = DataFeed(mgr, input_mapping={"x": "x", "y": "y"})
+        t, box = start_feeder(_rows(40))
+        first = feed.next_batch_columns(8)  # mid-partition
+        assert first["y"].tolist() == list(range(8))
+        time.sleep(0.3)
+        assert not box["done"].is_set()  # waiting for the other frames
+        feed.terminate()
+        assert box["done"].wait(10), "feeder still waiting after the drain"
+        assert box["error"] is None
+        assert calls["stops"] == 1
+        assert ring.qsize_bytes() == 0
+        assert not shm.producer_active(name)
+    finally:
+        ring.close()
+
+
+def test_dead_consumer_fails_the_handover(monkeypatch):
+    """A consumer whose heartbeat went stale fails the waiting feeder
+    within the heartbeat's limit (+ the one-second check), not after
+    feed_timeout."""
+    from tensorflowonspark_tpu import manager as tfmanager
+
+    name = f"/tfosq-term-{os.getpid()}-h"
+    ring = shm.ShmQueue(name, capacity=1 << 16, create=True)
+    mgr = FakeMgr({"shm_input": name, "state": "running"})
+    calls = patch_feeder(monkeypatch, mgr, chunk_records=8, partition=1)
+    monkeypatch.setenv("TFOS_HEARTBEAT_STALE", "1")
+    monkeypatch.delenv("TFOS_ACTOR_HEARTBEAT_STALE", raising=False)
+    mgr.kv[tfmanager.HEARTBEAT_KEY] = time.time()  # alive, then silent
+    try:
+        t0 = time.time()
+        t, box = start_feeder(_rows(12), feed_timeout=600)
+        assert box["done"].wait(10), "the feeder outlived a dead consumer"
+        assert time.time() - t0 < 6
+        assert isinstance(box["error"], RuntimeError)
+        assert "consumer appears dead" in str(box["error"])
+        assert calls["done"] == []  # nothing was reported consumed
+    finally:
+        ring.close()

@@ -234,3 +234,118 @@ def test_marshal_ext_fuzz_no_crash():
             ext.columns_to_rows(cols)
         except (TypeError, ValueError, BufferError):
             pass
+
+
+# -- rows_to_columns(out=): fill the arrays the caller brings ----------------
+
+def _value(code, width, i):
+    """One field's value for row ``i``: ndarray rows for the narrow
+    codes (how image bytes arrive), python values for the rest."""
+    dt = marshal._CODE_TO_DTYPE[code]
+    if code == "?":
+        scalar = lambda k: bool((i + k) % 2)  # noqa: E731
+    elif code in "fd":
+        scalar = lambda k: float(i) + 0.25 * k  # noqa: E731
+    else:
+        scalar = lambda k: (7 * i + k) % 100  # noqa: E731
+    if not width:
+        return scalar(0)
+    vals = [scalar(k) for k in range(width)]
+    return np.asarray(vals, dt) if code in "bBhH" or i % 2 else vals
+
+
+def _ring_like_out(spec, n):
+    """Arrays of exactly the columns' dtypes and shapes, cut from ONE
+    byte buffer at 8-aligned offsets — as ``reserve_columns`` cuts them
+    from the ring."""
+    descrs = marshal.column_descrs(spec, n)
+    sizes = [int(np.dtype(d).itemsize * np.prod(s)) for d, s in descrs]
+    buf = np.full(sum((s + 7) & ~7 for s in sizes), 0xAB, np.uint8)
+    out, off = [], 0
+    for (d, shape), size in zip(descrs, sizes):
+        out.append(buf[off:off + size].view(d).reshape(shape))
+        off += (size + 7) & ~7
+    return tuple(out)
+
+
+@pytest.mark.parametrize("width", [0, 3])
+@pytest.mark.parametrize("code", list("?ilfdbBhH"))
+def test_rows_to_columns_out_equals_allocating_form(impl, code, width):
+    spec = [(code, width), ("l", 0)]
+    rows = [(_value(code, width, i), i) for i in range(9)]
+    want = marshal.rows_to_columns(rows, spec)
+    out = _ring_like_out(spec, len(rows))
+    got = marshal.rows_to_columns(rows, spec, out=out)
+    assert len(got) == len(want) == 2
+    for g, w, o in zip(got, want, out):
+        assert g is o, "the caller's arrays come back, filled"
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+
+
+def test_rows_to_columns_out_whole_dtype_matrix(impl):
+    out = _ring_like_out(SPEC, len(ROWS))
+    got = marshal.rows_to_columns(ROWS, SPEC, out=out)
+    for g, w in zip(got, marshal.rows_to_columns(ROWS, SPEC)):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("fault", ["rows", "width", "dtype", "strided",
+                                   "readonly", "count", "not_array"])
+def test_rows_to_columns_refuses_unfit_out(impl, fault):
+    spec = [("d", 4), ("l", 0)]
+    rows = [([0.5 * i] * 4, i) for i in range(6)]
+    good = [np.empty((6, 4), np.float64), np.empty(6, np.int64)]
+    bad = {
+        "rows": [np.empty((5, 4), np.float64), good[1]],
+        "width": [np.empty((6, 3), np.float64), good[1]],
+        "dtype": [np.empty((6, 4), np.float32), good[1]],
+        "strided": [np.empty((6, 8), np.float64)[:, ::2], good[1]],
+        "readonly": [good[0], np.zeros(6, np.int64)],
+        "count": good[:1],
+        "not_array": [good[0], bytearray(48)],
+    }[fault]
+    if fault == "readonly":
+        bad[1].flags.writeable = False
+    with pytest.raises(ValueError):
+        marshal.rows_to_columns(rows, spec, out=bad)
+    marshal.rows_to_columns(rows, spec, out=good)  # and takes what fits
+    assert good[1].tolist() == list(range(6))
+
+
+def test_native_refuses_out_of_the_wrong_size():
+    """The extension checks sizes itself: it writes through raw
+    pointers, so this is memory safety, not manners."""
+    if not marshal.native_available():
+        pytest.skip("native marshal not built")
+    rows = [([1.0, 2.0], 3)] * 4
+    spec = [("d", 2), ("l", 0)]
+    with pytest.raises(ValueError):
+        marshal._load_ext().rows_to_columns(
+            rows, spec, [np.empty((3, 2)), np.empty(4, np.int64)])
+    with pytest.raises(ValueError):
+        marshal._load_ext().rows_to_columns(rows, spec, [np.empty((4, 2))])
+
+
+def test_row_that_breaks_the_spec_raises_with_out_too(impl):
+    spec = [("l", 2), ("l", 0)]
+    rows = [([1, 2], 1), ([3, 4], 2), ([5.5, 6], 3)]
+    with pytest.raises((ValueError, TypeError)):
+        marshal.rows_to_columns(rows, spec, out=_ring_like_out(spec, 3))
+
+
+def test_ndarray_rows_of_the_columns_dtype_are_copied_exactly():
+    """The path image bytes take: 1-D uint8 rows, one copy per row into
+    place, the same bytes as the allocating form."""
+    rng = np.random.default_rng(3)
+    rows = [(rng.integers(0, 256, 4096, dtype=np.uint8), i)
+            for i in range(16)]
+    spec = marshal.infer_spec(rows[0])
+    assert spec == [("B", 4096), ("l", 0)]
+    out = _ring_like_out(spec, 16)
+    got = marshal.rows_to_columns(rows, spec, out=out)
+    np.testing.assert_array_equal(got[0], np.stack([r[0] for r in rows]))
+    np.testing.assert_array_equal(
+        marshal.rows_to_columns(rows, spec)[0], got[0])
+    assert got[1].tolist() == list(range(16))

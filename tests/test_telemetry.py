@@ -177,6 +177,8 @@ def test_trace_merge_names_follow_telemetry():
                                    telemetry.FEED_RING_READ)
     assert tm.FEED_TO_COLUMNS == telemetry.FEED_TO_COLUMNS
     assert tm.CLOCK_SPAN == telemetry.CLOCK
+    assert tm.FEEDER_CHUNK == telemetry.FEEDER_CHUNK
+    assert tm.FEEDER_HANDOFF == telemetry.FEEDER_HANDOFF
     names = [v for k, v in vars(telemetry).items()
              if k.isupper() and isinstance(v, str) and v.startswith("tfos/")]
     assert len(names) == len(set(names)) >= 20
@@ -559,6 +561,36 @@ def test_trace_merge_golden(tmp_path):
         assert n["mfu"] == pytest.approx(
             (10 * 32 * 2.0e9) / (n["step_total_s"] * 197e12), rel=1e-6)
     assert "train/step" in text and "worker-1" in text
+
+
+def test_trace_merge_feeder_rows(tmp_path):
+    """The producer's side of the ring, from the feeder task's spans:
+    frames in place and copied, means per frame, the hand-over."""
+    os.environ[telemetry.DIR_ENV] = str(tmp_path)
+    telemetry.configure(node_id="chief-0", role="chief")
+    for part in range(2):
+        for _ in range(8):
+            telemetry.record_span(
+                telemetry.FEEDER_CHUNK, 0.020, part=part, records=256,
+                inplace=1, source_ms=1.0, room_wait_ms=4.0, write_ms=15.0)
+        telemetry.record_span(telemetry.FEEDER_HANDOFF, 0.040, part=part)
+    telemetry.record_span(
+        telemetry.FEEDER_CHUNK, 0.100, part=2, records=100, inplace=0,
+        source_ms=1.0, room_wait_ms=0.0, encode_ms=60.0, write_ms=39.0)
+    telemetry.flush()
+    tm = _load_trace_merge()
+    pairs, skipped = tm.load_records(str(tmp_path))
+    text, stats = tm.summarize(pairs, skipped)
+    fd = stats["feeder"]["chief-0"]
+    assert (fd["frames"], fd["inplace"], fd["copied"]) == (17, 16, 1)
+    assert fd["records"] == 16 * 256 + 100
+    assert fd["room_wait_ms"] == pytest.approx(64.0 / 17)
+    assert fd["write_ms"] == pytest.approx((16 * 15.0 + 39.0) / 17)
+    assert fd["encode_ms"] == pytest.approx(60.0 / 17)
+    assert fd["handoff_ms"] == pytest.approx(40.0, rel=1e-3)
+    assert fd["records_per_s"] == pytest.approx(
+        fd["records"] / (16 * 0.020 + 2 * 0.040 + 0.100), rel=1e-3)
+    assert "-- feeder (tfos/feeder/chunk, handoff) --" in text
 
 
 def test_trace_merge_skips_malformed_lines(tmp_path):
