@@ -12,6 +12,16 @@ activations/matmuls run in the config compute dtype (bfloat16 on TPU)
 with f32 accumulation; layers are scanned (one compiled layer body);
 attention is ops.flash_attention (pallas) unless a sequence-parallel
 attn_fn is injected.
+
+Two blocks live here.  The CLASSIC block (``Config``'s defaults:
+per-head K/V attention, GELU MLP, float32 weights) is what every
+function below the ``Config`` class implements, unchanged.  The LATENT
+block (``attn_kind="latent"``: latent attention, a gated SwiGLU MLP in
+the leading dense layers, an expert layer with a shared expert in the
+rest, weights in ``param_dtype``) is the section "The latent block";
+``init`` and ``apply`` dispatch on the config, and the serving engine
+takes either block's incremental functions and cache layout from
+``Config.decode_fns()``.
 """
 
 from __future__ import annotations
@@ -25,7 +35,9 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from tensorflowonspark_tpu import ops
+from tensorflowonspark_tpu.models import latent_attention as latent
 from tensorflowonspark_tpu.models import layers as L
+from tensorflowonspark_tpu.models import moe
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,6 +54,59 @@ class Config:
     # GSPMD cannot auto-partition a pallas_call); 'reference' = pure XLA
     # einsum formulation, partitionable by GSPMD on any mesh.
     attn_impl: str = "flash"
+    # -- what describes a published block; the defaults are the classic one
+    param_dtype: str = "float32"     # the type weights are made, kept and
+    #                                  exported in
+    attn_kind: str = "mha"           # "mha": per-head K/V | "latent"
+    kv_lora_rank: int = 0            # latent: width of the cached latent
+    qk_nope_dim: int = 0             # latent: per-head q/k width without rope
+    qk_rope_dim: int = 0             # latent: rotary width (one key for all heads)
+    v_head_dim: int = 0              # latent: per-head v width
+    qk_norm: bool = False            # latent: RMSNorm on each head's q
+    rope_scaling: ops.YarnScaling | None = None
+    ffn_kind: str = "gelu"           # MLP of a dense layer: "gelu" | "swiglu"
+    ffn_dim: int = 0                 # its width; 0: dim * mlp_ratio
+    n_dense_layers: int = 0          # leading dense layers before the expert ones
+    n_experts: int = 0               # routed experts = router outputs; 0: none
+    n_experts_held: int = 0          # experts THIS program holds; 0: all
+    expert_offset: int = 0           # first held expert's index
+    experts_per_token: int = 0
+    expert_dim: int = 0
+    n_shared_experts: int = 0
+    routed_scale: float = 1.0
+
+    def __post_init__(self):
+        if self.attn_kind not in ("mha", "latent"):
+            raise ValueError(f"unknown attn_kind {self.attn_kind!r}")
+        if self.ffn_kind not in ("gelu", "swiglu"):
+            raise ValueError(f"unknown ffn_kind {self.ffn_kind!r}")
+        if self.attn_kind == "latent":
+            if min(self.kv_lora_rank, self.qk_nope_dim, self.qk_rope_dim,
+                   self.v_head_dim) < 1 or self.qk_rope_dim % 2:
+                raise ValueError(
+                    "latent attention needs kv_lora_rank, qk_nope_dim, "
+                    "v_head_dim >= 1 and an even qk_rope_dim >= 2")
+            if self.ffn_kind != "swiglu":
+                raise ValueError("the latent block's MLP is ffn_kind="
+                                 "'swiglu'")
+        elif (self.ffn_kind != "gelu" or self.n_experts
+              or self.rope_scaling is not None):
+            raise ValueError(
+                "per-head K/V attention (attn_kind='mha') runs only the "
+                "classic block: GELU MLP, no experts, plain rotary.  A "
+                "gated MLP, experts or rope scaling need "
+                "attn_kind='latent'")
+        if self.n_experts:
+            held = self.n_experts_held or self.n_experts
+            if not (0 < self.experts_per_token <= self.n_experts
+                    and self.expert_dim > 0
+                    and 0 <= self.expert_offset
+                    and self.expert_offset + held <= self.n_experts
+                    and 0 <= self.n_dense_layers < self.n_layers):
+                raise ValueError(
+                    "experts need 0 < experts_per_token <= n_experts, an "
+                    "expert_dim, held experts inside [0, n_experts) and "
+                    "at least one expert layer after the dense ones")
 
     @property
     def head_dim(self):
@@ -52,36 +117,72 @@ class Config:
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def classic(self):
+        return self.attn_kind == "mha"
+
+    @property
+    def q_head_dim(self):
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    @property
+    def latent_row(self):
+        """Numbers the latent cache keeps per token and layer."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def dense_layers(self):
+        """Layers unrolled before the scan: the leading dense ones of an
+        expert model."""
+        return self.n_dense_layers if self.n_experts else 0
+
+    def decode_fns(self):
+        """The seam between this model and the serving engine
+        (:class:`DecodeFns`): the incremental functions and the cache's
+        row layout, for whichever block this config describes."""
+        return _decode_fns(self)
+
 
 def _layer_init(key, cfg):
     ks = jax.random.split(key, 6)
     dim, mlp = cfg.dim, cfg.dim * cfg.mlp_ratio
-    dense = lambda k, i, o: L._he_init(k, (i, o), i, jnp.float32)
+    dt = jnp.dtype(cfg.param_dtype)
+    dense = lambda k, i, o: L._he_init(k, (i, o), i, dt)
     return {
-        "ln1": jnp.ones((dim,), jnp.float32),
+        "ln1": jnp.ones((dim,), dt),
         "wqkv": dense(ks[0], dim, 3 * dim),
         "wo": dense(ks[1], dim, dim),
-        "ln2": jnp.ones((dim,), jnp.float32),
+        "ln2": jnp.ones((dim,), dt),
         "w1": dense(ks[2], dim, mlp),
         "w2": dense(ks[3], mlp, dim),
     }
 
 
 def init(key, cfg: Config):
-    """Params pytree; per-layer trees stacked on a leading n_layers axis
-    so apply() scans one compiled layer body."""
+    """Params pytree in ``cfg.param_dtype``; per-layer trees stacked on a
+    leading axis so apply() scans one compiled layer body.  The latent
+    block's leading dense layers are a second stack, ``dense_layers``."""
     k_embed, k_head, k_layers = jax.random.split(key, 3)
+    dt = jnp.dtype(cfg.param_dtype)
+    nd = cfg.dense_layers
+    layer_init = _layer_init if cfg.classic else functools.partial(
+        _latent_layer_init, expert=bool(cfg.n_experts))
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    layers = jax.vmap(lambda k: _layer_init(k, cfg))(layer_keys)
-    return {
-        "embed": jax.random.normal(
+    layers = jax.vmap(lambda k: layer_init(k, cfg))(
+        layer_keys[nd:] if nd else layer_keys)
+    params = {
+        "embed": (jax.random.normal(
             k_embed, (cfg.vocab_size, cfg.dim), jnp.float32
-        ) * 0.02,
+        ) * 0.02).astype(dt),
         "layers": layers,
-        "ln_f": jnp.ones((cfg.dim,), jnp.float32),
-        "head": L._he_init(k_head, (cfg.dim, cfg.vocab_size), cfg.dim,
-                           jnp.float32),
+        "ln_f": jnp.ones((cfg.dim,), dt),
+        "head": L._he_init(k_head, (cfg.dim, cfg.vocab_size), cfg.dim, dt),
     }
+    if nd:
+        params["dense_layers"] = jax.vmap(
+            lambda k: _latent_layer_init(k, cfg, expert=False)
+        )(layer_keys[:nd])
+    return params
 
 
 def param_specs(cfg: Config, *, tp_axis="model", fsdp_axis="fsdp", mesh=None):
@@ -93,6 +194,11 @@ def param_specs(cfg: Config, *, tp_axis="model", fsdp_axis="fsdp", mesh=None):
     leading scan axis (None).  Pass ``mesh`` to drop axes the mesh does
     not define (e.g. a data x seq x model mesh without fsdp).
     """
+    if not cfg.classic:
+        raise NotImplementedError(
+            "param_specs describes the classic block; the latent block "
+            "has no tensor-parallel layout yet (its expert axis: "
+            "models/moe.param_specs)")
     if mesh is not None:
         axes = set(mesh.shape)
         tp_axis = tp_axis if tp_axis in axes else None
@@ -181,6 +287,13 @@ def apply(params, tokens, cfg: Config, *, attn_fn=None,
     forward re-runs on backward regardless — full remat cost plus extra
     residency.
     """
+    if not cfg.classic:
+        if positions is not None or remat:
+            raise NotImplementedError(
+                "the latent block's apply takes no positions= or remat=")
+        return _latent_apply(params, tokens, cfg, attn_fn=attn_fn,
+                             logits_dtype=logits_dtype,
+                             return_hidden=return_hidden)
     if positions is not None and attn_fn is None:
         # the default flash mask is causal by ARRAY INDEX; on permuted
         # input that silently attends to the future — demand an attn_fn
@@ -366,6 +479,13 @@ def loss_fn(params, tokens, cfg: Config, *, attn_fn=None, remat=False,
 _NEG_INF = -1e30  # finite mask fill (ops.attention convention: never -inf)
 
 
+def _classic_only(cfg, name):
+    if not cfg.classic:
+        raise ValueError(
+            f"transformer.{name} is the classic block's; a latent config "
+            f"has its own (cfg.decode_fns() hands out either block's)")
+
+
 def _layer_apply_kv(p, x, cfg, rope, attn_fn):
     """``_layer_apply`` that also returns the layer's rope-rotated keys
     and values in cache layout [B, H, S, D].  Keys are cached
@@ -403,6 +523,7 @@ def prefill(params, tokens, cfg: Config, *, lengths=None, attn_fn=None):
     and ``decode_step`` masks to ``position <= cursor`` while its next
     write lands AT the cursor, overwriting the first padded column.
     """
+    _classic_only(cfg, "prefill")
     if attn_fn is None:
         base = (ops.flash_attention if cfg.attn_impl == "flash"
                 else ops.mha_reference)
@@ -456,6 +577,7 @@ def decode_step(params, tokens, cfg: Config, cache_k, cache_v, lengths):
     cache column — finite garbage confined to that slot's logits row,
     which the scheduler discards.  No operation mixes slots.
     """
+    _classic_only(cfg, "decode_step")
     dtype = cfg.compute_dtype
     h, hd = cfg.n_heads, cfg.head_dim
     s_slots = tokens.shape[0]
@@ -531,6 +653,7 @@ def decode_step_paged(params, tokens, cfg: Config, pool_k, pool_v,
     slots (length 0, all-sentinel table) stay numerically inert exactly
     as in ``decode_step``.
     """
+    _classic_only(cfg, "decode_step_paged")
     dtype = cfg.compute_dtype
     h, hd = cfg.n_heads, cfg.head_dim
     s_slots, w = tokens.shape
@@ -626,6 +749,7 @@ def prefill_extend(params, tokens, cfg: Config, pool_k, pool_v,
     into the slot's private blocks (the tail starts block-aligned, so
     the writes never touch shared blocks).
     """
+    _classic_only(cfg, "prefill_extend")
     dtype = cfg.compute_dtype
     h, hd = cfg.n_heads, cfg.head_dim
     b, t = tokens.shape
@@ -697,6 +821,364 @@ def prefill_extend(params, tokens, cfg: Config, pool_k, pool_v,
         x, jnp.clip(last, 0, t - 1)[:, None, None], axis=1)[:, 0]
     logits = _matmul(x_last, params["head"]).astype(jnp.float32)
     return logits, k.transpose(1, 0, 2, 3, 4), v.transpose(1, 0, 2, 3, 4)
+
+
+# ---------------------------------------------------------------------------
+# The latent block: latent attention (models/latent_attention.py), a gated
+# SwiGLU MLP in the dense layers, an expert layer with a shared expert in
+# the rest (models/moe.py).  Pre-norm RMSNorm, sequential residuals, as the
+# classic block.  The leading dense layers are unrolled, the others scan.
+#
+# Its cache is ONE pool of rows ``[c; k_rope]`` (``cfg.latent_row`` wide, for
+# all heads): ``[num_blocks, n_layers, block_size, latent_row]``.  Prefill
+# runs the expanded attention path and hands back rows; the decode step and
+# a tail over a cached prefix run the absorbed path over gathered rows.  The
+# step takes the pool as a loop carry and writes its rows in place (the
+# engine donates it), so nothing pool-sized is copied.
+# ---------------------------------------------------------------------------
+
+def _latent_layer_init(key, cfg, expert):
+    ka, kf = jax.random.split(key)
+    dt = jnp.dtype(cfg.param_dtype)
+    p = {"ln1": jnp.ones((cfg.dim,), dt), "ln2": jnp.ones((cfg.dim,), dt),
+         "attn": latent.init(ka, cfg, dt)}
+    if expert:
+        p["moe"] = moe.init(
+            kf, cfg.dim, cfg.expert_dim, cfg.n_experts,
+            num_held=cfg.n_experts_held or cfg.n_experts,
+            num_shared=cfg.n_shared_experts, dtype=dt)
+    else:
+        width = cfg.ffn_dim or cfg.dim * cfg.mlp_ratio
+        k1, k2, k3 = jax.random.split(kf, 3)
+        p["wg"] = L._he_init(k1, (cfg.dim, width), cfg.dim, dt)
+        p["wu"] = L._he_init(k2, (cfg.dim, width), cfg.dim, dt)
+        p["wd"] = L._he_init(k3, (width, cfg.dim), width, dt)
+    return p
+
+
+def _latent_ffn(p, x, cfg, index, live=None):
+    """``(x + FFN(norm(x)), expert statistics or None)`` of layer
+    ``index``; ``live`` marks the tokens whose routing the statistics
+    count.  An expert layer's ``p["moe"]`` holds the routed experts of
+    ALL expert layers (``_latent_layers``)."""
+    y = ops.rmsnorm_reference(x, p["ln2"])
+    if "moe" in p:
+        y, stats = moe.apply(
+            p["moe"], y, top_k=cfg.experts_per_token,
+            routed_scale=cfg.routed_scale, expert_offset=cfg.expert_offset,
+            live=live, bank=(index - cfg.dense_layers,
+                             cfg.n_experts_held or cfg.n_experts))
+        return x + y, {k: stats[k] for k in _MOE_COUNTERS}
+    with jax.named_scope("mlp"):
+        return x + moe.swiglu(y, p["wg"], p["wu"], p["wd"]), None
+
+
+_MOE_COUNTERS = ("picks", "picks_held", "rows_held", "experts_touched",
+                 "tokens_per_expert_max", "dropped")
+
+
+def _latent_layers(params, cfg, carry, layer_fn):
+    """Run ``layer_fn(p, carry, layer_index) -> (carry, out, stats)``
+    over every layer: the leading dense ones unrolled, the others under
+    one scan.  Returns ``(carry, outs stacked [n_layers, ...], expert
+    statistics stacked over the expert layers or None)``."""
+    nd = cfg.dense_layers
+    outs = []
+    for i in range(nd):
+        p = jax.tree_util.tree_map(lambda a: a[i], params["dense_layers"])
+        carry, out, _ = layer_fn(p, carry, i)
+        outs.append(out)
+
+    # the routed experts do not ride the scan: sliced out layer by layer
+    # they would be copied, whole, in front of the grouped-product kernel.
+    # Every layer sees the bank of all layers' experts and picks its own
+    # groups (moe.apply's ``bank``)
+    layers, bank = params["layers"], {}
+    if "moe" in layers:
+        bank = {k: layers["moe"][k].reshape((-1,) + layers["moe"][k].shape[2:])
+                for k in ("wg", "wu", "wd")}
+        layers = dict(layers, moe={k: v for k, v in layers["moe"].items()
+                                   if k not in bank})
+
+    def body(carry, inp):
+        p, index = inp
+        if bank:
+            p = dict(p, moe=dict(p["moe"], **bank))
+        carry, out, stats = layer_fn(p, carry, index)
+        return carry, (out, stats)
+
+    carry, (scanned, stats) = lax.scan(
+        body, carry, (layers, nd + jnp.arange(cfg.n_layers - nd)))
+    if outs and scanned is not None:
+        scanned = jnp.concatenate([jnp.stack(outs), scanned])
+    return carry, scanned, stats
+
+
+def _expanded_attn_fn(cfg, attn_fn):
+    if attn_fn is not None:
+        return attn_fn
+    base = (ops.flash_attention if cfg.attn_impl == "flash"
+            else ops.mha_reference)
+    return functools.partial(base, causal=True)
+
+
+def _last_logits(params, x, lengths):
+    """Head over each row's final REAL position: [B, T, dim] -> [B, vocab]."""
+    b, t, _ = x.shape
+    x = ops.rmsnorm_reference(x, params["ln_f"])
+    if lengths is None:
+        last = jnp.full((b,), t - 1, jnp.int32)
+    else:
+        last = jnp.asarray(lengths, jnp.int32) - 1
+    x_last = jnp.take_along_axis(
+        x, jnp.clip(last, 0, t - 1)[:, None, None], axis=1)[:, 0]
+    return _matmul(x_last, params["head"]).astype(jnp.float32)
+
+
+def _latent_forward(params, tokens, cfg, attn_fn):
+    """Whole-sequence pass on the expanded path: ``(hidden [B, T, dim],
+    rows [n_layers, B, T, latent_row])``."""
+    attn_fn = _expanded_attn_fn(cfg, attn_fn)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    cos, sin = latent.rope_tables(cfg, tokens.shape[1])
+
+    def layer(p, x, index):
+        y = ops.rmsnorm_reference(x, p["ln1"])
+        q, rows = latent.project(p["attn"], y, cfg, cos, sin)
+        a = latent.attend_expanded(p["attn"], q, rows, cfg, attn_fn)
+        x, stats = _latent_ffn(p, x + _matmul(a, p["attn"]["wo"]), cfg,
+                               index)
+        return x, rows, stats
+
+    x, rows, _ = _latent_layers(params, cfg, x, layer)
+    return x, rows
+
+
+def _latent_apply(params, tokens, cfg, *, attn_fn, logits_dtype,
+                  return_hidden):
+    x, _rows = _latent_forward(params, tokens, cfg, attn_fn)
+    with jax.named_scope("lm_head"):
+        x = ops.rmsnorm_reference(x, params["ln_f"])
+        if return_hidden:
+            return x
+        logits = _matmul(x, params["head"])
+        return (logits if logits_dtype is None
+                else logits.astype(logits_dtype))
+
+
+def latent_prefill(params, tokens, cfg: Config, *, lengths=None,
+                   attn_fn=None):
+    """The latent block's :func:`prefill`: ``(logits [B, vocab] float32
+    at each row's last real position, rows [B, n_layers, T,
+    latent_row])`` — the rows are all the cache keeps of a token."""
+    x, rows = _latent_forward(params, tokens, cfg, attn_fn)
+    return _last_logits(params, x, lengths), rows.transpose(1, 0, 2, 3)
+
+
+def _paged_context(pool, layer, tables):
+    """Every mapped position of each slot, ``[S, blocks*block_size,
+    row]``, of one layer of a ``[num_blocks, n_layers, block_size, row]``
+    pool."""
+    with jax.named_scope("gather_kv"):
+        ctx = pool[tables, layer]               # [S, blocks, bs, row]
+        return ctx.reshape(ctx.shape[0], -1, ctx.shape[-1])
+
+
+def latent_decode_step_paged(params, tokens, cfg: Config, pool,
+                             block_tables, lengths):
+    """The latent block's :func:`decode_step_paged`: one windowed decode
+    iteration over the latent pool ``[num_blocks, n_layers, block_size,
+    latent_row]``, same write and masking discipline (overflow into the
+    sentinel block, query j sees ``position <= lengths + j``).  Returns
+    ``(logits [S, W, vocab] float32, new_pool, counters)``; ``counters``
+    are the expert layers' dispatch counts summed over layers (empty for
+    a model without experts), a few ints that ride back with the logits.
+    """
+    dtype = cfg.compute_dtype
+    s_slots, w = tokens.shape
+    bs = pool.shape[2]
+    cap = block_tables.shape[1] * bs
+    lengths = jnp.asarray(lengths, jnp.int32)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    pos = lengths[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
+    posc = jnp.clip(pos, 0, cap - 1)
+    # a window token past the slot's mapped capacity lands in the sentinel
+    wblk = jnp.where(pos < cap,
+                     jnp.take_along_axis(tables, posc // bs, axis=1),
+                     0).reshape(-1)
+    woff = (posc % bs).reshape(-1)
+    kv_mask = (jnp.arange(cap)[None, None, None, :]
+               <= pos[:, None, :, None])                 # [S, 1, W, cap]
+    x = params["embed"].astype(dtype)[tokens]            # [S, W, dim]
+    cos, sin = latent.rope_tables(cfg, cap)
+    # a free slot (length 0) carries padding: it runs, it is not counted
+    live = jnp.broadcast_to((lengths > 0)[:, None], (s_slots, w))
+
+    def layer(p, carry, index):
+        x, pool = carry
+        y = ops.rmsnorm_reference(x, p["ln1"])
+        q, rows = latent.project(p["attn"], y, cfg, cos, sin, posc)
+        with jax.named_scope("write_kv"):
+            pool = pool.at[wblk, index, woff].set(
+                rows.reshape(-1, rows.shape[-1]).astype(pool.dtype))
+        ctx = _paged_context(pool, index, tables)
+        a = latent.attend_absorbed(p["attn"], q, ctx, kv_mask, cfg)
+        x, stats = _latent_ffn(p, x + _matmul(a, p["attn"]["wo"]), cfg,
+                               index, live)
+        return (x, pool), None, stats
+
+    (x, pool), _, stats = _latent_layers(params, cfg, (x, pool), layer)
+    x = ops.rmsnorm_reference(x, params["ln_f"])
+    logits = _matmul(x, params["head"]).astype(jnp.float32)
+    counters = {}
+    if stats is not None:
+        counters = {f"moe_{k}": jnp.sum(v) for k, v in stats.items()
+                    if k != "tokens_per_expert_max"}
+        counters["moe_tokens_per_expert_max"] = jnp.max(
+            stats["tokens_per_expert_max"])
+        counters["moe_layers"] = jnp.asarray(
+            cfg.n_layers - cfg.dense_layers, jnp.int32)
+    return logits, pool, counters
+
+
+def latent_prefill_extend(params, tokens, cfg: Config, pool, prefix_tables,
+                          prefix_lens, *, lengths=None):
+    """The latent block's :func:`prefill_extend`: the unmatched tail on
+    top of trie-matched prefix blocks.  The tail attends the gathered
+    prefix rows (``position < prefix_lens``) and itself causally, all on
+    the absorbed path (keys and values are expanded for neither).
+    Returns ``(logits [B, vocab], rows [B, n_layers, T, latent_row])``.
+    The scores are [B, H, T, prefix + T] at once: tails of a few
+    thousand tokens, not whole long prompts."""
+    dtype = cfg.compute_dtype
+    b, t = tokens.shape
+    bs = pool.shape[2]
+    pcap = prefix_tables.shape[1] * bs
+    plens = jnp.asarray(prefix_lens, jnp.int32)
+    ptab = jnp.asarray(prefix_tables, jnp.int32)
+    pos = plens[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    cos, sin = latent.rope_tables(cfg, pcap + t)
+    pmask = jnp.broadcast_to(
+        (jnp.arange(pcap)[None, :] < plens[:, None])[:, None, None, :],
+        (b, 1, t, pcap))
+    cmask = jnp.broadcast_to(
+        (jnp.arange(t)[None, :] <= jnp.arange(t)[:, None])[None, None],
+        (b, 1, t, t))
+    mask = jnp.concatenate([pmask, cmask], axis=-1)
+    x = params["embed"].astype(dtype)[tokens]
+
+    def layer(p, x, index):
+        y = ops.rmsnorm_reference(x, p["ln1"])
+        q, rows = latent.project(p["attn"], y, cfg, cos, sin, pos)
+        ctx = jnp.concatenate(
+            [_paged_context(pool, index, ptab).astype(dtype), rows], axis=1)
+        a = latent.attend_absorbed(p["attn"], q, ctx, mask, cfg)
+        x, stats = _latent_ffn(p, x + _matmul(a, p["attn"]["wo"]), cfg,
+                               index)
+        return x, rows, stats
+
+    x, rows, _ = _latent_layers(params, cfg, x, layer)
+    return _last_logits(params, x, lengths), rows.transpose(1, 0, 2, 3)
+
+
+# ---------------------------------------------------------------------------
+# The seam between a model and the serving engine.
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class DecodeFns:
+    """What ``serving/decode`` needs of a model, and all it knows of it.
+
+    ``rows``: the cache's layout, ``((pool name, per-layer shape of one
+    sequence with None for the token axis), ...)`` — a paged pool is
+    ``[num_blocks, n_layers, *shape]`` with ``block_size`` for None, a
+    prefill hands back ``[B, n_layers, *shape]`` with T for None.
+    ``pools`` below is the tuple of pools in that order.
+
+    ``prefill(params, tokens, lengths) -> (logits, rows)``
+    ``prefill_extend(params, tokens, pools, prefix_tables, prefix_lens,
+    lengths) -> (logits, rows)``
+    ``decode_step_paged(params, tokens [S, W], pools, block_tables,
+    lengths) -> (logits, pools, counters)``; ``counters`` is a (possibly
+    empty) dict of scalars: the engine sums each over steps (keeps the
+    maximum of a name ending in ``_max``) and ``summarize(totals)`` turns
+    the totals into what ``stats()`` shows.
+    ``decode_step(params, tokens [S], caches, lengths) -> (logits,
+    caches)`` is the unpaged step, or None where the model has none.
+    ``donate``: the paged step may overwrite the pools it is given (the
+    cache's insert always does).
+    """
+    rows: tuple
+    prefill: object
+    prefill_extend: object
+    decode_step_paged: object
+    decode_step: object = None
+    donate: bool = False
+    summarize: object = None
+
+
+def _summarize_moe(totals):
+    """``stats()["moe"]`` from the step counters' totals."""
+    touched = totals.get("moe_experts_touched", 0)
+    return {"moe": {
+        "picks_held": round(totals.get("moe_picks_held", 0)
+                            / max(totals.get("moe_picks", 0), 1), 6),
+        "experts_touched": round(
+            touched / max(totals.get("moe_layers", 0), 1), 4),
+        "tokens_per_expert_mean": round(
+            totals.get("moe_rows_held", 0) / max(touched, 1), 4),
+        "tokens_per_expert_max": int(
+            totals.get("moe_tokens_per_expert_max", 0)),
+        "dropped": int(totals.get("moe_dropped", 0)),
+    }}
+
+
+def _decode_fns(cfg):
+    if cfg.classic:
+        per_head = (cfg.n_heads, None, cfg.head_dim)
+
+        def prefill_fn(p, toks, lens):
+            logits, k, v = prefill(p, toks, cfg, lengths=lens)
+            return logits, (k, v)
+
+        def extend_fn(p, toks, pools, ptab, plens, lens):
+            logits, k, v = prefill_extend(p, toks, cfg, *pools, ptab, plens,
+                                          lengths=lens)
+            return logits, (k, v)
+
+        def step_paged_fn(p, toks, pools, tables, lens):
+            logits, k, v = decode_step_paged(p, toks, cfg, *pools, tables,
+                                             lens)
+            return logits, (k, v), {}
+
+        def step_fn(p, toks, caches, lens):
+            logits, k, v = decode_step(p, toks, cfg, *caches, lens)
+            return logits, (k, v)
+
+        return DecodeFns(rows=(("k", per_head), ("v", per_head)),
+                         prefill=prefill_fn, prefill_extend=extend_fn,
+                         decode_step_paged=step_paged_fn,
+                         decode_step=step_fn)
+
+    def prefill_fn(p, toks, lens):
+        logits, rows = latent_prefill(p, toks, cfg, lengths=lens)
+        return logits, (rows,)
+
+    def extend_fn(p, toks, pools, ptab, plens, lens):
+        logits, rows = latent_prefill_extend(p, toks, cfg, pools[0], ptab,
+                                             plens, lengths=lens)
+        return logits, (rows,)
+
+    def step_paged_fn(p, toks, pools, tables, lens):
+        logits, pool, counters = latent_decode_step_paged(
+            p, toks, cfg, pools[0], tables, lens)
+        return logits, (pool,), counters
+
+    return DecodeFns(rows=(("kv", (None, cfg.latent_row)),),
+                     prefill=prefill_fn, prefill_extend=extend_fn,
+                     decode_step_paged=step_paged_fn, donate=True,
+                     summarize=_summarize_moe if cfg.n_experts else None)
 
 
 @functools.lru_cache(maxsize=8)

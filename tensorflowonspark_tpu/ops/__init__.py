@@ -10,10 +10,14 @@ the CPU fallback.
 """
 
 from tensorflowonspark_tpu.ops.attention import (  # noqa: F401
+    YarnScaling,
     apply_rope,
     flash_attention,
     mha_reference,
     rope_angles,
+    yarn_frequencies,
+    yarn_mscale,
+    yarn_softmax_scale,
 )
 from tensorflowonspark_tpu.ops.norm import (  # noqa: F401
     fused_rmsnorm,

@@ -16,6 +16,7 @@ semantics via a custom VJP over the reference implementation.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -30,13 +31,71 @@ _NEG_INF = -1e30
 
 # -- rotary position embeddings ----------------------------------------------
 
-def rope_angles(seq_len, head_dim, base=10000.0, dtype=jnp.float32):
-    """(cos, sin) tables of shape [seq_len, head_dim//2]."""
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling as ``deepseek_yarn`` configs state it
+    (Peng et al., arXiv:2309.00071): dimensions that turn more than
+    ``beta_fast`` times over the ORIGINAL context keep their frequency,
+    those that turn fewer than ``beta_slow`` times are interpolated by
+    ``factor``, a linear ramp joins the two."""
+    factor: float
+    original_max_seq: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor, mscale=1.0):
+    """``0.1 * mscale * ln(factor) + 1`` (1 where nothing is scaled)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_softmax_scale(width, scaling):
+    """``width^-0.5 * m^2``, ``m = yarn_mscale(factor, mscale_all_dim)``:
+    the factor a YaRN model multiplies its attention scores by."""
+    m = 1.0 if scaling is None else yarn_mscale(
+        scaling.factor, scaling.mscale_all_dim)
+    return m * m / math.sqrt(width)
+
+
+def yarn_frequencies(head_dim, base, scaling):
+    """Inverse frequencies [head_dim // 2] under ``scaling``."""
     half = head_dim // 2
-    freqs = 1.0 / (base ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    plain = 1.0 / (base ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+
+    def correction_dim(rotations):
+        return head_dim * math.log(
+            scaling.original_max_seq / (rotations * 2 * math.pi)
+        ) / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / scaling.factor * ramp + plain * (1.0 - ramp)
+
+
+def rope_angles(seq_len, head_dim, base=10000.0, dtype=jnp.float32,
+                scaling=None):
+    """(cos, sin) tables of shape [seq_len, head_dim//2].  With a
+    :class:`YarnScaling` the frequencies are YaRN's and both tables carry
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``."""
+    half = head_dim // 2
+    if scaling is None:
+        freqs = 1.0 / (base ** (jnp.arange(0, half, dtype=jnp.float32)
+                                / half))
+        mag = 1.0
+    else:
+        freqs = yarn_frequencies(head_dim, base, scaling)
+        mag = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim)
     pos = jnp.arange(seq_len, dtype=jnp.float32)
     ang = jnp.outer(pos, freqs)
-    return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
+    if mag == 1.0:
+        return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
+    return (jnp.cos(ang) * mag).astype(dtype), \
+        (jnp.sin(ang) * mag).astype(dtype)
 
 
 def apply_rope(x, cos, sin, positions=None):
@@ -94,7 +153,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, seq_q, seq_kv,
     import jax.experimental.pallas as pl
 
     q_blk = q_ref[0].astype(jnp.float32) * scale  # [block_q, D]
-    head_dim = q_blk.shape[-1]
+    head_dim = v_ref.shape[-1]  # the output's width is v's, not q's
     q_start = pl.program_id(1) * block_q
 
     num_kv = pl.cdiv(seq_kv, block_kv)
@@ -145,12 +204,13 @@ def _flash_forward(q, k, v, *, causal, scale, block_q, block_kv, interpret):
     import jax.experimental.pallas as pl
 
     b, sq, h, d = q.shape
-    skv = k.shape[1]
+    skv, dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, max(sq, 8))
     block_kv = min(block_kv, max(skv, 8))
 
     def flat(x):  # [B, S, H, D] -> [B*H, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
+        return x.transpose(0, 2, 1, 3).reshape(
+            b * h, x.shape[1], x.shape[-1])
 
     qf, kf, vf = flat(q), flat(k), flat(v)
     pad_q = (-sq) % block_q
@@ -171,26 +231,39 @@ def _flash_forward(q, k, v, *, causal, scale, block_q, block_kv, interpret):
         scale=scale,
         causal=causal,
     )
+    # the kernel keeps one head's whole K and V in VMEM (double-buffered)
+    # beside its [block_q, block_kv] float32 temporaries; past the
+    # compiler's default scoped limit (16 MiB: 8k positions of a 192-wide
+    # key and a 128-wide value) say how much it needs
+    resident = 2 * (skv + pad_kv) * (d + dv) * k.dtype.itemsize \
+        + 8 * block_q * block_kv * 4 + 4 * block_q * (d + dv) * 4
+    extra = {}
+    if resident > 12 * 2 ** 20:
+        from jax.experimental.pallas import tpu as pltpu
+
+        extra["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=min(resident + 16 * 2 ** 20, 100 * 2 ** 20))
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
             pl.BlockSpec((1, skv + pad_kv, d), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((1, skv + pad_kv, d), lambda bh, i: (bh, 0, 0)),
+            pl.BlockSpec((1, skv + pad_kv, dv), lambda bh, i: (bh, 0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, i: (bh, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, i: (bh, i, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((b * h, sq + pad_q, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq + pad_q, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq + pad_q, 1), jnp.float32),
         ),
         interpret=interpret,
         name="tfos_flash_fwd",
+        **extra,
     )(qf, kf, vf)
-    out = out[:, :sq].reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    out = out[:, :sq].reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
     lse = lse[:, :sq, 0].reshape(b, h, sq)  # [B, H, Sq]
     return out, lse
 
@@ -391,6 +464,11 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_kv, interpret,
 
 
 def _flash_bwd(causal, scale, block_q, block_kv, interpret, bwd_impl, res, g):
+    if res[2].shape[-1] != res[0].shape[-1]:
+        raise NotImplementedError(
+            "flash_attention's backward needs v as wide as q and k "
+            f"(got q/k {res[0].shape[-1]}, v {res[2].shape[-1]}): only "
+            "the forward takes a narrower v (latent-attention prefill)")
     if bwd_impl == "pallas":
         q, k, v, out, lse = res
         return _flash_backward_pallas(
@@ -471,7 +549,10 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, *, causal=False, scale=None, block_q=512,
                     block_kv=512, interpret=None, bwd_impl="xla"):
-    """Flash attention on [B, S, H, D]; differentiable.
+    """Flash attention on [B, S, H, D]; differentiable.  ``v`` may be
+    narrower or wider than ``q``/``k`` ([B, S, H, Dv] -> out [B, S, H,
+    Dv]) in the forward pass (latent attention's prefill: 192 against
+    128); the backward pass needs equal widths.
 
     ``interpret=None`` auto-selects: compiled pallas on TPU, interpreter
     mode on a CPU backend that was asked for (tests / virtual-device

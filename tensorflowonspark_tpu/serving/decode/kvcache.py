@@ -1,28 +1,37 @@
-"""Slot- and block-paged KV caches for continuous-batching decode.
+"""Slot- and block-paged caches for continuous-batching decode.
 
 No reference counterpart (the reference delegates all inference to TF
 Serving, SURVEY.md §2.2; reference Inference.scala:27-79 is offline
-batch only).  Two tiers:
+batch only).
+
+What a cached token IS belongs to the model: ``cfg.decode_fns().rows``
+names the pools and gives each one's per-layer shape for one sequence,
+with ``None`` where the token axis goes (per-head keys and values:
+``("k", (heads, None, head_dim)), ("v", ...)``; a latent cache: one pool
+``("kv", (None, row_width))``).  This module allocates ``[lead,
+n_layers, *shape]`` arrays from that, reaches them as ``cache.pools`` (a
+tuple in the layout's order, also ``cache.<name>``), and moves rows in
+and out along the token axis; everything else — slots, block tables,
+refcounts, the trie — never looks inside a row.  Two tiers:
 
 :class:`SlotKVCache` — vLLM-style paging simplified to one page per
-session: two preallocated ``[slots, n_layers, n_heads, max_seq,
-head_dim]`` arrays (keys cached rope-rotated) plus a per-slot length
-cursor.  Admission/retirement are O(1) (pop/push a free slot) and the
-fused ``models/transformer.decode_step`` always sees the same
+session: preallocated ``[slots, n_layers, ...max_seq...]`` arrays plus a
+per-slot length cursor.  Admission/retirement are O(1) (pop/push a free
+slot) and the model's fused unpaged step always sees the same
 ``[slots, ...]`` arrays, so it compiles exactly once.
 
 :class:`PagedKVCache` — full block paging with ref-counted prefix
-sharing: the pool is ``[num_blocks, n_layers, n_heads, block_size,
-head_dim]`` and each slot maps logical positions through a per-slot
-block-table row (``models/transformer.decode_step_paged`` gathers
-through it).  Blocks carry refcounts, so admission can map a new
-request's matched prompt-prefix blocks from the :class:`PrefixTrie`
-(bumping refcounts) instead of re-prefilling them — only the unmatched
-tail is prefilled, and tail writes always land in session-private
-blocks because trie matches are whole-block (copy-on-write by block
-alignment, never in place).  Retired sessions decref; blocks a trie
-path still references stay resident for future hits and are reclaimed
-LRU-leaf-first only when allocation would otherwise fail.
+sharing: a pool is ``[num_blocks, n_layers, ...block_size...]`` and each
+slot maps logical positions through a per-slot block-table row (the
+model's paged step gathers through it). Blocks carry refcounts, so
+admission can map a new request's matched prompt-prefix blocks from the
+:class:`PrefixTrie` (bumping refcounts) instead of re-prefilling them —
+only the unmatched tail is prefilled, and tail writes always land in
+session-private blocks because trie matches are whole-block
+(copy-on-write by block alignment, never in place).  Retired sessions
+decref; blocks a trie path still references stay resident for future
+hits and are reclaimed LRU-leaf-first only when allocation would
+otherwise fail.
 
 Physical block 0 is a reserved SENTINEL: free slots' table rows point
 at it, so their numerically-inert writes (and the padded rows of a
@@ -43,41 +52,94 @@ import functools
 import numpy as np
 
 
-@functools.lru_cache(maxsize=1)
-def _kv_insert():
-    """The paged insert's scatter as ONE named program: a device trace
-    shows it as ``jit_tfos_kv_insert`` under the scope ``kv_insert``,
-    where the eager ``pool.at[blocks].set(...)`` was the library's
-    anonymous ``jit_scatter``.  The same scatter, one program per number
-    of blocks as before."""
+@functools.lru_cache(maxsize=4)
+def _kv_insert(token_axes, block_size):
+    """The paged insert as ONE named program (``jit_tfos_kv_insert``,
+    scope ``kv_insert``), on the device from end to end: row ``row`` of a
+    prefill's ``[B, n_layers, ...T...]`` outputs is cut into blocks and
+    scattered into the pools at ``blocks``.  ``blocks`` has one entry per
+    block of the PADDED length T (the prefill's bucket), the entries past
+    the prompt's own blocks naming the sentinel, so there is one program
+    per prefill shape and not one per prompt length, and no row travels
+    through the host.  The pools are donated: the cache keeps the new
+    ones, and a second copy of every pool never exists."""
     import jax
+    import jax.numpy as jnp
 
-    def tfos_kv_insert(pool, blocks, values):
+    def tfos_kv_insert(pools, blocks, rows, row):
+        nb = blocks.shape[0]
+        out = []
         with jax.named_scope("kv_insert"):
-            return pool.at[blocks].set(values)
+            for pool, r, ax in zip(pools, rows, token_axes):
+                r = jax.lax.dynamic_index_in_dim(r, row, 0, keepdims=False)
+                ax += 1                          # after the layer axis
+                pad = [(0, 0)] * r.ndim
+                pad[ax] = (0, nb * block_size - r.shape[ax])
+                r = jnp.pad(r, pad)
+                r = r.reshape(r.shape[:ax] + (nb, block_size)
+                              + r.shape[ax + 1:])
+                out.append(pool.at[blocks].set(
+                    jnp.moveaxis(r, ax, 0).astype(pool.dtype)))
+        return tuple(out)
 
-    return jax.jit(tfos_kv_insert)
+    return jax.jit(tfos_kv_insert, donate_argnums=(0,))
+
+
+class _Pools:
+    """The device arrays of a cache, allocated from the model's row
+    layout: ``pools`` in the layout's order, each also an attribute under
+    its layout name."""
+
+    def _allocate(self, cfg, lead, tokens, dtype):
+        import jax.numpy as jnp
+
+        fns = cfg.decode_fns()
+        self.layout = fns.rows
+        self.dtype = dtype or cfg.compute_dtype
+        self._token_axes = tuple(shape.index(None)
+                                 for _name, shape in fns.rows)
+        self.pools = tuple(
+            jnp.zeros((lead, cfg.n_layers)
+                      + tuple(tokens if d is None else d for d in shape),
+                      self.dtype)
+            for _name, shape in fns.rows)
+        return fns
+
+    def __getattr__(self, name):
+        # only reached for names not found the normal way
+        layout = self.__dict__.get("layout", ())
+        for i, (pool_name, _shape) in enumerate(layout):
+            if pool_name == name:
+                return self.pools[i]
+        raise AttributeError(name)
+
+    @property
+    def row_bytes(self):
+        """Bytes one cached token takes, all layers and pools."""
+        per_layer = sum(
+            int(np.prod([d for d in shape if d is not None]))
+            for _name, shape in self.layout)
+        return per_layer * self.pools[0].shape[1] \
+            * np.dtype(self.dtype).itemsize
 
 
 class CacheOOM(RuntimeError):
     """Block allocation failed even after trie reclamation."""
 
 
-class SlotKVCache:
-    """Preallocated per-slot K/V pages + host-side cursor/free-list."""
+class SlotKVCache(_Pools):
+    """Preallocated per-slot pages + host-side cursor/free-list."""
 
     def __init__(self, cfg, slots, max_seq=None, dtype=None):
-        import jax.numpy as jnp
-
         self.slots = int(slots)
         if self.slots < 1:
             raise ValueError("need at least one slot")
         self.max_seq = int(max_seq or cfg.max_seq)
-        self.dtype = dtype or cfg.compute_dtype
-        shape = (self.slots, cfg.n_layers, cfg.n_heads, self.max_seq,
-                 cfg.head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        if self._allocate(cfg, self.slots, self.max_seq,
+                          dtype).decode_step is None:
+            raise ValueError(
+                "this model has no unpaged decode step: serve it with "
+                "DecodeSpec(paged=True)")
         # host mirrors: the scheduler reads/writes these every iteration
         # without a device round-trip
         self.lengths = np.zeros((self.slots,), np.int32)
@@ -96,15 +158,20 @@ class SlotKVCache:
         self.lengths[slot] = 0
         self._free.append(slot)
 
-    def insert(self, slot, k, v, length):
-        """Install a prefill result: ``k``/``v``
-        [n_layers, n_heads, T, head_dim] into ``slot``'s first T
+    def insert(self, slot, *rows_length):
+        """``insert(slot, *rows, length)``: install a prefill result, one
+        ``[n_layers, ...T...]`` array per pool, into ``slot``'s first T
         columns, cursor to ``length`` (<= T <= max_seq)."""
-        t = k.shape[2]
-        if t > self.max_seq:
-            raise ValueError(f"prefill length {t} > max_seq {self.max_seq}")
-        self.k = self.k.at[slot, :, :, :t, :].set(k.astype(self.dtype))
-        self.v = self.v.at[slot, :, :, :t, :].set(v.astype(self.dtype))
+        *rows, length = rows_length
+        pools = []
+        for pool, r, ax in zip(self.pools, rows, self._token_axes):
+            t = r.shape[ax + 1]
+            if t > self.max_seq:
+                raise ValueError(
+                    f"prefill length {t} > max_seq {self.max_seq}")
+            at = (slot,) + (slice(None),) * (ax + 1) + (slice(0, t),)
+            pools.append(pool.at[at].set(r.astype(self.dtype)))
+        self.pools = tuple(pools)
         self.lengths[slot] = int(length)
 
     # -- introspection ------------------------------------------------------
@@ -216,21 +283,19 @@ class PrefixTrie:
         return freed
 
 
-class PagedKVCache:
-    """Block-paged K/V pool + per-slot block tables + prefix trie.
+class PagedKVCache(_Pools):
+    """Block-paged pools + per-slot block tables + prefix trie.
 
-    Device side: ``k``/``v`` ``[num_blocks, n_layers, n_heads,
-    block_size, head_dim]``.  Host side: ``block_tables`` [slots,
-    blocks_per_slot] int32 (unused entries point at sentinel block 0),
-    ``lengths`` [slots], ``refcount`` [num_blocks], a block free list
-    and a slot free list.  ``models/transformer.decode_step_paged`` and
-    ``prefill_extend`` consume the pool + tables directly.
+    Device side: ``pools``, each ``[num_blocks, n_layers,
+    ...block_size...]`` as the model's row layout says.  Host side:
+    ``block_tables`` [slots, blocks_per_slot] int32 (unused entries point
+    at sentinel block 0), ``lengths`` [slots], ``refcount`` [num_blocks],
+    a block free list and a slot free list.  The model's paged step and
+    tail prefill consume the pools + tables directly.
     """
 
     def __init__(self, cfg, slots, block_size=None, num_blocks=None,
                  max_seq=None, dtype=None, prefix_sharing=True):
-        import jax.numpy as jnp
-
         self.slots = int(slots)
         if self.slots < 1:
             raise ValueError("need at least one slot")
@@ -249,11 +314,7 @@ class PagedKVCache:
                 f"num_blocks {self.num_blocks} < sentinel + "
                 f"slots*blocks_per_slot = {min_blocks}: live sessions "
                 "could starve")
-        self.dtype = dtype or cfg.compute_dtype
-        shape = (self.num_blocks, cfg.n_layers, cfg.n_heads,
-                 self.block_size, cfg.head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        self._allocate(cfg, self.num_blocks, self.block_size, dtype)
         self.block_tables = np.zeros((self.slots, self.blocks_per_slot),
                                      np.int32)
         self.lengths = np.zeros((self.slots,), np.int32)
@@ -371,12 +432,17 @@ class PagedKVCache:
                          self._incref)
 
     # -- device writes ------------------------------------------------------
-    def insert_tail(self, slot, k, v, start, length):
-        """Install prefill K/V ``[n_layers, n_heads, T, head_dim]``
-        into the slot's blocks covering positions ``[start, start +
-        length)``.  ``start`` must be block-aligned (trie matches are
-        whole-block); the padded remainder of the last block is
-        session-private scratch that decode overwrites in order."""
+    def insert_tail(self, slot, *rows_start_length, row=None):
+        """``insert_tail(slot, *rows, start, length)``: install prefill
+        rows, one ``[n_layers, ...T...]`` array per pool, into the slot's
+        blocks covering positions ``[start, start + length)``.  With
+        ``row=i`` the arrays are a whole prefill's ``[B, n_layers,
+        ...T...]`` outputs, still on the device, and row ``i`` of them is
+        meant.  ``start`` must be block-aligned (trie matches are
+        whole-block); what the arrays hold past ``length`` (a bucket's
+        padding) lands in the session-private remainder of the last
+        block, which decode overwrites in order, and in the sentinel."""
+        *rows, start, length = rows_start_length
         bs = self.block_size
         if start % bs:
             raise ValueError(f"tail start {start} not block-aligned ({bs})")
@@ -384,23 +450,15 @@ class PagedKVCache:
         if start + t > self.max_seq:
             raise ValueError(
                 f"prefill end {start + t} > max_seq {self.max_seq}")
+        if row is None:
+            rows, row = [r[None] for r in rows], 0
+        padded = rows[0].shape[self._token_axes[0] + 2]
         first = start // bs
         nch = -(-t // bs)
-        phys = self.block_tables[slot, first:first + nch]
-        kk = np.asarray(k)[:, :, :t]
-        vv = np.asarray(v)[:, :, :t]
-        pad = nch * bs - t
-        if pad:
-            padw = ((0, 0), (0, 0), (0, pad), (0, 0))
-            kk = np.pad(kk, padw, mode="edge")
-            vv = np.pad(vv, padw, mode="edge")
-        # [L, H, nch*bs, D] -> [nch, L, H, bs, D] (pool layout)
-        ll, hh, _, dd = kk.shape
-        kk = kk.reshape(ll, hh, nch, bs, dd).transpose(2, 0, 1, 3, 4)
-        vv = vv.reshape(ll, hh, nch, bs, dd).transpose(2, 0, 1, 3, 4)
-        insert = _kv_insert()
-        self.k = insert(self.k, phys, kk.astype(self.dtype))
-        self.v = insert(self.v, phys, vv.astype(self.dtype))
+        blocks = np.zeros((-(-padded // bs),), np.int32)
+        blocks[:nch] = self.block_tables[slot, first:first + nch]
+        self.pools = _kv_insert(self._token_axes, bs)(
+            self.pools, blocks, tuple(rows), np.int32(row))
 
     # -- introspection ------------------------------------------------------
     @property

@@ -23,14 +23,14 @@ all token-exact against the full-recompute oracle):
   the cache is a :class:`~.kvcache.PagedKVCache`; admission matches
   each prompt against the resident prefix trie and maps the shared
   blocks (refcount bump) instead of re-prefilling them — only the
-  unmatched tail runs ``models/transformer.prefill_extend``.
+  unmatched tail runs the model's tail prefill.
 - **Seeded sampling** (per-session temperature/top-k/top-p/seed,
   ``serving/decode/sampling.py``): logits come back to the host and
   the token is a pure function of ``(logits, params, index)``, so a
   failover replay re-draws the identical stream.
 - **Speculative decoding** (``spec_window`` + a draft model): the
-  draft proposes K-1 tokens, the verify step is ONE windowed
-  ``decode_step_paged`` over the K-token window, and a draft token is
+  draft proposes K-1 tokens, the verify step is ONE windowed paged
+  step over the K-token window, and a draft token is
   accepted iff it EQUALS the target's seeded sample at that index —
   so speculative output is byte-identical to non-speculative at the
   same seed, not merely distribution-preserving.
@@ -42,6 +42,12 @@ replay after a replica SIGKILL (greedy and seeded-sampled decode are
 both deterministic) re-delivers identical ``(index, token)`` pairs —
 first arrival wins, ``_set``/``_fail`` resolve once, zero drop and
 zero dup by construction.
+
+The engine knows nothing of the model but its seam: ``cfg.decode_fns()``
+(``models/transformer.DecodeFns``) hands it the incremental functions
+(prefill, tail prefill, paged step, unpaged step) under one signature
+each and the cache's row layout; the caches allocate their pools from
+that layout and the engine passes them through as a tuple.
 
 Module import stays stdlib + numpy (driver-importable); jax and the
 model only load inside :class:`DecodeEngine`'s replica-side thread.
@@ -117,13 +123,18 @@ class DecodeSpec:
     arms when BOTH ``draft_params`` (a transformer params pytree) and
     ``draft_cfg`` are given: the draft proposes ``spec_window - 1``
     tokens per iteration and one windowed verify step scores them
-    (paged mode only — the verify step is ``decode_step_paged``).
+    (paged mode only — the verify step is the paged step).
+
+    ``prefill_tokens`` bounds the padded tokens of ONE prefill program
+    (rows x sequence bucket): admission cuts a wave that would exceed it
+    into several prefills, so a prefill's temporaries are bounded however
+    many long prompts arrive together.  Default: no bound.
     """
 
     def __init__(self, cfg, slots=None, eos_id=None, max_tokens=None,
                  paged=None, block_size=None, num_blocks=None,
                  prefix_sharing=None, draft_params=None, draft_cfg=None,
-                 spec_window=None):
+                 spec_window=None, prefill_tokens=None):
         self.cfg = cfg
         self.slots = int(slots or slots_default())
         self.eos_id = eos_id
@@ -137,6 +148,10 @@ class DecodeSpec:
         self.draft_params = draft_params
         self.draft_cfg = draft_cfg
         self.spec_window = int(spec_window or spec_window_default())
+        self.prefill_tokens = (None if prefill_tokens is None
+                               else int(prefill_tokens))
+        if self.prefill_tokens is not None and self.prefill_tokens < 1:
+            raise ValueError("prefill_tokens must be >= 1")
         if self.spec_window < 2:
             raise ValueError(
                 f"spec_window must be >= 2, got {self.spec_window}")
@@ -147,7 +162,7 @@ class DecodeSpec:
         if draft_params is not None and not self.paged:
             raise ValueError(
                 "speculative decoding requires paged=True (the verify "
-                "step is decode_step_paged)")
+                "step is the paged step)")
 
     @property
     def speculative(self):
@@ -232,16 +247,20 @@ class _Slot:
     """Replica-side per-slot generation state."""
 
     __slots__ = ("sid", "prompt_len", "generated", "max_tokens", "eos_id",
-                 "sampling", "trace", "last", "t_admit")
+                 "sampling", "trace", "last", "t_admit", "prefill_rows")
 
     def __init__(self, sid, prompt_len, max_tokens, eos_id, first_token,
-                 sampling=None, trace=None):
+                 sampling=None, trace=None, prefill_rows=None):
         self.sid = sid
         self.prompt_len = prompt_len
         self.max_tokens = max_tokens
         self.eos_id = eos_id
         self.sampling = sampling
         self.trace = trace
+        # rows of the prefill program that admitted it (its wave's row
+        # bucket): a program is compiled per shape, and on the chip a
+        # one-row prefill rounds otherwise than one of several rows
+        self.prefill_rows = prefill_rows
         self.generated = [first_token]
         self.last = first_token
         self.t_admit = time.perf_counter()
@@ -294,6 +313,14 @@ class DecodeEngine:
         self._phase_s = {"idle": 0.0, "admit": 0.0, "step": 0.0,
                          "fetch": 0.0, "host": 0.0}
         self._t_mark = self._t_started = None
+        self._fns = None            # the model's seam, engine thread
+        # the paged step's own counters (computed on the device, fetched
+        # with the logits): summed over steps, a ``*_max`` kept as maximum
+        self._step_counters = {}
+        # cached positions of all sessions, summed over paged iterations:
+        # between two ``stats()`` its difference over that of
+        # ``iterations`` is the mean a step gathered
+        self._live_token_steps = 0
 
     def _mark(self, phase):
         """Everything since the last mark was ``phase``."""
@@ -380,6 +407,17 @@ class DecodeEngine:
             out["prefix_tokens_saved"] = self.prefix_tokens_saved
             out["blocks_in_use"] = (cache.blocks_in_use
                                     if cache is not None else 0)
+            if cache is not None:
+                out["cache"] = {"row_bytes": cache.row_bytes,
+                                "live_tokens": int(cache.lengths.sum()),
+                                "live_token_steps": self._live_token_steps}
+        fns = self._fns
+        if fns is not None and fns.summarize is not None:
+            # since the engine started; ``step_counters`` are the raw
+            # totals, so that a reader can summarize an interval
+            totals = dict(self._step_counters)
+            out.update(fns.summarize(totals))
+            out["step_counters"] = totals
         if self._spec.speculative:
             out["spec_proposed"] = self.spec_proposed
             out["spec_accepted"] = self.spec_accepted
@@ -410,46 +448,55 @@ class DecodeEngine:
             import jax.numpy as jnp  # noqa: F401 - jit closure imports
 
             from tensorflowonspark_tpu import tpu_info
-            from tensorflowonspark_tpu.models import transformer
             from tensorflowonspark_tpu.serving.decode import kvcache
 
             spec = self._spec
-            cfg = spec.cfg
+            fns = self._fns = spec.cfg.decode_fns()
+            self._device_get = jax.device_get
             self._device = tpu_info.device_facts()
             self.set_params(self._params)
 
             # the closures' names are the programs' names in a device
             # trace (``jit_tfos_decode_step_paged``): say what they are
             def tfos_prefill(p, toks, lens):
-                return transformer.prefill(p, toks, cfg, lengths=lens)
+                return fns.prefill(p, toks, lens)
 
             self._prefill_jit = jax.jit(tfos_prefill)
             if spec.paged:
-                def tfos_prefill_extend(p, toks, pk, pv, ptab, plens, lens):
-                    return transformer.prefill_extend(
-                        p, toks, cfg, pk, pv, ptab, plens, lengths=lens)
+                def tfos_prefill_extend(p, toks, pools, ptab, plens, lens):
+                    return fns.prefill_extend(p, toks, pools, ptab, plens,
+                                              lens)
 
-                def tfos_decode_step_paged(p, toks, pk, pv, tables, lens):
-                    return transformer.decode_step_paged(
-                        p, toks, cfg, pk, pv, tables, lens)
+                def tfos_decode_step_paged(p, toks, pools, tables, lens):
+                    return fns.decode_step_paged(p, toks, pools, tables,
+                                                 lens)
 
                 self._extend_jit = jax.jit(tfos_prefill_extend)
-                self._pstep_jit = jax.jit(tfos_decode_step_paged)
+                self._pstep_jit = jax.jit(
+                    tfos_decode_step_paged,
+                    donate_argnums=(2,) if fns.donate else ())
             else:
-                def tfos_decode_step(p, toks, ck, cv, lens):
-                    return transformer.decode_step(
-                        p, toks, cfg, ck, cv, lens)
+                if fns.decode_step is None:
+                    raise ValueError(
+                        "this model has no unpaged decode step: serve it "
+                        "with DecodeSpec(paged=True)")
+
+                def tfos_decode_step(p, toks, caches, lens):
+                    return fns.decode_step(p, toks, caches, lens)
 
                 self._step_jit = jax.jit(tfos_decode_step)
             if spec.speculative:
-                dcfg = spec.draft_cfg
+                dfns = spec.draft_cfg.decode_fns()
+                if dfns.decode_step is None:
+                    raise ValueError(
+                        "the draft model needs an unpaged decode step "
+                        "(its cache is a SlotKVCache)")
 
                 def tfos_draft_prefill(p, toks, lens):
-                    return transformer.prefill(p, toks, dcfg, lengths=lens)
+                    return dfns.prefill(p, toks, lens)
 
-                def tfos_draft_step(p, toks, ck, cv, lens):
-                    return transformer.decode_step(
-                        p, toks, dcfg, ck, cv, lens)
+                def tfos_draft_step(p, toks, caches, lens):
+                    return dfns.decode_step(p, toks, caches, lens)
 
                 self._dprefill_jit = jax.jit(tfos_draft_prefill)
                 self._dstep_jit = jax.jit(tfos_draft_step)
@@ -487,7 +534,7 @@ class DecodeEngine:
 
         Paged mode: each prompt is first matched against the prefix
         trie; a hit maps the shared blocks (refcount bump) and only the
-        unmatched tail runs ``prefill_extend`` — grouped by (tail
+        unmatched tail runs the model's tail prefill — grouped by (tail
         bucket, prefix-block bucket) so compile count stays
         logarithmic.  Misses (and slot mode) run the plain bucketed
         ``prefill``.  Every admitted prompt's whole-block prefix is then
@@ -505,9 +552,33 @@ class DecodeEngine:
         n_prompt = sum(len(req["prompt"]) for req in batch)
         with telemetry.span(telemetry.DECODE_ADMIT_SPAN,
                             sessions=len(batch), prompt_tokens=n_prompt):
-            self._admit_batch(batch, cache, dcache)
+            try:
+                self._admit_batch(batch, cache, dcache)
+            except BaseException as e:
+                # these left the queue and hold no slot yet: nobody else
+                # would ever answer them
+                seated = {st.sid for st in self._active.values()}
+                for req in batch:
+                    if req["sid"] not in seated:
+                        with self._qlock:
+                            self._sids.discard(req["sid"])
+                        self._emit("error", req["sid"], repr(e))
+                raise
         self.prompt_tokens += n_prompt
         self._mark("admit")
+
+    def _waves(self, members, bucket):
+        """``members`` of one sequence bucket, cut so that no prefill
+        program pads to more than ``DecodeSpec.prefill_tokens`` tokens
+        (rows are padded to a power of two, so the cut is one too); one
+        wave where there is no bound."""
+        bound = self._spec.prefill_tokens
+        if bound is None:
+            return [members]
+        rows = 1
+        while rows * 2 * bucket <= bound:
+            rows *= 2
+        return [members[i:i + rows] for i in range(0, len(members), rows)]
 
     def _admit_batch(self, batch, cache, dcache):
         """The admission itself, under ``_admit``'s span: trie match,
@@ -524,28 +595,34 @@ class DecodeEngine:
                 else:
                     plain.append(req)
 
-        admitted = []  # (req, logits_row [vocab], k_i, v_i, shared, mlen)
+        # (req, logits_row [vocab], the prefill's rows (still on the
+        # device, whole batch), row index, shared, mlen)
+        admitted = []
         # -- plain bucketed prefill (whole prompt) --------------------------
         groups = {}
         for req in plain:
             t = _batcher.bucket_seq(len(req["prompt"]), cfg.max_seq)
             groups.setdefault(t, []).append(req)
-        for t, members in groups.items():
-            rows = _batcher.bucket_size(len(members), self._spec.slots)
-            toks = np.stack([
-                _batcher.pad_seq(np.asarray(m["prompt"], np.int32), t)
-                for m in members])
-            lens = np.asarray([len(m["prompt"]) for m in members], np.int32)
-            toks = _batcher.pad_rows(toks, rows)
-            lens = _batcher.pad_rows(lens, rows)
-            # dispatch to first-token logits on the host
-            with telemetry.span(telemetry.DECODE_PREFILL, bucket=t,
-                                rows=rows):
-                logits, k, v = self._prefill_jit(self._params, toks, lens)
-                logits = np.asarray(logits)
-            self.prefills += 1
-            for i, req in enumerate(members):
-                admitted.append((req, logits[i], k[i], v[i], [], 0))
+        for t, group in groups.items():
+            waves = self._waves(group, t)
+            for members in waves:
+                rows = _batcher.bucket_size(len(members), self._spec.slots)
+                toks = np.stack([
+                    _batcher.pad_seq(np.asarray(m["prompt"], np.int32), t)
+                    for m in members])
+                lens = np.asarray([len(m["prompt"]) for m in members],
+                                  np.int32)
+                toks = _batcher.pad_rows(toks, rows)
+                lens = _batcher.pad_rows(lens, rows)
+                # dispatch to first-token logits on the host
+                with telemetry.span(telemetry.DECODE_PREFILL, bucket=t,
+                                    rows=rows, tokens=rows * t,
+                                    split=len(waves)):
+                    logits, kv = self._prefill_jit(self._params, toks, lens)
+                    logits = np.asarray(logits)
+                self.prefills += 1
+                for i, req in enumerate(members):
+                    admitted.append((req, logits[i], kv, i, [], 0))
         # -- prefix-hit tail prefill ----------------------------------------
         groups = {}
         for req, shared, mlen in matched:
@@ -554,36 +631,38 @@ class DecodeEngine:
                    _batcher.bucket_size(len(shared),
                                         cache.blocks_per_slot))
             groups.setdefault(key, []).append((req, shared, mlen))
-        for (t, nbp), members in groups.items():
-            rows = _batcher.bucket_size(len(members), self._spec.slots)
-            toks = np.stack([
-                _batcher.pad_seq(
-                    np.asarray(m[0]["prompt"][m[2]:], np.int32), t)
-                for m in members])
-            lens = np.asarray(
-                [len(m[0]["prompt"]) - m[2] for m in members], np.int32)
-            ptab = np.zeros((len(members), nbp), np.int32)
-            for i, (_req, shared, _mlen) in enumerate(members):
-                ptab[i, :len(shared)] = shared
-            plens = np.asarray([m[2] for m in members], np.int32)
-            toks = _batcher.pad_rows(toks, rows)
-            lens = _batcher.pad_rows(lens, rows)
-            ptab = _batcher.pad_rows(ptab, rows)
-            plens = _batcher.pad_rows(plens, rows)
-            with telemetry.span(telemetry.DECODE_PREFILL, bucket=t,
-                                rows=rows, prefix_blocks=nbp):
-                logits, k, v = self._extend_jit(
-                    self._params, toks, cache.k, cache.v, ptab, plens,
-                    lens)
-                logits = np.asarray(logits)
-            self.prefills += 1
-            for i, (req, shared, mlen) in enumerate(members):
-                admitted.append((req, logits[i], k[i], v[i], shared, mlen))
-                self.prefix_hits += 1
-                self.prefix_tokens_saved += mlen
-                metrics_registry.inc("tfos_decode_prefix_hits")
+        for (t, nbp), group in groups.items():
+            waves = self._waves(group, t)
+            for members in waves:
+                rows = _batcher.bucket_size(len(members), self._spec.slots)
+                toks = np.stack([
+                    _batcher.pad_seq(
+                        np.asarray(m[0]["prompt"][m[2]:], np.int32), t)
+                    for m in members])
+                lens = np.asarray(
+                    [len(m[0]["prompt"]) - m[2] for m in members], np.int32)
+                ptab = np.zeros((len(members), nbp), np.int32)
+                for i, (_req, shared, _mlen) in enumerate(members):
+                    ptab[i, :len(shared)] = shared
+                plens = np.asarray([m[2] for m in members], np.int32)
+                toks = _batcher.pad_rows(toks, rows)
+                lens = _batcher.pad_rows(lens, rows)
+                ptab = _batcher.pad_rows(ptab, rows)
+                plens = _batcher.pad_rows(plens, rows)
+                with telemetry.span(telemetry.DECODE_PREFILL, bucket=t,
+                                    rows=rows, prefix_blocks=nbp,
+                                    tokens=rows * t, split=len(waves)):
+                    logits, kv = self._extend_jit(
+                        self._params, toks, cache.pools, ptab, plens, lens)
+                    logits = np.asarray(logits)
+                self.prefills += 1
+                for i, (req, shared, mlen) in enumerate(members):
+                    admitted.append((req, logits[i], kv, i, shared, mlen))
+                    self.prefix_hits += 1
+                    self.prefix_tokens_saved += mlen
+                    metrics_registry.inc("tfos_decode_prefix_hits")
         # -- draft prefill (speculative mode: full prompt, own cache) -------
-        draft_kv = {}  # sid -> (k_i, v_i)
+        draft_kv = {}  # sid -> the draft's rows of that prompt
         if dcache is not None:
             groups = {}
             for req in batch:
@@ -599,13 +678,17 @@ class DecodeEngine:
                     [len(m["prompt"]) for m in members], np.int32)
                 toks = _batcher.pad_rows(toks, rows)
                 lens = _batcher.pad_rows(lens, rows)
-                _lg, dk, dv = self._dprefill_jit(
+                _lg, dkv = self._dprefill_jit(
                     self._spec.draft_params, toks, lens)
                 for i, req in enumerate(members):
-                    draft_kv[req["sid"]] = (dk[i], dv[i])
+                    draft_kv[req["sid"]] = [r[i] for r in dkv]
 
         # -- slot installation + first-token emission -----------------------
-        for req, logits_row, k_i, v_i, shared, mlen in admitted:
+        for i in range(len(admitted)):
+            # drop each prefill's rows with its last session: several waves'
+            # outputs need not stay on the device until all are seated
+            req, logits_row, kv, row, shared, mlen = admitted[i]
+            admitted[i] = None
             plen = len(req["prompt"])
             slot = cache.alloc()
             # cannot be None: admission is bounded by free_slots
@@ -615,19 +698,19 @@ class DecodeEngine:
                 cache.map_session(slot, shared, own, plen)
                 with telemetry.span(telemetry.DECODE_KV_INSERT,
                                     tokens=plen - mlen):
-                    cache.insert_tail(slot, k_i, v_i, mlen, plen - mlen)
+                    cache.insert_tail(slot, *kv, mlen, plen - mlen, row=row)
                 cache.register_prompt(slot, req["prompt"])
             else:
                 with telemetry.span(telemetry.DECODE_KV_INSERT,
                                     tokens=plen):
-                    cache.insert(slot, k_i, v_i, plen)
+                    cache.insert(slot, *(r[row] for r in kv), plen)
             if dcache is not None:
-                dk, dv = draft_kv[req["sid"]]
-                dcache.insert(slot, dk, dv, plen)
+                dcache.insert(slot, *draft_kv[req["sid"]], plen)
             first = _sampling.sample_token(logits_row, req["sampling"], 0)
             mt = min(req["max_tokens"], cache.max_seq - plen)
             st = _Slot(req["sid"], plen, max(1, mt), req["eos_id"], first,
-                       req["sampling"], trace=req.get("trace"))
+                       req["sampling"], trace=req.get("trace"),
+                       prefill_rows=int(kv[0].shape[0]))
             self._active[slot] = st
             with telemetry.activate(st.trace):
                 telemetry.event(
@@ -657,8 +740,8 @@ class DecodeEngine:
                     tokens[slot] = st.last
             self._mark("host")
             with telemetry.span(telemetry.DECODE_STEP_DISPATCH):
-                logits, cache.k, cache.v = self._step_jit(
-                    self._params, tokens, cache.k, cache.v, cache.lengths)
+                logits, cache.pools = self._step_jit(
+                    self._params, tokens, cache.pools, cache.lengths)
             self._mark("step")
             with telemetry.span(telemetry.DECODE_LOGITS_FETCH):
                 logits = np.asarray(logits)   # device wait + D2H
@@ -693,7 +776,7 @@ class DecodeEngine:
         Without a draft model the window is 1 token — the plain paged
         step.  With one, the draft proposes ``K-1`` tokens host-sampled
         at their future indices, the window ``[last, d_1 .. d_{K-1}]``
-        runs ONE ``decode_step_paged`` verify, and draft token ``d_j``
+        runs ONE paged verify step, and draft token ``d_j``
         is accepted iff it equals the target's seeded sample at index
         ``base+j-1`` — every emitted token is exactly the target
         sample conditioned on a correct history, so speculative output
@@ -729,11 +812,16 @@ class DecodeEngine:
             for slot, st in self._active.items():
                 window[slot, 0] = st.last
             n0 = cache.lengths.copy()
+            self._live_token_steps += int(n0.sum())
             if dcache is not None:
                 for j in range(k_win):
-                    dlogits, dcache.k, dcache.v = self._dstep_jit(
-                        spec.draft_params, window[:, j], dcache.k,
-                        dcache.v, dcache.lengths)
+                    # a COPY of the cursors: dispatch is asynchronous and
+                    # may read the host array in place, after the
+                    # increment below (the draft then writes one column
+                    # on and its proposals go astray)
+                    dlogits, dcache.pools = self._dstep_jit(
+                        spec.draft_params, window[:, j], dcache.pools,
+                        dcache.lengths.copy())
                     for slot in self._active:
                         dcache.lengths[slot] += 1
                     if j < k_win - 1:
@@ -746,12 +834,18 @@ class DecodeEngine:
                 cache.ensure_capacity(slot, int(n0[slot]) + k_win)
         self._mark("host")
         with telemetry.span(telemetry.DECODE_STEP_DISPATCH):
-            logits, cache.k, cache.v = self._pstep_jit(
-                self._params, window, cache.k, cache.v,
-                cache.block_tables, n0)
+            logits, cache.pools, counters = self._pstep_jit(
+                self._params, window, cache.pools, cache.block_tables, n0)
         self._mark("step")
         with telemetry.span(telemetry.DECODE_LOGITS_FETCH):
             logits = np.asarray(logits)           # [slots, K, vocab]
+            # a few ints computed by the same program: one more fetch,
+            # nothing more to wait for
+            for name, value in self._device_get(counters).items():
+                total = self._step_counters.get(name, 0)
+                self._step_counters[name] = (
+                    max(total, int(value)) if name.endswith("_max")
+                    else total + int(value))
         self._mark("fetch")
         self.iterations += 1
         sampled = {}
@@ -812,6 +906,7 @@ class DecodeEngine:
         self._emit("done", st.sid, list(st.generated), {
             "replica": self._replica,
             "prompt_len": st.prompt_len,
+            "prefill_rows": st.prefill_rows,
             "gen_ms": gen_ms,
         })
 

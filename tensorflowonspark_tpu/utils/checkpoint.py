@@ -36,19 +36,44 @@ from tensorflowonspark_tpu.utils import faults, metrics_registry, telemetry
 logger = logging.getLogger(__name__)
 
 
+# numpy's own file format knows only numpy's own types: an extension type
+# (bfloat16, float8: ml_dtypes) is stored as the unsigned integers of its
+# width under ``<key>::<dtype name>`` and viewed back at load
+_EXT_SEP = "::"
+
+
+def _storable(key, arr):
+    arr = np.asarray(arr)
+    if arr.dtype.kind != "V" or arr.dtype.names is not None:
+        return key, arr
+    return (f"{key}{_EXT_SEP}{arr.dtype.name}",
+            arr.view(np.dtype(f"u{arr.dtype.itemsize}")))
+
+
+def _restored(key, arr):
+    if _EXT_SEP not in key:
+        return key, arr
+    import ml_dtypes
+
+    key, name = key.rsplit(_EXT_SEP, 1)
+    return key, arr.view(np.dtype(getattr(ml_dtypes, name)))
+
+
 def _flatten(tree, prefix=""):
     out = {}
     if isinstance(tree, dict):
         for k in sorted(tree):
             out.update(_flatten(tree[k], f"{prefix}{k}/"))
     else:
-        out[prefix[:-1]] = np.asarray(tree)
+        key, arr = _storable(prefix[:-1], tree)
+        out[key] = arr
     return out
 
 
 def _unflatten(flat):
     tree = {}
     for key, value in flat.items():
+        key, value = _restored(key, value)
         parts = key.split("/")
         node = tree
         for p in parts[:-1]:
@@ -127,9 +152,10 @@ def export_model(export_dir, params, ctx=None, metadata=None):
     with telemetry.span("checkpoint/export"):
         _fs.makedirs(export_dir)
         flat = _flatten(_to_host(params))
-        buf = io.BytesIO()
-        np.savez(buf, **flat)
-        _fs.write_bytes(_fs.join(export_dir, "params.npz"), buf.getvalue())
+        # straight into the file: a second copy of ten gigabytes of
+        # weights in a BytesIO does not fit beside the first
+        with _fs.open_file(_fs.join(export_dir, "params.npz"), "wb") as f:
+            np.savez(f, **flat)
         meta = {"format": "tfos-tpu-export-v1"}
         meta.update(metadata or {})
         _fs.write_bytes(_fs.join(export_dir, "export.json"),

@@ -229,6 +229,71 @@ def test_paged_kvcache_trie_reclaim_lru_and_oom():
     assert cache.blocks_in_use == 0 and cache.leaked_blocks() == []
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_trie_reclaim_releases_what_a_walk_per_block_would(seed):
+    """``PrefixTrie.reclaim``'s contract, restated here one block at a
+    time: the least recently touched leaf whose block only the trie
+    holds, parents exposed as their last child goes, blocks a session
+    still holds never.  Today's ``reclaim`` IS that walk per block (a
+    third of a second per admission into a pool of 8,704 blocks:
+    PERF.md section 7); whatever replaces it must release the same
+    blocks in the same order."""
+    import random
+
+    from tensorflowonspark_tpu.serving.decode import kvcache
+
+    def plain(trie, need, refcount, release):
+        freed = 0
+        while freed < need:
+            best, stack = None, [trie.root]
+            while stack:
+                children = stack.pop()
+                for key, node in children.items():
+                    if node.children:
+                        stack.append(node.children)
+                    elif refcount[node.block] == 1 and (
+                            best is None or node.tick < best[0]):
+                        best = (node.tick, children, key, node)
+            if best is None:
+                break
+            del best[1][best[2]]
+            release(best[3].block)
+            freed += 1
+        return freed
+
+    def build():
+        rng = random.Random(seed)
+        trie, refcount, block = kvcache.PrefixTrie(2), {}, 0
+        stems = [[rng.randrange(4) for _ in range(8)] for _ in range(3)]
+        for _ in range(12):
+            stem = rng.choice(stems)[:2 * rng.randrange(1, 5)]
+            tokens = stem + [rng.randrange(4) for _ in range(
+                2 * rng.randrange(0, 4))]
+            blocks = list(range(block, block + len(tokens) // 2))
+            block += len(blocks)
+            trie.insert(tokens, blocks,
+                        lambda b: refcount.__setitem__(b, 1))
+            trie.match(rng.choice(stems))
+        for b in rng.sample(sorted(refcount), len(refcount) // 5):
+            refcount[b] += 1            # a session holds these
+        return trie, refcount
+
+    for need in (1, 3, 7, 1000):
+        released = []
+        for reclaim in (lambda t, *a: t.reclaim(*a), plain):
+            trie, refcount = build()
+            order = []
+
+            def release(b):
+                refcount[b] -= 1
+                order.append(b)
+            got = reclaim(trie, need, refcount, release)
+            assert got == len(order) <= need
+            released.append(order)
+        assert released[0] == released[1]
+        assert released[0]      # something was evictable
+
+
 def test_sampling_make_validation_and_pure_function():
     from tensorflowonspark_tpu.serving.decode import sampling
     assert sampling.make() is None

@@ -220,9 +220,11 @@ def test_scopes_and_kernel_names_reach_the_lowered_program():
     x = jax.ShapeDtypeStruct((8, 128), jnp.float32)
     assert "tfos_rmsnorm" in lowered(
         lambda x: ops.fused_rmsnorm(x, jnp.ones((128,))), x)
-    insert = kvcache._kv_insert()
-    text = insert.lower(pool, jnp.zeros((2,), jnp.int32),
-                        jax.ShapeDtypeStruct((2, 1, 2, 4, 16),
-                                             jnp.float32)).as_text(
-        debug_info=True)
+    # one pool whose rows are [heads, T, head_dim] (token axis 1), blocks
+    # of 4: a prefill's [B, layers, heads, T, head_dim], row 0 of it
+    insert = kvcache._kv_insert((1,), 4)
+    text = insert.lower((pool,), jnp.zeros((2,), jnp.int32),
+                        (jax.ShapeDtypeStruct((3, 1, 2, 8, 16),
+                                              jnp.float32),),
+                        jnp.int32(0)).as_text(debug_info=True)
     assert "tfos_kv_insert" in text and "kv_insert" in text

@@ -74,28 +74,47 @@ def test_pipeline_is_differentiable(eight_devices):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-def test_moe_forward_and_balance_loss():
-    params = moe.init(jax.random.PRNGKey(0), dim=16, hidden=32, num_experts=4)
+@pytest.mark.parametrize("held", [None, 2])
+def test_moe_forward_and_balance_statistics(held):
+    """The expert layer's forward pass, its balance statistics, and
+    gradients to router and experts — holding every expert, and holding
+    2 of 4 (a share)."""
+    params = moe.init(jax.random.PRNGKey(0), dim=16, hidden=32,
+                      num_experts=4, num_held=held, num_shared=1)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16))
-    y, aux = moe.apply(params, x)
-    assert y.shape == x.shape
-    assert np.isfinite(float(aux)) and float(aux) > 0
+    y, stats = moe.apply(params, x, top_k=2, routed_scale=2.5)
+    assert y.shape == x.shape and np.isfinite(np.asarray(y)).all()
+    # every token makes 2 picks; load is their share per expert
+    assert int(stats["picks"]) == 2 * 8 * 2
+    np.testing.assert_allclose(float(jnp.sum(stats["load"])), 1.0, rtol=1e-6)
+    assert stats["importance"].shape == (4,)
+    assert int(stats["dropped"]) == 0
+    if held is None:
+        assert int(stats["picks_held"]) == int(stats["picks"])
+    else:
+        assert 0 < int(stats["picks_held"]) < int(stats["picks"])
+        assert int(stats["experts_touched"]) <= held
 
     # gradients flow to router and experts
     def loss(p):
-        y, aux = moe.apply(p, x)
-        return jnp.sum(y ** 2) + aux
+        y, stats = moe.apply(p, x, top_k=2, routed_scale=2.5)
+        return jnp.sum(y ** 2) + jnp.sum(stats["importance"] ** 2)
 
     g = jax.grad(loss)(params)
     assert float(jnp.abs(g["router"]).sum()) > 0
-    assert float(jnp.abs(g["w1"]).sum()) > 0
+    assert float(jnp.abs(g["wg"]).sum()) > 0
+    assert float(jnp.abs(g["shared_wd"]).sum()) > 0
 
 
 def test_moe_expert_sharded_on_mesh(eight_devices):
-    mesh = Mesh(np.array(eight_devices).reshape(2, 4), ("data", "model"))
-    params = moe.init(jax.random.PRNGKey(0), dim=16, hidden=64, num_experts=8)
+    """Experts on the ``ep`` axis of the 8 virtual devices equal the
+    unsharded result."""
+    mesh = Mesh(np.array(eight_devices).reshape(2, 4), ("data", "ep"))
+    params = moe.init(jax.random.PRNGKey(0), dim=16, hidden=64,
+                      num_experts=8, num_shared=1)
     specs = jax.tree.map(
-        lambda s: NamedSharding(mesh, s), moe.param_specs(ep_axis="model"),
+        lambda s: NamedSharding(mesh, s),
+        moe.param_specs(ep_axis="ep", shared=True),
         is_leaf=lambda s: isinstance(s, P),
     )
     sharded = jax.device_put(params, specs)
@@ -103,6 +122,7 @@ def test_moe_expert_sharded_on_mesh(eight_devices):
         jax.random.normal(jax.random.PRNGKey(1), (4, 8, 16)),
         NamedSharding(mesh, P("data")),
     )
-    y, aux = jax.jit(moe.apply)(sharded, x)
-    ref, _ = moe.apply(params, jax.device_get(x))
+    apply = lambda p, x: moe.apply(p, x, top_k=2, routed_scale=2.5)[0]
+    y = jax.jit(apply)(sharded, x)
+    ref = apply(params, jax.device_get(x))
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=1e-5)

@@ -24,7 +24,7 @@ import pytest  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from tensorflowonspark_tpu import ops  # noqa: E402
-from tensorflowonspark_tpu.models import transformer  # noqa: E402
+from tensorflowonspark_tpu.models import moe, transformer  # noqa: E402
 
 # the bench transformer's width (bench.py _transformer_bench)
 B, S, H, D = 8, 2048, 8, 128
@@ -78,7 +78,26 @@ def _programs(sh):
     per_slot = S // block
     pool = a((1 + 2 * slots * per_slot, LM.n_layers, H, block, D),
              jnp.bfloat16)
+    # latent attention's prefill at the published widths: 64 heads, q/k
+    # 192 wide against v 128, 8,192 positions (one head's K and V stay in
+    # VMEM: the kernel has to ask for more than the default scoped limit)
+    lat_qk = a((1, 8192, 64, 192), jnp.bfloat16)
+    lat_v = a((1, 8192, 64, 128), jnp.bfloat16)
+    # the expert layer at a decode step's size: 16 tokens x top-8 over 32
+    # held experts of 128, width 4096 -> 2048 (grouped products)
+    experts = {
+        "router": a((4096, 128), jnp.bfloat16),
+        "router_bias": a((128,), jnp.float32),
+        "wg": a((32, 4096, 2048), jnp.bfloat16),
+        "wu": a((32, 4096, 2048), jnp.bfloat16),
+        "wd": a((32, 2048, 4096), jnp.bfloat16)}
     return {
+        "flash_fwd_latent": (
+            functools.partial(FLASH, scale=0.135), (lat_qk, lat_qk, lat_v),
+            True),
+        "expert_layer_decode": (
+            lambda p, x: moe.apply(p, x, top_k=8, routed_scale=2.5)[0],
+            (experts, a((16, 1, 4096), jnp.bfloat16)), True),
         "flash_fwd": (FLASH, (qkv, qkv, qkv), True),
         "flash_bwd_pallas": (_flash_grad("pallas"), (qkv,) * 4, True),
         "flash_bwd_xla": (_flash_grad("xla"), (qkv,) * 4, True),
@@ -103,7 +122,8 @@ def _programs(sh):
 
 @pytest.mark.parametrize("name", [
     "flash_fwd", "flash_bwd_pallas", "flash_bwd_xla", "fused_rmsnorm",
-    "decode_step_paged", "prefill"])
+    "decode_step_paged", "prefill", "flash_fwd_latent",
+    "expert_layer_decode"])
 def test_compiles_for_described_v5e(v5e, name):
     fn, args, has_kernel = _programs(v5e)[name]
     compiled = jax.jit(fn).lower(*args).compile()
