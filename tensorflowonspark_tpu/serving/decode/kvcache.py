@@ -31,7 +31,10 @@ session-private blocks because trie matches are whole-block
 (copy-on-write by block alignment, never in place).  Retired sessions
 decref; blocks a trie path still references stay resident for future
 hits and are reclaimed LRU-leaf-first only when allocation would
-otherwise fail.
+otherwise fail.  A pool sized to its live set fills with such blocks
+within minutes and every allocation then evicts, so the trie keeps its
+eviction order as it goes (a heap of candidate leaves): releasing a
+block costs a heap operation, whatever the trie holds.
 
 Physical block 0 is a reserved SENTINEL: free slots' table rows point
 at it, so their numerically-inert writes (and the padded rows of a
@@ -48,8 +51,12 @@ jax is imported lazily: the classes are instantiated replica-side only
 from __future__ import annotations
 
 import functools
+import heapq
+import time
 
 import numpy as np
+
+from tensorflowonspark_tpu.utils import telemetry
 
 
 @functools.lru_cache(maxsize=4)
@@ -185,12 +192,14 @@ class SlotKVCache(_Pools):
 
 
 class _TrieNode:
-    __slots__ = ("children", "block", "tick")
+    __slots__ = ("children", "block", "tick", "parent", "key")
 
-    def __init__(self, block, tick):
+    def __init__(self, block, tick, parent, key):
         self.children = {}      # block-token tuple -> _TrieNode
         self.block = int(block)
-        self.tick = tick
+        self.tick = tick        # None once evicted
+        self.parent = parent    # None under the root
+        self.key = key          # its key in the parent's ``children``
 
 
 class PrefixTrie:
@@ -206,14 +215,37 @@ class PrefixTrie:
     session references stack on top, so ``refcount == 1`` means
     "trie-only" — the reclaimable state.
 
+    Eviction is least-recently-touched LEAF first, and the order is
+    kept as the trie changes, not found by a walk: ``_heap`` is a
+    min-heap of ``(tick, seq, node)`` candidates, pushed wherever a node
+    becomes a leaf or a leaf's tick changes (the end of an inserted or
+    matched path, a parent whose last child was evicted) and checked
+    when popped — an entry counts only while its node is in the trie,
+    is a leaf and carries that tick.  Ticks are unique among leaves: one
+    tick goes to the nodes of one root path, of which at most one is a
+    leaf.  Reference counts change outside the trie without notice, so
+    they are read at the pop too.  Entries that went stale stay until
+    popped; :meth:`_bound_heap` rebuilds the heap from the live leaves
+    once it holds more than ``HEAP_SLACK * nodes + HEAP_SLACK_MIN``.
+
     Host-side bookkeeping only; the trie never touches device arrays.
+    ``reclaim_calls``, ``blocks_reclaimed`` and ``reclaim_s`` (seconds
+    inside :meth:`reclaim`) are totals since the trie was made.
     """
+
+    HEAP_SLACK = 2
+    HEAP_SLACK_MIN = 64
 
     def __init__(self, block_size):
         self.block_size = int(block_size)
         self.root = {}          # block-token tuple -> _TrieNode
         self._tick = 0
         self.nodes = 0
+        self._heap = []         # (tick, seq, node): eviction candidates
+        self._seq = 0           # orders entries of one tick: nodes do not
+        self.reclaim_calls = 0
+        self.blocks_reclaimed = 0
+        self.reclaim_s = 0.0
 
     def _blocks_of(self, tokens, limit=None):
         bs = self.block_size
@@ -222,19 +254,49 @@ class PrefixTrie:
         return [tuple(int(t) for t in tokens[i * bs:(i + 1) * bs])
                 for i in range(n)]
 
+    def _offer(self, node):
+        """``node``, if a leaf, is an eviction candidate at its tick."""
+        if node is not None and not node.children:
+            self._seq += 1
+            heapq.heappush(self._heap, (node.tick, self._seq, node))
+
+    def walk(self):
+        """Every node, parents before their children."""
+        stack = [self.root]
+        while stack:
+            for node in stack.pop().values():
+                yield node
+                if node.children:
+                    stack.append(node.children)
+
+    def _bound_heap(self):
+        """Drop the stale entries once they outnumber the live ones:
+        the one walk left, paid for by the pushes that called for it."""
+        if len(self._heap) <= (self.HEAP_SLACK * self.nodes
+                               + self.HEAP_SLACK_MIN):
+            return
+        leaves = [node for node in self.walk() if not node.children]
+        self._heap = [(node.tick, self._seq + i, node)
+                      for i, node in enumerate(leaves, 1)]
+        self._seq += len(leaves)
+        heapq.heapify(self._heap)
+
     def match(self, tokens, limit=None):
         """Physical block ids of the longest resident whole-block
         prefix of ``tokens`` (at most ``limit`` blocks); touches the
         matched path's LRU ticks."""
         self._tick += 1
-        out, children = [], self.root
+        out, children, node = [], self.root, None
         for key in self._blocks_of(tokens, limit):
-            node = children.get(key)
-            if node is None:
+            nxt = children.get(key)
+            if nxt is None:
                 break
+            node = nxt
             node.tick = self._tick
             out.append(node.block)
             children = node.children
+        self._offer(node)           # a leaf here has a new tick
+        self._bound_heap()
         return out
 
     def insert(self, tokens, phys_blocks, incref):
@@ -243,43 +305,52 @@ class PrefixTrie:
         own (content-identical) blocks; each NEWLY created node calls
         ``incref(block)`` to take the trie's reference."""
         self._tick += 1
-        children = self.root
+        children, node = self.root, None
         for key, block in zip(self._blocks_of(tokens), phys_blocks):
-            node = children.get(key)
-            if node is None:
-                node = _TrieNode(block, self._tick)
-                children[key] = node
+            nxt = children.get(key)
+            if nxt is None:
+                nxt = _TrieNode(block, self._tick, node, key)
+                children[key] = nxt
                 self.nodes += 1
-                incref(node.block)
+                incref(nxt.block)
             else:
-                node.tick = self._tick
+                nxt.tick = self._tick
+            node = nxt
             children = node.children
+        self._offer(node)           # the new leaf, or an old one touched
+        self._bound_heap()
 
     def reclaim(self, need, refcount, release):
         """Evict least-recently-matched leaf nodes whose blocks are
         trie-only (``refcount[block] == 1``) until ``need`` blocks were
         released or nothing else is evictable.  Returns the count
         released.  Evicting a leaf may expose its parent as the next
-        candidate, so the scan loops to fixpoint."""
-        freed = 0
-        while freed < need:
-            best = None  # (tick, parent_children, key, node)
-            stack = [self.root]
-            while stack:
-                children = stack.pop()
-                for key, node in children.items():
-                    if node.children:
-                        stack.append(node.children)
-                    elif refcount[node.block] == 1 and (
-                            best is None or node.tick < best[0]):
-                        best = (node.tick, children, key, node)
-            if best is None:
-                return freed
-            _, children, key, node = best
-            del children[key]
+        candidate.  The cost is that of the entries popped — the blocks
+        released, the stale ones, and one leaf per session that holds
+        its own — whatever the trie's size."""
+        t0 = time.perf_counter()
+        heap, held, freed = self._heap, [], 0
+        while freed < need and heap:
+            entry = heapq.heappop(heap)
+            tick, _seq, node = entry
+            if node.tick != tick or node.children:
+                continue            # evicted, touched since, or grown
+            if refcount[node.block] != 1:
+                held.append(entry)  # a session's: back when we are done
+                continue
+            parent = node.parent
+            del (self.root if parent is None else parent.children)[node.key]
+            node.tick = None
             self.nodes -= 1
             release(node.block)
             freed += 1
+            self._offer(parent)
+        for entry in held:
+            heapq.heappush(heap, entry)
+        self._bound_heap()
+        self.reclaim_calls += 1
+        self.blocks_reclaimed += freed
+        self.reclaim_s += time.perf_counter() - t0
         return freed
 
 
@@ -340,9 +411,13 @@ class PagedKVCache(_Pools):
         """``n`` fresh private blocks (refcount 1 each), reclaiming
         trie-only blocks LRU-first if the free list runs dry; raises
         :class:`CacheOOM` when live sessions hold everything."""
-        if n > len(self._free_blocks) and self.trie is not None:
-            self.trie.reclaim(n - len(self._free_blocks), self.refcount,
-                              self._release)
+        short = n - len(self._free_blocks)
+        if short > 0 and self.trie is not None:
+            # a span only where the trie has to give blocks back
+            with telemetry.span(telemetry.DECODE_ALLOC_BLOCKS,
+                                blocks=n) as sp:
+                sp.add(reclaimed=self.trie.reclaim(
+                    short, self.refcount, self._release))
         if n > len(self._free_blocks):
             raise CacheOOM(
                 f"need {n} blocks, {len(self._free_blocks)} free "
@@ -485,12 +560,8 @@ class PagedKVCache(_Pools):
             for b in self.block_tables[slot, :self._nblocks[slot]]:
                 refs[int(b)] += 1
         if self.trie is not None:
-            stack = [self.trie.root]
-            while stack:
-                children = stack.pop()
-                for node in children.values():
-                    refs[node.block] += 1
-                    stack.append(node.children)
+            for node in self.trie.walk():
+                refs[node.block] += 1
         if not np.array_equal(refs, self.refcount):
             bad = np.nonzero(refs != self.refcount)[0]
             raise AssertionError(

@@ -411,6 +411,14 @@ class DecodeEngine:
                 out["cache"] = {"row_bytes": cache.row_bytes,
                                 "live_tokens": int(cache.lengths.sum()),
                                 "live_token_steps": self._live_token_steps}
+                trie = cache.trie
+                if trie is not None:
+                    # totals since the engine started, like ``phase_s``
+                    out["cache"].update(
+                        trie_nodes=trie.nodes,
+                        blocks_reclaimed=trie.blocks_reclaimed,
+                        reclaim_calls=trie.reclaim_calls,
+                        reclaim_s=round(trie.reclaim_s, 6))
         fns = self._fns
         if fns is not None and fns.summarize is not None:
             # since the engine started; ``step_counters`` are the raw

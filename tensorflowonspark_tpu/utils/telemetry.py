@@ -125,6 +125,7 @@ DECODE_ADMIT_SPAN = "tfos/decode/admit"
 DECODE_TRIE_MATCH = "tfos/decode/trie_match"
 DECODE_PREFILL = "tfos/decode/prefill"
 DECODE_KV_INSERT = "tfos/decode/kv_insert"
+DECODE_ALLOC_BLOCKS = "tfos/decode/alloc_blocks"  # only when the trie evicts
 DECODE_ITERATE = "tfos/decode/iterate"
 DECODE_BUILD_WINDOW = "tfos/decode/build_window"
 DECODE_STEP_DISPATCH = "tfos/decode/step_dispatch"

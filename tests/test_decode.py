@@ -294,6 +294,243 @@ def test_trie_reclaim_releases_what_a_walk_per_block_would(seed):
         assert released[0]      # something was evictable
 
 
+def _walk_reclaim(trie, need, refcount, release):
+    """The eviction order, found by one walk of the trie per block
+    released: the oracle of the tests below (the pinned test above
+    keeps its own copy)."""
+    freed = 0
+    while freed < need:
+        best, stack = None, [trie.root]
+        while stack:
+            children = stack.pop()
+            for key, node in children.items():
+                if node.children:
+                    stack.append(node.children)
+                elif refcount[node.block] == 1 and (
+                        best is None or node.tick < best[0]):
+                    best = (node.tick, children, key, node)
+        if best is None:
+            break
+        del best[1][best[2]]
+        trie.nodes -= 1
+        release(best[3].block)
+        freed += 1
+    return freed
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_paged_kvcache_long_run_evicts_as_a_walk_per_block_would(seed):
+    """A few hundred sessions through a pool that is exactly its live
+    set, driven the way the engine drives it, beside a cache whose trie
+    evicts by the plain walk per block: the same physical blocks at
+    every allocation (so the same eviction order), no block leaked, and
+    ``nodes`` is what a walk counts."""
+    import random
+
+    from tensorflowonspark_tpu.serving.decode import kvcache
+
+    class WalkTrie(kvcache.PrefixTrie):
+        reclaim = _walk_reclaim
+
+    cfg = _cfg()
+    slots, bs = 4, 4
+    caches = [kvcache.PagedKVCache(cfg, slots, block_size=bs,
+                                   num_blocks=1 + slots * 8)
+              for _ in range(2)]
+    caches[1].trie = WalkTrie(bs)
+    rng = random.Random(seed)
+    stems = [[rng.randrange(3) for _ in range(16)] for _ in range(4)]
+    live = {}           # slot -> [length, tokens still to decode]
+    admitted = evicting = 0
+    while admitted < 300 or live:
+        if admitted < 300 and len(live) < slots and rng.random() < 0.6:
+            prompt = rng.choice(stems)[:bs * rng.randrange(0, 5)] + [
+                rng.randrange(3) for _ in range(rng.randrange(1, 12))]
+            got = []
+            for cache in caches:
+                shared, mlen = cache.match_prefix(prompt)
+                slot = cache.alloc()
+                evicting += -(-(len(prompt) - mlen) // bs) \
+                    > len(cache._free_blocks)
+                own = cache.alloc_blocks(-(-(len(prompt) - mlen) // bs))
+                cache.map_session(slot, shared, own, len(prompt))
+                cache.register_prompt(slot, prompt)
+                got.append((slot, shared, own))
+            assert got[0] == got[1]
+            live[got[0][0]] = [len(prompt), rng.randrange(1, 32 - len(prompt))]
+            admitted += 1
+        for slot in sorted(live):
+            length, left = live[slot]
+            for cache in caches:
+                cache.ensure_capacity(slot, length + 1)
+                cache.lengths[slot] += 1
+            if left == 1:
+                for cache in caches:
+                    cache.retire(slot)
+                del live[slot]
+            else:
+                live[slot] = [length + 1, left - 1]
+        assert np.array_equal(caches[0].block_tables, caches[1].block_tables)
+    assert evicting > 100         # the free list was short most times
+    for cache in caches:
+        assert cache.leaked_blocks() == []
+        assert cache.trie.nodes == sum(1 for _ in cache.trie.walk())
+    assert caches[0].trie.nodes == caches[1].trie.nodes
+    assert caches[0]._free_blocks == caches[1]._free_blocks
+    assert caches[0].trie.blocks_reclaimed > 300
+
+
+class _CountingRefs(dict):
+    reads = 0
+
+    def __getitem__(self, key):
+        self.reads += 1
+        return dict.__getitem__(self, key)
+
+
+def test_trie_reclaim_reads_no_more_refcounts_than_it_releases():
+    """The cost of ``reclaim`` without a clock: it looks at a block's
+    reference count once per leaf it pops, so releasing 64 blocks of a
+    trie of 4,000 nodes reads 64 counts and one per leaf a session
+    holds, however large the trie (a walk per block reads every leaf's,
+    64 times)."""
+    from tensorflowonspark_tpu.serving.decode import kvcache
+
+    trie, refs, slots = kvcache.PrefixTrie(1), _CountingRefs(), 16
+    for p in range(500):
+        trie.insert([p] + list(range(7)), range(8 * p, 8 * p + 8),
+                    lambda b: refs.__setitem__(b, 1))
+    assert trie.nodes == 4000
+    for p in range(slots):          # the oldest leaves: all popped first
+        refs[8 * p + 7] += 1
+    order, refs.reads = [], 0
+    assert trie.reclaim(64, refs, order.append) == 64
+    assert refs.reads <= 64 + slots + 2
+    # the first path no session holds, from its leaf up, and so on
+    assert order[:9] == [8 * slots + i for i in range(7, -1, -1)] \
+        + [8 * slots + 15]
+    walked = _CountingRefs(refs)
+    for b in order:
+        walked[b] -= 1              # as a cache's ``release`` would
+    assert _walk_reclaim(trie, 1, walked, order.append) == 1
+    assert walked.reads > 400       # one walk: every leaf left
+
+
+def test_trie_heap_stays_within_its_multiple_of_nodes():
+    """Entries gone stale (a leaf matched again, grown or evicted) are
+    dropped by a rebuild once they outnumber the nodes: a server that
+    runs for days holds a heap no larger than ``HEAP_SLACK * nodes +
+    HEAP_SLACK_MIN``."""
+    import random
+
+    from tensorflowonspark_tpu.serving.decode import kvcache
+
+    rng = random.Random(7)
+    trie, refs = kvcache.PrefixTrie(2), {}
+    free, prompts, rebuilds = list(range(400)), [], 0
+
+    def release(b):
+        del refs[b]
+        free.append(b)
+
+    def incref(b):
+        refs[b] = 1
+        free.remove(b)
+    for _ in range(10000):
+        what = rng.random()
+        if what < 0.97 and prompts:
+            before = len(trie._heap)    # a match pushes: fewer = rebuilt
+            trie.match(rng.choice(prompts))
+            rebuilds += len(trie._heap) < before
+        elif what < 0.995:
+            tokens = [rng.randrange(3)
+                      for _ in range(2 * rng.randrange(1, 9))]
+            if len(free) < 8:
+                trie.reclaim(8 - len(free), refs, release)
+            trie.insert(tokens, free[-8:], incref)
+            prompts = prompts[-63:] + [tokens]
+        else:
+            trie.reclaim(rng.randrange(1, 20), refs, release)
+        assert len(trie._heap) <= (trie.HEAP_SLACK * trie.nodes
+                                   + trie.HEAP_SLACK_MIN)
+    assert rebuilds >= 5                # the bound was met, and held
+    assert trie.nodes == sum(1 for _ in trie.walk()) == len(refs) > 100
+
+
+def test_alloc_blocks_span_only_when_the_free_list_is_short(monkeypatch):
+    from tensorflowonspark_tpu.serving.decode import kvcache
+
+    spans = []
+
+    class Span:
+        def __init__(self, name, **attrs):
+            spans.append((name, attrs))
+            self.attrs = attrs
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def add(self, **attrs):
+            self.attrs.update(attrs)
+
+    monkeypatch.setattr(kvcache.telemetry, "span", Span)
+    cache = kvcache.PagedKVCache(_cfg(), slots=1, block_size=4, num_blocks=9)
+    slot = cache.alloc()
+    cache.map_session(slot, [], cache.alloc_blocks(3), 10)
+    cache.register_prompt(slot, list(range(1, 11)))
+    cache.retire(slot)
+    got = cache.alloc_blocks(6)             # exactly the free list
+    assert spans == [] and cache.trie.reclaim_calls == 0
+    got += cache.alloc_blocks(1)            # one short: the leaf goes
+    assert spans == [("tfos/decode/alloc_blocks",
+                      {"blocks": 1, "reclaimed": 1})]
+    with pytest.raises(kvcache.CacheOOM):
+        cache.alloc_blocks(2)               # one more is all there is
+    assert spans[1:] == [("tfos/decode/alloc_blocks",
+                          {"blocks": 2, "reclaimed": 1})]
+    assert (cache.trie.reclaim_calls, cache.trie.blocks_reclaimed,
+            cache.trie.nodes) == (2, 2, 0)
+    assert cache.trie.reclaim_s > 0
+
+
+def test_engine_stats_count_the_trie_and_what_it_gave_back():
+    """``stats()["cache"]`` carries ``trie_nodes`` and, as totals since
+    the engine started that only grow, ``reclaim_calls``,
+    ``blocks_reclaimed`` and ``reclaim_s``."""
+    cfg = _cfg()
+    params = _params(cfg)
+    # the pool is the live set: retired prompts' blocks have to go
+    spec = D.DecodeSpec(cfg, slots=2, max_tokens=4, paged=True,
+                        block_size=4, num_blocks=1 + 2 * 8)
+    done = []
+    eng = D.DecodeEngine(
+        params, spec,
+        lambda kind, sid, *rest: kind in ("done", "error")
+        and done.append((kind, sid)))
+    eng.start(timeout=300)
+    snaps = []
+    try:
+        for rnd in range(2):
+            for i in range(6):
+                eng.submit((rnd, i), [1 + rnd, 2 + i] + list(range(3, 20)))
+            deadline = time.time() + 300
+            while len(done) < 6 * (rnd + 1) and time.time() < deadline:
+                time.sleep(0.01)
+            snaps.append(eng.stats()["cache"])
+    finally:
+        eng.stop()
+    assert [kind for kind, _sid in done] == ["done"] * 12
+    totals = ("reclaim_calls", "blocks_reclaimed", "reclaim_s")
+    for snap in snaps:
+        assert {"trie_nodes", *totals} <= set(snap)
+    assert snaps[0]["blocks_reclaimed"] > 0 and snaps[0]["trie_nodes"] > 0
+    assert all(snaps[1][k] > snaps[0][k] for k in totals)
+    assert snaps[1]["blocks_reclaimed"] >= snaps[1]["reclaim_calls"]
+
+
 def test_sampling_make_validation_and_pure_function():
     from tensorflowonspark_tpu.serving.decode import sampling
     assert sampling.make() is None
