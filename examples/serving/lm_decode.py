@@ -78,8 +78,8 @@ def main():
 
     with serving.Server(spec, num_replicas=args.num_replicas,
                         request_timeout=300) as srv:
-        print("warmup (first prefill/decode_step compiles are the slow "
-              "part)...")
+        print("warmup (the first prefill and decode_step_paged compiles "
+              "are the slow part)...")
         out = srv.generate(prompts[0], max_tokens=args.max_tokens,
                            timeout=300)
         ref = T.greedy_decode_reference(

@@ -14,14 +14,17 @@ attention is ops.flash_attention (pallas) unless a sequence-parallel
 attn_fn is injected.
 
 Two blocks live here.  The CLASSIC block (``Config``'s defaults:
-per-head K/V attention, GELU MLP, float32 weights) is what every
-function below the ``Config`` class implements, unchanged.  The LATENT
-block (``attn_kind="latent"``: latent attention, a gated SwiGLU MLP in
-the leading dense layers, an expert layer with a shared expert in the
-rest, weights in ``param_dtype``) is the section "The latent block";
-``init`` and ``apply`` dispatch on the config, and the serving engine
-takes either block's incremental functions and cache layout from
-``Config.decode_fns()``.
+per-head K/V attention, GELU MLP, float32 weights) has its sublayers
+written once (``_attn_inputs``, ``_mlp``, ``_last_logits``) and three
+programs over them besides ``apply``: ``prefill``, ``prefill_extend``
+and ``decode_step_paged``.  The LATENT block (``attn_kind="latent"``:
+latent attention, a gated SwiGLU MLP in the leading dense layers, an
+expert layer with a shared expert in the rest, weights in
+``param_dtype``) is the section "The latent block", with the same three
+programs.  ``init`` and ``apply`` dispatch on the config, and the serving
+engine takes either block's incremental functions and cache layout from
+``Config.decode_fns()``: one paged cache, one step (ROADMAP.md Design 4
+has what still keeps the two sets of programs apart).
 """
 
 from __future__ import annotations
@@ -228,29 +231,67 @@ def _matmul(x, w):
     ).astype(x.dtype)
 
 
-def _layer_apply(p, x, cfg, rope, attn_fn):
-    b, s, dim = x.shape
-    h, hd = cfg.n_heads, cfg.head_dim
-    cos, sin, positions = rope
+# The classic block's sublayers, each written once.  They open no
+# ``jax.named_scope``: the scopes are the stable names a device trace is
+# read by (PERF.md section 3; metadata only), and they differ between the
+# training program and the serving ones, so every caller opens its own.
 
-    # the scopes are the stable names a device trace is reduced by
-    # (benchmark/lib/program_trace.py); metadata only
-    with jax.named_scope("block/attn"):
-        y = ops.rmsnorm_reference(x, p["ln1"])
-        qkv = _matmul(y, p["wqkv"]).reshape(b, s, 3, h, hd)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        q = ops.apply_rope(q, cos, sin, positions=positions)
-        k = ops.apply_rope(k, cos, sin, positions=positions)
-        attn = attn_fn(q, k, v).reshape(b, s, dim)
-        x = x + _matmul(attn, p["wo"])
-
-    return x + _mlp(p, x)
+def _attn_inputs(p, x, cfg, cos, sin, positions):
+    """Norm, ``wqkv``, heads apart, rope on q and k at ``positions``
+    (None: the array index): ``q, k, v`` each [B, S, H, D].  Keys come
+    out POST-rotation, which is how they are cached."""
+    b, s, _ = x.shape
+    y = ops.rmsnorm_reference(x, p["ln1"])
+    qkv = _matmul(y, p["wqkv"]).reshape(b, s, 3, cfg.n_heads, cfg.head_dim)
+    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    q = ops.apply_rope(q, cos, sin, positions=positions)
+    k = ops.apply_rope(k, cos, sin, positions=positions)
+    return q, k, v
 
 
 def _mlp(p, x):
-    with jax.named_scope("block/mlp"):
-        y = ops.rmsnorm_reference(x, p["ln2"])
-        return _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
+    """The GELU MLP of ``norm(x)``, residual not added."""
+    y = ops.rmsnorm_reference(x, p["ln2"])
+    return _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
+
+
+def _block(p, x, cfg, rope, attn_fn, scope):
+    """The classic block over a whole sequence: ``(x after attention, the
+    MLP's output still to be added, k, v)``, k and v [B, S, H, D]."""
+    cos, sin, positions = rope
+    with jax.named_scope(scope + "attn"):
+        q, k, v = _attn_inputs(p, x, cfg, cos, sin, positions)
+        attn = attn_fn(q, k, v).reshape(x.shape)
+        x = x + _matmul(attn, p["wo"])
+    with jax.named_scope(scope + "mlp"):
+        return x, _mlp(p, x), k, v
+
+
+def _layer_apply(p, x, cfg, rope, attn_fn):
+    # ``jax.checkpoint`` wraps THIS function: the keys and values are
+    # dropped in here, so what remat saves does not know of them
+    x, y, _k, _v = _block(p, x, cfg, rope, attn_fn, "block/")
+    return x + y
+
+
+def _default_attn_fn(cfg, attn_fn):
+    """``attn_fn`` where the caller gave one, else the config's causal
+    attention over whole sequences."""
+    if attn_fn is not None:
+        return attn_fn
+    base = (ops.flash_attention if cfg.attn_impl == "flash"
+            else ops.mha_reference)
+    return functools.partial(base, causal=True)
+
+
+def _lm_head(params, x, logits_dtype, return_hidden):
+    with jax.named_scope("lm_head"):
+        x = ops.rmsnorm_reference(x, params["ln_f"])
+        if return_hidden:
+            return x
+        logits = _matmul(x, params["head"])
+        return (logits if logits_dtype is None
+                else logits.astype(logits_dtype))
 
 
 def apply(params, tokens, cfg: Config, *, attn_fn=None,
@@ -302,10 +343,7 @@ def apply(params, tokens, cfg: Config, *, attn_fn=None,
             "positions= implies a non-contiguous sequence layout; pass an "
             "attn_fn that masks by global position (e.g. "
             "sequence_parallel_attention(mesh, 'zigzag', causal=True))")
-    if attn_fn is None:
-        base = (ops.flash_attention if cfg.attn_impl == "flash"
-                else ops.mha_reference)
-        attn_fn = functools.partial(base, causal=True)
+    attn_fn = _default_attn_fn(cfg, attn_fn)
     dtype = cfg.compute_dtype
     with jax.named_scope("embed"):
         x = params["embed"].astype(dtype)[tokens]
@@ -339,13 +377,7 @@ def apply(params, tokens, cfg: Config, *, attn_fn=None,
         return layer_fn(layer_params, x, cfg, rope, attn_fn), None
 
     x, _ = lax.scan(body, x, params["layers"])
-    with jax.named_scope("lm_head"):
-        x = ops.rmsnorm_reference(x, params["ln_f"])
-        if return_hidden:
-            return x
-        logits = _matmul(x, params["head"])
-        return (logits if logits_dtype is None
-                else logits.astype(logits_dtype))
+    return _lm_head(params, x, logits_dtype, return_hidden)
 
 
 def _blockwise_nll(x, head, labels, block_v):
@@ -467,13 +499,13 @@ def loss_fn(params, tokens, cfg: Config, *, attn_fn=None, remat=False,
 #
 # No reference counterpart (the reference delegates all inference to TF
 # Serving, SURVEY.md §2.2): ``prefill`` runs the prompt once and hands back
-# the per-layer keys/values, ``decode_step`` extends every active slot of a
-# preallocated slot-paged cache (serving/decode/kvcache.py) by one token.
-# Both reuse the exact ``_layer_apply`` arithmetic (rmsnorm / rope / gelu
-# MLP / f32-accumulated matmuls), so a KV-cached greedy decode is
-# token-identical to re-running ``apply`` on the growing sequence —
-# ``greedy_decode_reference`` below is that oracle, and
-# tests/test_decode.py gates the parity.
+# the per-layer keys/values, ``decode_step_paged`` extends every slot of a
+# block-paged pool (serving/decode/kvcache.py) by a window of tokens,
+# ``prefill_extend`` runs a prompt's tail over a cached prefix.  All reuse
+# ``_layer_apply``'s sublayers (rmsnorm / rope / gelu MLP / f32-accumulated
+# matmuls), so a KV-cached greedy decode is token-identical to re-running
+# ``apply`` on the growing sequence — ``greedy_decode_reference`` below is
+# that oracle, and tests/test_decode.py gates the parity.
 # ---------------------------------------------------------------------------
 
 _NEG_INF = -1e30  # finite mask fill (ops.attention convention: never -inf)
@@ -486,25 +518,24 @@ def _classic_only(cfg, name):
             f"has its own (cfg.decode_fns() hands out either block's)")
 
 
+def _last_logits(params, x, lengths):
+    """Head over each row's final REAL position: [B, T, dim] -> [B, vocab]."""
+    b, t, _ = x.shape
+    x = ops.rmsnorm_reference(x, params["ln_f"])
+    if lengths is None:
+        last = jnp.full((b,), t - 1, jnp.int32)
+    else:
+        last = jnp.asarray(lengths, jnp.int32) - 1
+    x_last = jnp.take_along_axis(
+        x, jnp.clip(last, 0, t - 1)[:, None, None], axis=1)[:, 0]
+    return _matmul(x_last, params["head"]).astype(jnp.float32)
+
+
 def _layer_apply_kv(p, x, cfg, rope, attn_fn):
     """``_layer_apply`` that also returns the layer's rope-rotated keys
     and values in cache layout [B, H, S, D].  Keys are cached
     POST-rotation, so a cached entry never needs its position again."""
-    b, s, dim = x.shape
-    h, hd = cfg.n_heads, cfg.head_dim
-    cos, sin, positions = rope
-
-    with jax.named_scope("attn"):
-        y = ops.rmsnorm_reference(x, p["ln1"])
-        qkv = _matmul(y, p["wqkv"]).reshape(b, s, 3, h, hd)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        q = ops.apply_rope(q, cos, sin, positions=positions)
-        k = ops.apply_rope(k, cos, sin, positions=positions)
-        attn = attn_fn(q, k, v).reshape(b, s, dim)
-        x = x + _matmul(attn, p["wo"])
-    with jax.named_scope("mlp"):
-        y = ops.rmsnorm_reference(x, p["ln2"])
-        y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
+    x, y, k, v = _block(p, x, cfg, rope, attn_fn, "")
     with jax.named_scope("write_kv"):
         return x + y, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
@@ -516,21 +547,18 @@ def prefill(params, tokens, cfg: Config, *, lengths=None, attn_fn=None):
     prompt lengths (default: all T).  Returns ``(logits, k, v)`` —
     ``logits`` [B, vocab] float32 at each row's final REAL position (the
     next-token distribution), ``k``/``v`` [B, n_layers, n_heads, T,
-    head_dim] in the slot-cache layout (keys rope-rotated).
+    head_dim], keys rope-rotated (``PagedKVCache.insert_tail`` cuts them
+    into blocks).
 
     Padded tail positions produce garbage k/v, but they are never read:
     causal masking keeps them out of the real positions' attention here,
-    and ``decode_step`` masks to ``position <= cursor`` while its next
-    write lands AT the cursor, overwriting the first padded column.
+    and ``decode_step_paged`` masks to ``position <= cursor`` while its
+    next write lands AT the cursor, overwriting the first padded column.
     """
     _classic_only(cfg, "prefill")
-    if attn_fn is None:
-        base = (ops.flash_attention if cfg.attn_impl == "flash"
-                else ops.mha_reference)
-        attn_fn = functools.partial(base, causal=True)
-    dtype = cfg.compute_dtype
-    b, t = tokens.shape
-    x = params["embed"].astype(dtype)[tokens]
+    attn_fn = _default_attn_fn(cfg, attn_fn)
+    t = tokens.shape[1]
+    x = params["embed"].astype(cfg.compute_dtype)[tokens]
     cos, sin = ops.rope_angles(t, cfg.head_dim, cfg.rope_base)
     rope = (cos, sin, None)
 
@@ -539,102 +567,18 @@ def prefill(params, tokens, cfg: Config, *, lengths=None, attn_fn=None):
         return x, (k, v)
 
     x, (k, v) = lax.scan(body, x, params["layers"])
-    x = ops.rmsnorm_reference(x, params["ln_f"])
-    if lengths is None:
-        last = jnp.full((b,), t - 1, jnp.int32)
-    else:
-        last = jnp.asarray(lengths, jnp.int32) - 1
-    x_last = jnp.take_along_axis(
-        x, jnp.clip(last, 0, t - 1)[:, None, None], axis=1)[:, 0]
-    logits = _matmul(x_last, params["head"]).astype(jnp.float32)
     # scan stacks layers leading: [L, B, H, T, D] -> [B, L, H, T, D]
-    return logits, k.transpose(1, 0, 2, 3, 4), v.transpose(1, 0, 2, 3, 4)
-
-
-def _cache_write(cache_l, new, cursors):
-    """Write one [H, D] entry per slot at its cursor column:
-    ``cache_l`` [S, H, M, D], ``new`` [S, H, D], ``cursors`` [S]."""
-
-    def one(c, n, p):
-        return lax.dynamic_update_slice(c, n[:, None, :], (0, p, 0))
-
-    return jax.vmap(one)(cache_l, new, cursors)
-
-
-def decode_step(params, tokens, cfg: Config, cache_k, cache_v, lengths):
-    """One fused continuous-batching decode iteration over ALL slots.
-
-    ``tokens`` [S] int32 — each slot's incoming token (sitting at
-    position ``lengths[s]``); ``cache_k``/``cache_v``
-    [S, n_layers, n_heads, max_seq, head_dim] (kvcache.SlotKVCache
-    arrays, keys rope-rotated); ``lengths`` [S] int32 — tokens already
-    resident per slot.  Writes each slot's new k/v at its cursor,
-    attends over ``position <= cursor`` only, and returns
-    ``(logits [S, vocab] float32, new_cache_k, new_cache_v)``.
-
-    Free/padding slots are numerically inert by construction: with
-    length 0 and token 0 a free slot attends exactly its own position-0
-    cache column — finite garbage confined to that slot's logits row,
-    which the scheduler discards.  No operation mixes slots.
-    """
-    _classic_only(cfg, "decode_step")
-    dtype = cfg.compute_dtype
-    h, hd = cfg.n_heads, cfg.head_dim
-    s_slots = tokens.shape[0]
-    m = cache_k.shape[3]
-    lengths = jnp.asarray(lengths, jnp.int32)
-    cursors = jnp.clip(lengths, 0, m - 1)
-    pos = cursors[:, None]                              # [S, 1] rope rows
-    scale = 1.0 / (hd ** 0.5)
-    # [S, 1, M] -> broadcasts over heads in the masked-score add below
-    kv_mask = jnp.arange(m)[None, None, :] <= cursors[:, None, None]
-
-    x = params["embed"].astype(dtype)[tokens][:, None, :]   # [S, 1, dim]
-    cos, sin = ops.rope_angles(m, cfg.head_dim, cfg.rope_base)
-
-    def body(carry, inp):
-        x, = carry
-        p, ck_l, cv_l = inp                     # ck_l/cv_l: [S, H, M, D]
-        y = ops.rmsnorm_reference(x, p["ln1"])
-        qkv = _matmul(y, p["wqkv"]).reshape(s_slots, 1, 3, h, hd)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        q = ops.apply_rope(q, cos, sin, positions=pos)
-        k = ops.apply_rope(k, cos, sin, positions=pos)
-        ck_l = _cache_write(ck_l, k[:, 0], cursors)
-        cv_l = _cache_write(cv_l, v[:, 0], cursors)
-        # f32 masked softmax, ops.mha_reference convention
-        qf = q[:, 0].astype(jnp.float32)                      # [S, H, D]
-        scores = jnp.einsum(
-            "shd,shmd->shm", qf, ck_l.astype(jnp.float32)) * scale
-        scores = jnp.where(kv_mask, scores, _NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum(
-            "shm,shmd->shd", probs, cv_l.astype(jnp.float32))
-        attn = attn.astype(dtype).reshape(s_slots, 1, h * hd)
-        x = x + _matmul(attn, p["wo"])
-        y = ops.rmsnorm_reference(x, p["ln2"])
-        y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
-        return (x + y,), (ck_l, cv_l)
-
-    # scan over layers: cache arrives [S, L, ...] -> scan axis leading
-    (x,), (new_k, new_v) = lax.scan(
-        body, (x,),
-        (params["layers"],
-         cache_k.transpose(1, 0, 2, 3, 4), cache_v.transpose(1, 0, 2, 3, 4)))
-    x = ops.rmsnorm_reference(x, params["ln_f"])
-    logits = _matmul(x[:, 0], params["head"]).astype(jnp.float32)
-    return (logits,
-            new_k.transpose(1, 0, 2, 3, 4),
-            new_v.transpose(1, 0, 2, 3, 4))
+    return (_last_logits(params, x, lengths),
+            k.transpose(1, 0, 2, 3, 4), v.transpose(1, 0, 2, 3, 4))
 
 
 def decode_step_paged(params, tokens, cfg: Config, pool_k, pool_v,
                       block_tables, lengths):
     """One fused decode iteration over a block-paged KV pool.
 
-    The windowed generalization of ``decode_step`` for
-    ``kvcache.PagedKVCache``: ``tokens`` [S, W] int32 is a WINDOW of W
-    tokens per slot (W=1 is the plain paged step; W=K is the
+    The step of ``kvcache.PagedKVCache``, continuous batching over ALL
+    slots: ``tokens`` [S, W] int32 is a WINDOW of W tokens per slot (W=1
+    is the plain step, and the draft model's; W=K is the
     speculative-verify step over a draft window), token j of slot s
     sitting at logical position ``lengths[s] + j``.  ``pool_k``/
     ``pool_v`` are the shared pools [num_blocks, n_layers, n_heads,
@@ -650,8 +594,10 @@ def decode_step_paged(params, tokens, cfg: Config, pool_k, pool_v,
     Query j attends ``position <= lengths[s] + j`` — causal inside the
     window, and stale entries past a rejected draft's rollback cursor
     are unreachable until a later (correct) write lands on them.  Free
-    slots (length 0, all-sentinel table) stay numerically inert exactly
-    as in ``decode_step``.
+    slots are numerically inert by construction: with length 0, token 0
+    and an all-sentinel table a free slot attends the sentinel block
+    only — finite garbage confined to that slot's logits rows, which the
+    scheduler discards.  No operation mixes slots.
     """
     _classic_only(cfg, "decode_step_paged")
     dtype = cfg.compute_dtype
@@ -687,11 +633,7 @@ def decode_step_paged(params, tokens, cfg: Config, pool_k, pool_v,
         x, = carry
         p, pk_l, pv_l = inp             # pk_l/pv_l: [NB, H, bs, D]
         with jax.named_scope("attn"):
-            y = ops.rmsnorm_reference(x, p["ln1"])
-            qkv = _matmul(y, p["wqkv"]).reshape(s_slots, w, 3, h, hd)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            q = ops.apply_rope(q, cos, sin, positions=posc)
-            k = ops.apply_rope(k, cos, sin, positions=posc)
+            q, k, v = _attn_inputs(p, x, cfg, cos, sin, posc)
         with jax.named_scope("write_kv"):
             # flatten pool block axis with its in-block axis: [NB*bs, H, D]
             pk_f = pk_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
@@ -710,8 +652,7 @@ def decode_step_paged(params, tokens, cfg: Config, pool_k, pool_v,
             attn = attn.astype(dtype).reshape(s_slots, w, h * hd)
             x = x + _matmul(attn, p["wo"])
         with jax.named_scope("mlp"):
-            y = ops.rmsnorm_reference(x, p["ln2"])
-            y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
+            y = _mlp(p, x)
         with jax.named_scope("write_kv"):
             pk_l = pk_f.reshape(nb, bs, h, hd).transpose(0, 2, 1, 3)
             pv_l = pv_f.reshape(nb, bs, h, hd).transpose(0, 2, 1, 3)
@@ -776,11 +717,7 @@ def prefill_extend(params, tokens, cfg: Config, pool_k, pool_v,
         x, = carry
         p, pk_l, pv_l = inp
         with jax.named_scope("attn"):
-            y = ops.rmsnorm_reference(x, p["ln1"])
-            qkv = _matmul(y, p["wqkv"]).reshape(b, t, 3, h, hd)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            q = ops.apply_rope(q, cos, sin, positions=pos)
-            k = ops.apply_rope(k, cos, sin, positions=pos)
+            q, k, v = _attn_inputs(p, x, cfg, cos, sin, pos)
         with jax.named_scope("gather_kv"):
             pk_f = pk_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
             pv_f = pv_l.transpose(0, 2, 1, 3).reshape(nb * bs, h, hd)
@@ -802,8 +739,7 @@ def prefill_extend(params, tokens, cfg: Config, pool_k, pool_v,
             attn = attn.astype(dtype).reshape(b, t, h * hd)
             x = x + _matmul(attn, p["wo"])
         with jax.named_scope("mlp"):
-            y = ops.rmsnorm_reference(x, p["ln2"])
-            y = _matmul(jax.nn.gelu(_matmul(y, p["w1"])), p["w2"])
+            y = _mlp(p, x)
         with jax.named_scope("write_kv"):
             return (x + y,), (k.transpose(0, 2, 1, 3),
                               v.transpose(0, 2, 1, 3))
@@ -812,15 +748,8 @@ def prefill_extend(params, tokens, cfg: Config, pool_k, pool_v,
         layer, (x,),
         (params["layers"],
          pool_k.transpose(1, 0, 2, 3, 4), pool_v.transpose(1, 0, 2, 3, 4)))
-    x = ops.rmsnorm_reference(x, params["ln_f"])
-    if lengths is None:
-        last = jnp.full((b,), t - 1, jnp.int32)
-    else:
-        last = jnp.asarray(lengths, jnp.int32) - 1
-    x_last = jnp.take_along_axis(
-        x, jnp.clip(last, 0, t - 1)[:, None, None], axis=1)[:, 0]
-    logits = _matmul(x_last, params["head"]).astype(jnp.float32)
-    return logits, k.transpose(1, 0, 2, 3, 4), v.transpose(1, 0, 2, 3, 4)
+    return (_last_logits(params, x, lengths),
+            k.transpose(1, 0, 2, 3, 4), v.transpose(1, 0, 2, 3, 4))
 
 
 # ---------------------------------------------------------------------------
@@ -914,31 +843,10 @@ def _latent_layers(params, cfg, carry, layer_fn):
     return carry, scanned, stats
 
 
-def _expanded_attn_fn(cfg, attn_fn):
-    if attn_fn is not None:
-        return attn_fn
-    base = (ops.flash_attention if cfg.attn_impl == "flash"
-            else ops.mha_reference)
-    return functools.partial(base, causal=True)
-
-
-def _last_logits(params, x, lengths):
-    """Head over each row's final REAL position: [B, T, dim] -> [B, vocab]."""
-    b, t, _ = x.shape
-    x = ops.rmsnorm_reference(x, params["ln_f"])
-    if lengths is None:
-        last = jnp.full((b,), t - 1, jnp.int32)
-    else:
-        last = jnp.asarray(lengths, jnp.int32) - 1
-    x_last = jnp.take_along_axis(
-        x, jnp.clip(last, 0, t - 1)[:, None, None], axis=1)[:, 0]
-    return _matmul(x_last, params["head"]).astype(jnp.float32)
-
-
 def _latent_forward(params, tokens, cfg, attn_fn):
     """Whole-sequence pass on the expanded path: ``(hidden [B, T, dim],
     rows [n_layers, B, T, latent_row])``."""
-    attn_fn = _expanded_attn_fn(cfg, attn_fn)
+    attn_fn = _default_attn_fn(cfg, attn_fn)
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.compute_dtype)[tokens]
     cos, sin = latent.rope_tables(cfg, tokens.shape[1])
@@ -958,13 +866,7 @@ def _latent_forward(params, tokens, cfg, attn_fn):
 def _latent_apply(params, tokens, cfg, *, attn_fn, logits_dtype,
                   return_hidden):
     x, _rows = _latent_forward(params, tokens, cfg, attn_fn)
-    with jax.named_scope("lm_head"):
-        x = ops.rmsnorm_reference(x, params["ln_f"])
-        if return_hidden:
-            return x
-        logits = _matmul(x, params["head"])
-        return (logits if logits_dtype is None
-                else logits.astype(logits_dtype))
+    return _lm_head(params, x, logits_dtype, return_hidden)
 
 
 def latent_prefill(params, tokens, cfg: Config, *, lengths=None,
@@ -1104,8 +1006,6 @@ class DecodeFns:
     empty) dict of scalars: the engine sums each over steps (keeps the
     maximum of a name ending in ``_max``) and ``summarize(totals)`` turns
     the totals into what ``stats()`` shows.
-    ``decode_step(params, tokens [S], caches, lengths) -> (logits,
-    caches)`` is the unpaged step, or None where the model has none.
     ``donate``: the paged step may overwrite the pools it is given (the
     cache's insert always does).
     """
@@ -1113,7 +1013,6 @@ class DecodeFns:
     prefill: object
     prefill_extend: object
     decode_step_paged: object
-    decode_step: object = None
     donate: bool = False
     summarize: object = None
 
@@ -1152,14 +1051,9 @@ def _decode_fns(cfg):
                                              lens)
             return logits, (k, v), {}
 
-        def step_fn(p, toks, caches, lens):
-            logits, k, v = decode_step(p, toks, cfg, *caches, lens)
-            return logits, (k, v)
-
         return DecodeFns(rows=(("k", per_head), ("v", per_head)),
                          prefill=prefill_fn, prefill_extend=extend_fn,
-                         decode_step_paged=step_paged_fn,
-                         decode_step=step_fn)
+                         decode_step_paged=step_paged_fn)
 
     def prefill_fn(p, toks, lens):
         logits, rows = latent_prefill(p, toks, cfg, lengths=lens)

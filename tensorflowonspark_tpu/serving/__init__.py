@@ -18,7 +18,7 @@ Pieces:
   - :mod:`~tensorflowonspark_tpu.serving.server` — in-process Client,
     stdlib HTTP endpoint, SLO stats, ``tfos-serve`` CLI;
   - :mod:`~tensorflowonspark_tpu.serving.decode` — continuous-batching
-    autoregressive decode (slot-paged KV cache, iteration-level
+    autoregressive decode (block-paged KV cache, iteration-level
     scheduler, open-loop load generator).
 """
 

@@ -7,8 +7,8 @@ in-framework LLM decode path on the existing serving runtime:
 
   - :mod:`~tensorflowonspark_tpu.serving.decode.kvcache` — the
     block-paged :class:`~.kvcache.PagedKVCache` (ref-counted prefix
-    sharing through a prompt trie) plus the legacy slot-paged
-    :class:`~.kvcache.SlotKVCache`, one page per session;
+    sharing through a prompt trie), the one cache of the engine and of
+    a speculative draft model;
   - :mod:`~tensorflowonspark_tpu.serving.decode.scheduler` —
     iteration-level continuous batcher (mid-flight admission, one fused
     decode step per iteration, immediate slot retirement; prefix-hit
@@ -22,8 +22,9 @@ in-framework LLM decode path on the existing serving runtime:
     shared-prefix traffic mix for the prefix-reuse bench lane.
 
 The model half lives in ``models/transformer.py`` (``prefill``,
-``prefill_extend``, ``decode_step``, ``decode_step_paged``,
-``greedy_decode_reference``); the frontend half in
+``prefill_extend``, ``decode_step_paged``,
+``greedy_decode_reference``, reached through ``Config.decode_fns()``);
+the frontend half in
 ``serving/server.py`` (``Server.generate``, ``POST /v1/generate``).
 """
 

@@ -1,4 +1,4 @@
-"""Slot- and block-paged caches for continuous-batching decode.
+"""The block-paged cache for continuous-batching decode.
 
 No reference counterpart (the reference delegates all inference to TF
 Serving, SURVEY.md §2.2; reference Inference.scala:27-79 is offline
@@ -12,18 +12,13 @@ with ``None`` where the token axis goes (per-head keys and values:
 n_layers, *shape]`` arrays from that, reaches them as ``cache.pools`` (a
 tuple in the layout's order, also ``cache.<name>``), and moves rows in
 and out along the token axis; everything else — slots, block tables,
-refcounts, the trie — never looks inside a row.  Two tiers:
+refcounts, the trie — never looks inside a row.
 
-:class:`SlotKVCache` — vLLM-style paging simplified to one page per
-session: preallocated ``[slots, n_layers, ...max_seq...]`` arrays plus a
-per-slot length cursor.  Admission/retirement are O(1) (pop/push a free
-slot) and the model's fused unpaged step always sees the same
-``[slots, ...]`` arrays, so it compiles exactly once.
-
-:class:`PagedKVCache` — full block paging with ref-counted prefix
-sharing: a pool is ``[num_blocks, n_layers, ...block_size...]`` and each
-slot maps logical positions through a per-slot block-table row (the
-model's paged step gathers through it). Blocks carry refcounts, so
+:class:`PagedKVCache` — block paging with ref-counted prefix sharing,
+the one cache there is (a speculative draft model has a second instance
+without a trie): a pool is ``[num_blocks, n_layers, ...block_size...]``
+and each slot maps logical positions through a per-slot block-table row
+(the model's paged step gathers through it). Blocks carry refcounts, so
 admission can map a new request's matched prompt-prefix blocks from the
 :class:`PrefixTrie` (bumping refcounts) instead of re-prefilling them —
 only the unmatched tail is prefilled, and tail writes always land in
@@ -39,8 +34,7 @@ block costs a heap operation, whatever the trie holds.
 Physical block 0 is a reserved SENTINEL: free slots' table rows point
 at it, so their numerically-inert writes (and the padded rows of a
 bucketed ``prefill_extend``) land in a block no live session ever
-attends to — the paged analogue of SlotKVCache's stale-own-page
-contract.  Capacity is validated so live sessions can never be starved:
+attends to.  Capacity is validated so live sessions can never be starved:
 ``num_blocks - 1 >= slots * blocks_per_slot`` and everything above the
 sentinel that is not session-referenced is trie-reclaimable.
 
@@ -92,103 +86,8 @@ def _kv_insert(token_axes, block_size):
     return jax.jit(tfos_kv_insert, donate_argnums=(0,))
 
 
-class _Pools:
-    """The device arrays of a cache, allocated from the model's row
-    layout: ``pools`` in the layout's order, each also an attribute under
-    its layout name."""
-
-    def _allocate(self, cfg, lead, tokens, dtype):
-        import jax.numpy as jnp
-
-        fns = cfg.decode_fns()
-        self.layout = fns.rows
-        self.dtype = dtype or cfg.compute_dtype
-        self._token_axes = tuple(shape.index(None)
-                                 for _name, shape in fns.rows)
-        self.pools = tuple(
-            jnp.zeros((lead, cfg.n_layers)
-                      + tuple(tokens if d is None else d for d in shape),
-                      self.dtype)
-            for _name, shape in fns.rows)
-        return fns
-
-    def __getattr__(self, name):
-        # only reached for names not found the normal way
-        layout = self.__dict__.get("layout", ())
-        for i, (pool_name, _shape) in enumerate(layout):
-            if pool_name == name:
-                return self.pools[i]
-        raise AttributeError(name)
-
-    @property
-    def row_bytes(self):
-        """Bytes one cached token takes, all layers and pools."""
-        per_layer = sum(
-            int(np.prod([d for d in shape if d is not None]))
-            for _name, shape in self.layout)
-        return per_layer * self.pools[0].shape[1] \
-            * np.dtype(self.dtype).itemsize
-
-
 class CacheOOM(RuntimeError):
     """Block allocation failed even after trie reclamation."""
-
-
-class SlotKVCache(_Pools):
-    """Preallocated per-slot pages + host-side cursor/free-list."""
-
-    def __init__(self, cfg, slots, max_seq=None, dtype=None):
-        self.slots = int(slots)
-        if self.slots < 1:
-            raise ValueError("need at least one slot")
-        self.max_seq = int(max_seq or cfg.max_seq)
-        if self._allocate(cfg, self.slots, self.max_seq,
-                          dtype).decode_step is None:
-            raise ValueError(
-                "this model has no unpaged decode step: serve it with "
-                "DecodeSpec(paged=True)")
-        # host mirrors: the scheduler reads/writes these every iteration
-        # without a device round-trip
-        self.lengths = np.zeros((self.slots,), np.int32)
-        self._free = list(range(self.slots - 1, -1, -1))  # pop() -> slot 0
-
-    # -- slot lifecycle -----------------------------------------------------
-    def alloc(self):
-        """A free slot index, or None when the cache is full."""
-        return self._free.pop() if self._free else None
-
-    def retire(self, slot):
-        """Return ``slot`` to the free list (cursor back to 0; the page
-        itself is left stale — see the inertness contract above)."""
-        if slot in self._free:
-            raise ValueError(f"slot {slot} is already free")
-        self.lengths[slot] = 0
-        self._free.append(slot)
-
-    def insert(self, slot, *rows_length):
-        """``insert(slot, *rows, length)``: install a prefill result, one
-        ``[n_layers, ...T...]`` array per pool, into ``slot``'s first T
-        columns, cursor to ``length`` (<= T <= max_seq)."""
-        *rows, length = rows_length
-        pools = []
-        for pool, r, ax in zip(self.pools, rows, self._token_axes):
-            t = r.shape[ax + 1]
-            if t > self.max_seq:
-                raise ValueError(
-                    f"prefill length {t} > max_seq {self.max_seq}")
-            at = (slot,) + (slice(None),) * (ax + 1) + (slice(0, t),)
-            pools.append(pool.at[at].set(r.astype(self.dtype)))
-        self.pools = tuple(pools)
-        self.lengths[slot] = int(length)
-
-    # -- introspection ------------------------------------------------------
-    @property
-    def occupancy(self):
-        return self.slots - len(self._free)
-
-    @property
-    def free_slots(self):
-        return len(self._free)
 
 
 class _TrieNode:
@@ -354,19 +253,22 @@ class PrefixTrie:
         return freed
 
 
-class PagedKVCache(_Pools):
+class PagedKVCache:
     """Block-paged pools + per-slot block tables + prefix trie.
 
-    Device side: ``pools``, each ``[num_blocks, n_layers,
-    ...block_size...]`` as the model's row layout says.  Host side:
-    ``block_tables`` [slots, blocks_per_slot] int32 (unused entries point
-    at sentinel block 0), ``lengths`` [slots], ``refcount`` [num_blocks],
-    a block free list and a slot free list.  The model's paged step and
-    tail prefill consume the pools + tables directly.
+    Device side: ``pools`` in the order of the model's row layout, each
+    ``[num_blocks, n_layers, ...block_size...]`` and also an attribute
+    under its layout name.  Host side: ``block_tables`` [slots,
+    blocks_per_slot] int32 (unused entries point at sentinel block 0),
+    ``lengths`` [slots], ``refcount`` [num_blocks], a block free list and
+    a slot free list.  The model's paged step and tail prefill consume
+    the pools + tables directly.
     """
 
     def __init__(self, cfg, slots, block_size=None, num_blocks=None,
                  max_seq=None, dtype=None, prefix_sharing=True):
+        import jax.numpy as jnp
+
         self.slots = int(slots)
         if self.slots < 1:
             raise ValueError("need at least one slot")
@@ -385,7 +287,15 @@ class PagedKVCache(_Pools):
                 f"num_blocks {self.num_blocks} < sentinel + "
                 f"slots*blocks_per_slot = {min_blocks}: live sessions "
                 "could starve")
-        self._allocate(cfg, self.num_blocks, self.block_size, dtype)
+        self.layout = cfg.decode_fns().rows
+        self.dtype = dtype or cfg.compute_dtype
+        self._token_axes = tuple(shape.index(None)
+                                 for _name, shape in self.layout)
+        self.pools = tuple(
+            jnp.zeros((self.num_blocks, cfg.n_layers)
+                      + tuple(self.block_size if d is None else d
+                              for d in shape), self.dtype)
+            for _name, shape in self.layout)
         self.block_tables = np.zeros((self.slots, self.blocks_per_slot),
                                      np.int32)
         self.lengths = np.zeros((self.slots,), np.int32)
@@ -395,6 +305,23 @@ class PagedKVCache(_Pools):
         self._free_blocks = list(range(self.num_blocks - 1, 0, -1))
         self._free = list(range(self.slots - 1, -1, -1))
         self.trie = PrefixTrie(self.block_size) if prefix_sharing else None
+
+    def __getattr__(self, name):
+        # only reached for names not found the normal way
+        layout = self.__dict__.get("layout", ())
+        for i, (pool_name, _shape) in enumerate(layout):
+            if pool_name == name:
+                return self.pools[i]
+        raise AttributeError(name)
+
+    @property
+    def row_bytes(self):
+        """Bytes one cached token takes, all layers and pools."""
+        per_layer = sum(
+            int(np.prod([d for d in shape if d is not None]))
+            for _name, shape in self.layout)
+        return per_layer * self.pools[0].shape[1] \
+            * np.dtype(self.dtype).itemsize
 
     # -- block accounting ---------------------------------------------------
     def _incref(self, block):
@@ -432,13 +359,6 @@ class PagedKVCache(_Pools):
         """A free slot index, or None when all slots are occupied
         (blocks are allocated separately via :meth:`map_session`)."""
         return self._free.pop() if self._free else None
-
-    def free_slot(self, slot):
-        """Undo a bare :meth:`alloc` (admission rollback before any
-        blocks were mapped)."""
-        if slot in self._free:
-            raise ValueError(f"slot {slot} is already free")
-        self._free.append(slot)
 
     def map_session(self, slot, shared_blocks, own_blocks, length):
         """Install a session's block-table row: ``shared_blocks``

@@ -12,7 +12,7 @@ so the driver-side IndexLedger dedupe (first arrival wins) sees zero
 drift.  It is also what makes speculative decoding exact rather than
 merely distribution-preserving: the verify step recomputes the target
 sample at each index and accepts a draft token only when it EQUALS
-that sample (scheduler._iterate_spec), so spec output == plain output
+that sample (scheduler._iterate), so spec output == plain output
 at the same seed by construction.
 
 Per-index keying uses ``numpy.random.default_rng([seed, index])`` —

@@ -16,24 +16,25 @@ scheduler the serving tier mounts behind
   immediately and the slot is eligible for re-admission in the same
   loop pass.
 
-Three engine upgrades ride the same loop (all default-on via env,
-all token-exact against the full-recompute oracle):
+Three mechanisms ride the one loop (all token-exact against the
+full-recompute oracle):
 
-- **Block-paged KV with prefix sharing** (``TFOS_DECODE_PAGED``):
-  the cache is a :class:`~.kvcache.PagedKVCache`; admission matches
-  each prompt against the resident prefix trie and maps the shared
-  blocks (refcount bump) instead of re-prefilling them — only the
-  unmatched tail runs the model's tail prefill.
+- **Block-paged KV with prefix sharing**: the cache is a
+  :class:`~.kvcache.PagedKVCache`, the only kind there is; admission
+  matches each prompt against the resident prefix trie and maps the
+  shared blocks (refcount bump) instead of re-prefilling them — only
+  the unmatched tail runs the model's tail prefill.
 - **Seeded sampling** (per-session temperature/top-k/top-p/seed,
   ``serving/decode/sampling.py``): logits come back to the host and
   the token is a pure function of ``(logits, params, index)``, so a
   failover replay re-draws the identical stream.
 - **Speculative decoding** (``spec_window`` + a draft model): the
-  draft proposes K-1 tokens, the verify step is ONE windowed paged
-  step over the K-token window, and a draft token is
-  accepted iff it EQUALS the target's seeded sample at that index —
-  so speculative output is byte-identical to non-speculative at the
-  same seed, not merely distribution-preserving.
+  draft, on a paged cache of its own with the same slots, proposes K-1
+  tokens, the verify step is ONE windowed paged step over the K-token
+  window, and a draft token is accepted iff it EQUALS the target's
+  seeded sample at that index — so speculative output is byte-identical
+  to non-speculative at the same seed, not merely
+  distribution-preserving.
 
 Tokens stream back through the resolve-once machinery the predict path
 already uses (batcher.PendingResult semantics): the driver-side
@@ -45,9 +46,9 @@ zero dup by construction.
 
 The engine knows nothing of the model but its seam: ``cfg.decode_fns()``
 (``models/transformer.DecodeFns``) hands it the incremental functions
-(prefill, tail prefill, paged step, unpaged step) under one signature
-each and the cache's row layout; the caches allocate their pools from
-that layout and the engine passes them through as a tuple.
+(prefill, tail prefill, paged step) under one signature each and the
+cache's row layout; the caches allocate their pools from that layout
+and the engine passes them through as a tuple.
 
 Module import stays stdlib + numpy (driver-importable); jax and the
 model only load inside :class:`DecodeEngine`'s replica-side thread.
@@ -65,46 +66,19 @@ import numpy as np
 
 from tensorflowonspark_tpu.actors.ledger import IndexLedger, ResolveOnce
 from tensorflowonspark_tpu.serving import batcher as _batcher
+from tensorflowonspark_tpu.serving.decode import kvcache as _kvcache
 from tensorflowonspark_tpu.serving.decode import sampling as _sampling
 from tensorflowonspark_tpu.utils import faults, metrics_registry, telemetry
 
 logger = logging.getLogger(__name__)
 
-SLOTS_ENV = "TFOS_DECODE_SLOTS"
 QUEUE_MAX_ENV = "TFOS_DECODE_QUEUE_MAX"
-MAX_TOKENS_ENV = "TFOS_DECODE_MAX_TOKENS"
-PAGED_ENV = "TFOS_DECODE_PAGED"
-BLOCK_ENV = "TFOS_DECODE_BLOCK"
-PREFIX_SHARING_ENV = "TFOS_DECODE_PREFIX_SHARING"
-SPEC_WINDOW_ENV = "TFOS_DECODE_SPEC_WINDOW"
-
-
-def slots_default():
-    return int(os.environ.get(SLOTS_ENV, "8"))
 
 
 def queue_max_default():
+    """A deployment's admission bound (``serving/server.py``): no field
+    of :class:`DecodeSpec`."""
     return int(os.environ.get(QUEUE_MAX_ENV, "64"))
-
-
-def max_tokens_default():
-    return int(os.environ.get(MAX_TOKENS_ENV, "64"))
-
-
-def paged_default():
-    return os.environ.get(PAGED_ENV, "1") != "0"
-
-
-def block_size_default():
-    return int(os.environ.get(BLOCK_ENV, "16"))
-
-
-def prefix_sharing_default():
-    return os.environ.get(PREFIX_SHARING_ENV, "1") != "0"
-
-
-def spec_window_default():
-    return int(os.environ.get(SPEC_WINDOW_ENV, "4"))
 
 
 class DecodeSpec:
@@ -116,14 +90,14 @@ class DecodeSpec:
     request may override (``max_tokens`` is always clamped to the
     cache page, ``max_seq - len(prompt)``).
 
-    Paged-cache knobs (defaults from env): ``paged`` selects
-    :class:`~.kvcache.PagedKVCache` over the legacy
-    :class:`~.kvcache.SlotKVCache`; ``block_size``/``num_blocks`` size
-    it; ``prefix_sharing`` arms the prefix trie.  Speculative decoding
+    ``block_size``/``num_blocks`` size the
+    :class:`~.kvcache.PagedKVCache` (``num_blocks`` None: twice the live
+    set); ``prefix_sharing`` arms the prefix trie.  Speculative decoding
     arms when BOTH ``draft_params`` (a transformer params pytree) and
     ``draft_cfg`` are given: the draft proposes ``spec_window - 1``
-    tokens per iteration and one windowed verify step scores them
-    (paged mode only — the verify step is the paged step).
+    tokens per iteration and one windowed verify step scores them.  The
+    draft's cache is derived: the same slots and block size, the
+    sentinel plus the live set of ITS ``max_seq``, no trie.
 
     ``prefill_tokens`` bounds the padded tokens of ONE prefill program
     (rows x sequence bucket): admission cuts a wave that would exceed it
@@ -131,23 +105,20 @@ class DecodeSpec:
     many long prompts arrive together.  Default: no bound.
     """
 
-    def __init__(self, cfg, slots=None, eos_id=None, max_tokens=None,
-                 paged=None, block_size=None, num_blocks=None,
-                 prefix_sharing=None, draft_params=None, draft_cfg=None,
-                 spec_window=None, prefill_tokens=None):
+    def __init__(self, cfg, slots=8, eos_id=None, max_tokens=64,
+                 block_size=16, num_blocks=None, prefix_sharing=True,
+                 draft_params=None, draft_cfg=None, spec_window=4,
+                 prefill_tokens=None):
         self.cfg = cfg
-        self.slots = int(slots or slots_default())
+        self.slots = int(slots)
         self.eos_id = eos_id
-        self.max_tokens = int(max_tokens or max_tokens_default())
-        self.paged = paged_default() if paged is None else bool(paged)
-        self.block_size = int(block_size or block_size_default())
+        self.max_tokens = int(max_tokens)
+        self.block_size = int(block_size)
         self.num_blocks = num_blocks
-        self.prefix_sharing = (prefix_sharing_default()
-                               if prefix_sharing is None
-                               else bool(prefix_sharing))
+        self.prefix_sharing = bool(prefix_sharing)
         self.draft_params = draft_params
         self.draft_cfg = draft_cfg
-        self.spec_window = int(spec_window or spec_window_default())
+        self.spec_window = int(spec_window)
         self.prefill_tokens = (None if prefill_tokens is None
                                else int(prefill_tokens))
         if self.prefill_tokens is not None and self.prefill_tokens < 1:
@@ -159,10 +130,6 @@ class DecodeSpec:
             raise ValueError(
                 "speculative decoding needs BOTH draft_params and "
                 "draft_cfg (or neither)")
-        if draft_params is not None and not self.paged:
-            raise ValueError(
-                "speculative decoding requires paged=True (the verify "
-                "step is the paged step)")
 
     @property
     def speculative(self):
@@ -290,6 +257,7 @@ class DecodeEngine:
         self._sids = set()          # sids queued or active (dedupe)
         self._active = {}           # slot index -> _Slot
         self._cache = None          # engine-thread cache, read by stats()
+        self._dcache = None         # the draft's, where there is a draft
         self._wake = threading.Event()
         self._stop = threading.Event()
         self._thread = None
@@ -317,7 +285,7 @@ class DecodeEngine:
         # the paged step's own counters (computed on the device, fetched
         # with the logits): summed over steps, a ``*_max`` kept as maximum
         self._step_counters = {}
-        # cached positions of all sessions, summed over paged iterations:
+        # cached positions of all sessions, summed over iterations:
         # between two ``stats()`` its difference over that of
         # ``iterations`` is the mean a step gathered
         self._live_token_steps = 0
@@ -398,27 +366,24 @@ class DecodeEngine:
             "active": len(self._active),
             "queued": queued,
             "slots": self._spec.slots,
-            "paged": self._spec.paged,
             "device": self._device,
+            "prefix_hits": self.prefix_hits,
+            "prefix_tokens_saved": self.prefix_tokens_saved,
         }
-        if self._spec.paged:
-            cache = self._cache
-            out["prefix_hits"] = self.prefix_hits
-            out["prefix_tokens_saved"] = self.prefix_tokens_saved
-            out["blocks_in_use"] = (cache.blocks_in_use
-                                    if cache is not None else 0)
-            if cache is not None:
-                out["cache"] = {"row_bytes": cache.row_bytes,
-                                "live_tokens": int(cache.lengths.sum()),
-                                "live_token_steps": self._live_token_steps}
-                trie = cache.trie
-                if trie is not None:
-                    # totals since the engine started, like ``phase_s``
-                    out["cache"].update(
-                        trie_nodes=trie.nodes,
-                        blocks_reclaimed=trie.blocks_reclaimed,
-                        reclaim_calls=trie.reclaim_calls,
-                        reclaim_s=round(trie.reclaim_s, 6))
+        cache = self._cache
+        out["blocks_in_use"] = cache.blocks_in_use if cache is not None else 0
+        if cache is not None:
+            out["cache"] = {"row_bytes": cache.row_bytes,
+                            "live_tokens": int(cache.lengths.sum()),
+                            "live_token_steps": self._live_token_steps}
+            trie = cache.trie
+            if trie is not None:
+                # totals since the engine started, like ``phase_s``
+                out["cache"].update(
+                    trie_nodes=trie.nodes,
+                    blocks_reclaimed=trie.blocks_reclaimed,
+                    reclaim_calls=trie.reclaim_calls,
+                    reclaim_s=round(trie.reclaim_s, 6))
         fns = self._fns
         if fns is not None and fns.summarize is not None:
             # since the engine started; ``step_counters`` are the raw
@@ -436,18 +401,20 @@ class DecodeEngine:
     # -- engine thread ------------------------------------------------------
     def _build_caches(self):
         spec = self._spec
-        if spec.paged:
-            cache = self._kvcache_mod.PagedKVCache(
-                spec.cfg, spec.slots, block_size=spec.block_size,
-                num_blocks=spec.num_blocks,
-                prefix_sharing=spec.prefix_sharing)
-        else:
-            cache = self._kvcache_mod.SlotKVCache(spec.cfg, spec.slots)
+        cache = _kvcache.PagedKVCache(
+            spec.cfg, spec.slots, block_size=spec.block_size,
+            num_blocks=spec.num_blocks, prefix_sharing=spec.prefix_sharing)
         dcache = None
         if spec.speculative:
-            dcache = self._kvcache_mod.SlotKVCache(
-                spec.draft_cfg, spec.slots)
-        self._cache = cache
+            # the draft's own pool: the sentinel and the live set of its
+            # max_seq.  No trie: it prefills every prompt whole, and a
+            # session sits in the SAME slot of both caches (one admission
+            # order, one retirement)
+            per_slot = -(-spec.draft_cfg.max_seq // spec.block_size)
+            dcache = _kvcache.PagedKVCache(
+                spec.draft_cfg, spec.slots, block_size=spec.block_size,
+                num_blocks=1 + spec.slots * per_slot, prefix_sharing=False)
+        self._cache, self._dcache = cache, dcache
         return cache, dcache
 
     def _run(self):
@@ -456,7 +423,6 @@ class DecodeEngine:
             import jax.numpy as jnp  # noqa: F401 - jit closure imports
 
             from tensorflowonspark_tpu import tpu_info
-            from tensorflowonspark_tpu.serving.decode import kvcache
 
             spec = self._spec
             fns = self._fns = spec.cfg.decode_fns()
@@ -469,46 +435,31 @@ class DecodeEngine:
             def tfos_prefill(p, toks, lens):
                 return fns.prefill(p, toks, lens)
 
+            def tfos_prefill_extend(p, toks, pools, ptab, plens, lens):
+                return fns.prefill_extend(p, toks, pools, ptab, plens, lens)
+
+            def tfos_decode_step_paged(p, toks, pools, tables, lens):
+                return fns.decode_step_paged(p, toks, pools, tables, lens)
+
             self._prefill_jit = jax.jit(tfos_prefill)
-            if spec.paged:
-                def tfos_prefill_extend(p, toks, pools, ptab, plens, lens):
-                    return fns.prefill_extend(p, toks, pools, ptab, plens,
-                                              lens)
-
-                def tfos_decode_step_paged(p, toks, pools, tables, lens):
-                    return fns.decode_step_paged(p, toks, pools, tables,
-                                                 lens)
-
-                self._extend_jit = jax.jit(tfos_prefill_extend)
-                self._pstep_jit = jax.jit(
-                    tfos_decode_step_paged,
-                    donate_argnums=(2,) if fns.donate else ())
-            else:
-                if fns.decode_step is None:
-                    raise ValueError(
-                        "this model has no unpaged decode step: serve it "
-                        "with DecodeSpec(paged=True)")
-
-                def tfos_decode_step(p, toks, caches, lens):
-                    return fns.decode_step(p, toks, caches, lens)
-
-                self._step_jit = jax.jit(tfos_decode_step)
+            self._extend_jit = jax.jit(tfos_prefill_extend)
+            self._pstep_jit = jax.jit(
+                tfos_decode_step_paged,
+                donate_argnums=(2,) if fns.donate else ())
             if spec.speculative:
                 dfns = spec.draft_cfg.decode_fns()
-                if dfns.decode_step is None:
-                    raise ValueError(
-                        "the draft model needs an unpaged decode step "
-                        "(its cache is a SlotKVCache)")
 
                 def tfos_draft_prefill(p, toks, lens):
                     return dfns.prefill(p, toks, lens)
 
-                def tfos_draft_step(p, toks, caches, lens):
-                    return dfns.decode_step(p, toks, caches, lens)
+                def tfos_draft_step(p, toks, pools, tables, lens):
+                    return dfns.decode_step_paged(p, toks, pools, tables,
+                                                  lens)
 
                 self._dprefill_jit = jax.jit(tfos_draft_prefill)
-                self._dstep_jit = jax.jit(tfos_draft_step)
-            self._kvcache_mod = kvcache
+                self._dstep_jit = jax.jit(
+                    tfos_draft_step,
+                    donate_argnums=(2,) if dfns.donate else ())
             cache, dcache = self._build_caches()
         except BaseException as e:  # noqa: BLE001 - surface via start()
             self._init_error = e
@@ -526,10 +477,7 @@ class DecodeEngine:
                         self._wake.clear()
                     self._mark("idle")
                     continue
-                if self._spec.paged:
-                    self._iterate_paged(cache, dcache)
-                else:
-                    self._iterate(cache)
+                self._iterate(cache, dcache)
             except BaseException as e:  # noqa: BLE001 - fail the cohort,
                 # rebuild the caches, keep the replica serving
                 logger.exception("decode engine iteration failed")
@@ -540,12 +488,12 @@ class DecodeEngine:
     def _admit(self, cache, dcache=None):
         """Move queued sessions into free slots.
 
-        Paged mode: each prompt is first matched against the prefix
-        trie; a hit maps the shared blocks (refcount bump) and only the
-        unmatched tail runs the model's tail prefill — grouped by (tail
-        bucket, prefix-block bucket) so compile count stays
-        logarithmic.  Misses (and slot mode) run the plain bucketed
-        ``prefill``.  Every admitted prompt's whole-block prefix is then
+        Each prompt is first matched against the prefix trie; a hit
+        maps the shared blocks (refcount bump) and only the unmatched
+        tail runs the model's tail prefill — grouped by (tail bucket,
+        prefix-block bucket) so compile count stays logarithmic.  Misses
+        run the plain bucketed ``prefill``, and so does the draft model
+        for every prompt.  Every admitted prompt's whole-block prefix is then
         offered to the trie, so the FIRST request of a prefix populates
         it for all followers.  The first token comes from the prefill
         logits either way (sampled at index 0).
@@ -592,12 +540,10 @@ class DecodeEngine:
         """The admission itself, under ``_admit``'s span: trie match,
         bucketed prefills, slot installation, first tokens."""
         cfg = self._spec.cfg
-        paged = self._spec.paged
         plain, matched = [], []
         with telemetry.span(telemetry.DECODE_TRIE_MATCH):
             for req in batch:
-                shared, mlen = (cache.match_prefix(req["prompt"])
-                                if paged else ([], 0))
+                shared, mlen = cache.match_prefix(req["prompt"])
                 if mlen > 0:
                     matched.append((req, shared, mlen))
                 else:
@@ -670,7 +616,8 @@ class DecodeEngine:
                     self.prefix_tokens_saved += mlen
                     metrics_registry.inc("tfos_decode_prefix_hits")
         # -- draft prefill (speculative mode: full prompt, own cache) -------
-        draft_kv = {}  # sid -> the draft's rows of that prompt
+        draft_kv = {}  # sid -> (the draft prefill's rows, on the device
+        #                  and whole, row index)
         if dcache is not None:
             groups = {}
             for req in batch:
@@ -689,7 +636,7 @@ class DecodeEngine:
                 _lg, dkv = self._dprefill_jit(
                     self._spec.draft_params, toks, lens)
                 for i, req in enumerate(members):
-                    draft_kv[req["sid"]] = [r[i] for r in dkv]
+                    draft_kv[req["sid"]] = (dkv, i)
 
         # -- slot installation + first-token emission -----------------------
         for i in range(len(admitted)):
@@ -700,20 +647,22 @@ class DecodeEngine:
             plen = len(req["prompt"])
             slot = cache.alloc()
             # cannot be None: admission is bounded by free_slots
-            if paged:
-                bs = cache.block_size
-                own = cache.alloc_blocks(-(-(plen - mlen) // bs))
-                cache.map_session(slot, shared, own, plen)
-                with telemetry.span(telemetry.DECODE_KV_INSERT,
-                                    tokens=plen - mlen):
-                    cache.insert_tail(slot, *kv, mlen, plen - mlen, row=row)
-                cache.register_prompt(slot, req["prompt"])
-            else:
-                with telemetry.span(telemetry.DECODE_KV_INSERT,
-                                    tokens=plen):
-                    cache.insert(slot, *(r[row] for r in kv), plen)
+            bs = cache.block_size
+            own = cache.alloc_blocks(-(-(plen - mlen) // bs))
+            cache.map_session(slot, shared, own, plen)
+            with telemetry.span(telemetry.DECODE_KV_INSERT,
+                                tokens=plen - mlen):
+                cache.insert_tail(slot, *kv, mlen, plen - mlen, row=row)
+            cache.register_prompt(slot, req["prompt"])
             if dcache is not None:
-                dcache.insert(slot, *draft_kv[req["sid"]], plen)
+                if dcache.alloc() != slot:
+                    raise AssertionError(
+                        "the draft's cache and the target's hand out "
+                        "different slots")
+                dkv, drow = draft_kv.pop(req["sid"])
+                dcache.map_session(slot, [],
+                                   dcache.alloc_blocks(-(-plen // bs)), plen)
+                dcache.insert_tail(slot, *dkv, 0, plen, row=drow)
             first = _sampling.sample_token(logits_row, req["sampling"], 0)
             mt = min(req["max_tokens"], cache.max_seq - plen)
             st = _Slot(req["sid"], plen, max(1, mt), req["eos_id"], first,
@@ -730,55 +679,14 @@ class DecodeEngine:
             self._emit("token", st.sid, 0, first)
             if (st.eos_id is not None and first == st.eos_id) \
                     or st.max_tokens <= 1:
-                self._retire(cache, slot)
+                self._retire(cache, dcache, slot)
         metrics_registry.set_gauge("tfos_decode_slot_occupancy",
                                    cache.occupancy)
-        if paged:
-            metrics_registry.set_gauge("tfos_decode_blocks_in_use",
-                                       cache.blocks_in_use)
+        metrics_registry.set_gauge("tfos_decode_blocks_in_use",
+                                   cache.blocks_in_use)
 
-    # -- iteration: legacy slot-paged path ----------------------------------
-    def _iterate(self, cache):
-        """One fused decode step over every occupied slot."""
-        with telemetry.span(telemetry.DECODE_ITERATE,
-                            active=len(self._active)) as span:
-            with telemetry.span(telemetry.DECODE_BUILD_WINDOW):
-                tokens = np.zeros((cache.slots,), np.int32)
-                for slot, st in self._active.items():
-                    tokens[slot] = st.last
-            self._mark("host")
-            with telemetry.span(telemetry.DECODE_STEP_DISPATCH):
-                logits, cache.pools = self._step_jit(
-                    self._params, tokens, cache.pools, cache.lengths)
-            self._mark("step")
-            with telemetry.span(telemetry.DECODE_LOGITS_FETCH):
-                logits = np.asarray(logits)   # device wait + D2H
-            self._mark("fetch")
-            self.iterations += 1
-            with telemetry.span(telemetry.DECODE_SAMPLE):
-                sampled = {
-                    slot: _sampling.sample_token(
-                        logits[slot], st.sampling, len(st.generated))
-                    for slot, st in self._active.items()}
-            with telemetry.span(telemetry.DECODE_EMIT):
-                for slot, tok in sampled.items():
-                    st = self._active[slot]
-                    cache.lengths[slot] += 1
-                    st.generated.append(tok)
-                    st.last = tok
-                    self._emit("token", st.sid, len(st.generated) - 1, tok)
-                    if (st.eos_id is not None and tok == st.eos_id) \
-                            or len(st.generated) >= st.max_tokens \
-                            or cache.lengths[slot] >= cache.max_seq:
-                        self._retire(cache, slot)
-            self.tokens += len(sampled)
-            span.add(tokens=len(sampled))
-        metrics_registry.set_gauge("tfos_decode_slot_occupancy",
-                                   cache.occupancy)
-        self._mark("host")
-
-    # -- iteration: paged path (plain W=1 or speculative W=K) ---------------
-    def _iterate_paged(self, cache, dcache):
+    # -- iteration (plain W=1 or speculative W=K) ---------------------------
+    def _iterate(self, cache, dcache):
         """One fused windowed step over every occupied slot.
 
         Without a draft model the window is 1 token — the plain paged
@@ -789,14 +697,91 @@ class DecodeEngine:
         ``base+j-1`` — every emitted token is exactly the target
         sample conditioned on a correct history, so speculative output
         matches non-speculative token-for-token.  The draft ingests the
-        full window (K steps) so its cache stays aligned; rejection
-        rolls both cursors back by assignment, and the stale K/V past
-        the cursor is unreachable (masked) until a later correct write
-        lands on it.
+        full window (K steps of its own paged step at width 1) so its
+        cache stays aligned; rejection rolls both cursors back by
+        assignment, and the stale K/V past the cursor is unreachable
+        (masked) until a later correct write lands on it.  Blocks grown
+        for a rejected window stay mapped until retirement.
+
+        The phases each run under their span and are closed by a
+        ``_mark``: build the window (the draft's proposals included),
+        dispatch the step, fetch the logits (device wait + D2H), sample
+        every slot, then emit and retire.
         """
+        spec = self._spec
+        k_win = spec.spec_window if dcache is not None else 1
         with telemetry.span(telemetry.DECODE_ITERATE,
                             active=len(self._active)) as span:
-            span.add(tokens=self._iterate_window(cache, dcache))
+            with telemetry.span(telemetry.DECODE_BUILD_WINDOW):
+                window = np.zeros((cache.slots, k_win), np.int32)
+                for slot, st in self._active.items():
+                    window[slot, 0] = st.last
+                n0 = cache.lengths.copy()
+                self._live_token_steps += int(n0.sum())
+                if dcache is not None:
+                    self._propose(dcache, window, n0)
+                for slot in self._active:
+                    cache.ensure_capacity(slot, int(n0[slot]) + k_win)
+            self._mark("host")
+            with telemetry.span(telemetry.DECODE_STEP_DISPATCH):
+                logits, cache.pools, counters = self._pstep_jit(
+                    self._params, window, cache.pools, cache.block_tables,
+                    n0)
+            self._mark("step")
+            with telemetry.span(telemetry.DECODE_LOGITS_FETCH):
+                logits = np.asarray(logits)           # [slots, K, vocab]
+                # a few ints computed by the same program: one more fetch,
+                # nothing more to wait for
+                for name, value in self._device_get(counters).items():
+                    total = self._step_counters.get(name, 0)
+                    self._step_counters[name] = (
+                        max(total, int(value)) if name.endswith("_max")
+                        else total + int(value))
+            self._mark("fetch")
+            self.iterations += 1
+            sampled = {}
+            with telemetry.span(telemetry.DECODE_SAMPLE):
+                for slot, st in self._active.items():
+                    base = len(st.generated)
+                    # rows past max_seq wrote their token's k/v to the
+                    # sentinel, so their logits miss history — never emit
+                    # from them
+                    valid = min(k_win, cache.max_seq - int(n0[slot]))
+                    emitted = []
+                    for j in range(valid):
+                        if j > 0 and int(window[slot, j]) != emitted[j - 1]:
+                            break       # draft diverged; later rows stale
+                        if j > 0:
+                            self.spec_accepted += 1
+                        emitted.append(_sampling.sample_token(
+                            logits[slot, j], st.sampling, base + j))
+                    if dcache is not None:
+                        self.spec_proposed += k_win - 1
+                    sampled[slot] = emitted
+            n_emitted = 0
+            with telemetry.span(telemetry.DECODE_EMIT):
+                for slot, emitted in sampled.items():
+                    st = self._active[slot]
+                    done = False
+                    for tok in emitted:
+                        st.generated.append(tok)
+                        st.last = tok
+                        cache.lengths[slot] += 1
+                        n_emitted += 1
+                        self._emit("token", st.sid, len(st.generated) - 1,
+                                   tok)
+                        if (st.eos_id is not None and tok == st.eos_id) \
+                                or len(st.generated) >= st.max_tokens:
+                            done = True
+                            break
+                    if dcache is not None:
+                        # roll the draft cursor back onto the accepted
+                        # prefix
+                        dcache.lengths[slot] = cache.lengths[slot]
+                    if done or cache.lengths[slot] >= cache.max_seq:
+                        self._retire(cache, dcache, slot)
+            self.tokens += n_emitted
+            span.add(tokens=n_emitted)
         metrics_registry.set_gauge("tfos_decode_slot_occupancy",
                                    cache.occupancy)
         metrics_registry.set_gauge("tfos_decode_blocks_in_use",
@@ -807,100 +792,38 @@ class DecodeEngine:
                 round(self.spec_accepted / max(1, self.spec_proposed), 4))
         self._mark("host")
 
-    def _iterate_window(self, cache, dcache):
-        """``_iterate_paged``'s body in its phases, each under its span
-        and closed by a ``_mark``: build the window (the draft's
-        proposals included), dispatch the step, fetch the logits (device
-        wait + D2H), sample every slot, then emit and retire.  Returns
-        the number of tokens emitted."""
-        spec = self._spec
-        k_win = spec.spec_window if dcache is not None else 1
-        with telemetry.span(telemetry.DECODE_BUILD_WINDOW):
-            window = np.zeros((cache.slots, k_win), np.int32)
-            for slot, st in self._active.items():
-                window[slot, 0] = st.last
-            n0 = cache.lengths.copy()
-            self._live_token_steps += int(n0.sum())
-            if dcache is not None:
-                for j in range(k_win):
-                    # a COPY of the cursors: dispatch is asynchronous and
-                    # may read the host array in place, after the
-                    # increment below (the draft then writes one column
-                    # on and its proposals go astray)
-                    dlogits, dcache.pools = self._dstep_jit(
-                        spec.draft_params, window[:, j], dcache.pools,
-                        dcache.lengths.copy())
-                    for slot in self._active:
-                        dcache.lengths[slot] += 1
-                    if j < k_win - 1:
-                        dlogits = np.asarray(dlogits)
-                        for slot, st in self._active.items():
-                            window[slot, j + 1] = _sampling.sample_token(
-                                dlogits[slot], st.sampling,
-                                len(st.generated) + j)
+    def _propose(self, dcache, window, n0):
+        """Fill ``window[:, 1:]`` with the draft's proposals: K steps of
+        the draft's own paged step at width 1, the last of which only
+        ingests the window's last token."""
+        k_win = window.shape[1]
+        for slot in self._active:
+            dcache.ensure_capacity(slot, int(n0[slot]) + k_win)
+        # COPIES of the table and the cursors: dispatch is asynchronous
+        # and may read a host array in place, after it changed (the
+        # cursors by the increment below: the draft then writes one
+        # column on and its proposals go astray; the table when a slot
+        # retires, while the window's last draft step, which nobody
+        # waits for, may not have run)
+        tables = dcache.block_tables.copy()
+        for j in range(k_win):
+            dlogits, dcache.pools, _ = self._dstep_jit(
+                self._spec.draft_params, window[:, j:j + 1], dcache.pools,
+                tables, dcache.lengths.copy())
             for slot in self._active:
-                cache.ensure_capacity(slot, int(n0[slot]) + k_win)
-        self._mark("host")
-        with telemetry.span(telemetry.DECODE_STEP_DISPATCH):
-            logits, cache.pools, counters = self._pstep_jit(
-                self._params, window, cache.pools, cache.block_tables, n0)
-        self._mark("step")
-        with telemetry.span(telemetry.DECODE_LOGITS_FETCH):
-            logits = np.asarray(logits)           # [slots, K, vocab]
-            # a few ints computed by the same program: one more fetch,
-            # nothing more to wait for
-            for name, value in self._device_get(counters).items():
-                total = self._step_counters.get(name, 0)
-                self._step_counters[name] = (
-                    max(total, int(value)) if name.endswith("_max")
-                    else total + int(value))
-        self._mark("fetch")
-        self.iterations += 1
-        sampled = {}
-        with telemetry.span(telemetry.DECODE_SAMPLE):
-            for slot, st in self._active.items():
-                base = len(st.generated)
-                # rows past max_seq wrote their token's k/v to the
-                # sentinel, so their logits miss history — never emit
-                # from them
-                valid = min(k_win, cache.max_seq - int(n0[slot]))
-                emitted = []
-                for j in range(valid):
-                    if j > 0 and int(window[slot, j]) != emitted[j - 1]:
-                        break           # draft diverged; later rows stale
-                    if j > 0:
-                        self.spec_accepted += 1
-                    emitted.append(_sampling.sample_token(
-                        logits[slot, j], st.sampling, base + j))
-                if dcache is not None:
-                    self.spec_proposed += k_win - 1
-                sampled[slot] = emitted
-        n_emitted = 0
-        with telemetry.span(telemetry.DECODE_EMIT):
-            for slot, emitted in sampled.items():
-                st = self._active[slot]
-                done = False
-                for tok in emitted:
-                    st.generated.append(tok)
-                    st.last = tok
-                    cache.lengths[slot] += 1
-                    n_emitted += 1
-                    self._emit("token", st.sid, len(st.generated) - 1, tok)
-                    if (st.eos_id is not None and tok == st.eos_id) \
-                            or len(st.generated) >= st.max_tokens:
-                        done = True
-                        break
-                if dcache is not None:
-                    # roll the draft cursor back onto the accepted prefix
-                    dcache.lengths[slot] = cache.lengths[slot]
-                if done or cache.lengths[slot] >= cache.max_seq:
-                    self._retire(cache, slot)
-        self.tokens += n_emitted
-        return n_emitted
+                dcache.lengths[slot] += 1
+            if j < k_win - 1:
+                dlogits = np.asarray(dlogits)
+                for slot, st in self._active.items():
+                    window[slot, j + 1] = _sampling.sample_token(
+                        dlogits[slot, 0], st.sampling,
+                        len(st.generated) + j)
 
-    def _retire(self, cache, slot):
+    def _retire(self, cache, dcache, slot):
         st = self._active.pop(slot)
         cache.retire(slot)
+        if dcache is not None:
+            dcache.retire(slot)
         with self._qlock:
             self._sids.discard(st.sid)
         self.retired += 1

@@ -392,9 +392,7 @@ class Server:
         ctx = telemetry.current()
         session = _decode.PendingSession(
             next(self._session_ids), prompt,
-            max_tokens or (self.spec.decode.max_tokens
-                           if self.spec.decode else None)
-            or _decode.max_tokens_default(),
+            max_tokens or self.spec.decode.max_tokens,
             self.spec.decode.eos_id if eos_id is None else eos_id,
             sampling=sampling,
             trace=ctx.to_header() if ctx is not None else None,

@@ -1,10 +1,10 @@
-"""Decode-tier tests: slot- and block-paged KV cache units (refcount
-lint, prefix-trie match/reclaim), sequence-length bucketing, the
-open-loop load generator, seeded sampling, the CPU parity acceptance
-gates (paged == slot-paged == full-recompute greedy; seeded-sampling
-replay token-identical; speculative == non-speculative at the same
-seed), and the Server/HTTP generate surface incl. oversized-prompt
-400s.  Slow lane: a replica SIGKILLed mid-decode (sessions re-prefill
+"""Decode-tier tests: block-paged KV cache units (refcount lint,
+prefix-trie match/reclaim), sequence-length bucketing, the open-loop
+load generator, seeded sampling, the CPU parity acceptance gates (paged
+== full-recompute greedy; seeded-sampling replay token-identical;
+speculative == non-speculative at the same seed, the draft on a paged
+cache of its own), and the Server/HTTP generate surface incl.
+oversized-prompt 400s.  Slow lane: a replica SIGKILLed mid-decode (sessions re-prefill
 on the survivor; zero dropped and zero duplicated tokens)."""
 
 import functools
@@ -135,27 +135,6 @@ def test_run_open_loop_classifies_and_aggregates():
 
 
 # --- KV cache units ---------------------------------------------------------
-
-def test_kvcache_slot_lifecycle_and_insert():
-    from tensorflowonspark_tpu.serving.decode import kvcache
-    cfg = _cfg()
-    cache = kvcache.SlotKVCache(cfg, slots=3)
-    assert cache.k.shape == (3, cfg.n_layers, cfg.n_heads, cfg.max_seq,
-                             cfg.dim // cfg.n_heads)
-    assert cache.free_slots == 3 and cache.occupancy == 0
-    got = [cache.alloc() for _ in range(3)]
-    assert got == [0, 1, 2]  # lowest slot first
-    assert cache.alloc() is None  # full
-    k = np.ones((cfg.n_layers, cfg.n_heads, 5, cfg.dim // cfg.n_heads),
-                np.float32)
-    cache.insert(1, k, k, 5)
-    assert cache.lengths[1] == 5 and cache.occupancy == 3
-    cache.retire(1)
-    assert cache.lengths[1] == 0 and cache.free_slots == 1
-    with pytest.raises(ValueError):
-        cache.retire(1)  # double retire
-    assert cache.alloc() == 1  # freed slot is reusable
-
 
 def test_paged_kvcache_lifecycle_refcounts_and_prefix_match():
     from tensorflowonspark_tpu.serving.decode import kvcache
@@ -503,8 +482,8 @@ def test_engine_stats_count_the_trie_and_what_it_gave_back():
     cfg = _cfg()
     params = _params(cfg)
     # the pool is the live set: retired prompts' blocks have to go
-    spec = D.DecodeSpec(cfg, slots=2, max_tokens=4, paged=True,
-                        block_size=4, num_blocks=1 + 2 * 8)
+    spec = D.DecodeSpec(cfg, slots=2, max_tokens=4, block_size=4,
+                        num_blocks=1 + 2 * 8)
     done = []
     eng = D.DecodeEngine(
         params, spec,
@@ -678,15 +657,14 @@ def _run_sessions(params, spec, jobs, timeout=300):
     return {sid: ev["done"] for sid, ev in events.items()}, stats, eng
 
 
-@pytest.mark.parametrize("paged", [True, False])
-def test_engine_counts_tokens_and_where_its_time_went(paged):
+def test_engine_counts_tokens_and_where_its_time_went():
     """``stats()`` always carries ``tokens`` (emitted by decode
     iterations: every token but each session's first, which its prefill
     produced), ``prompt_tokens``, and ``phase_s``, whose phases are
     chained clock reads and so sum to the engine thread's wall time."""
     cfg = _cfg()
     params = _params(cfg)
-    spec = D.DecodeSpec(cfg, slots=4, max_tokens=8, paged=paged)
+    spec = D.DecodeSpec(cfg, slots=4, max_tokens=8)
     jobs = [(i, [2 + i, 3, 5, 7][: 2 + i % 3], {"max_tokens": 4 + i})
             for i in range(6)]
     t0 = time.perf_counter()
@@ -709,11 +687,11 @@ def test_engine_counts_tokens_and_where_its_time_went(paged):
         sum(eng._phase_s.values()), abs=0.5)
 
 
-def test_parity_paged_equals_slot_equals_oracle_with_prefix_hits():
+def test_parity_paged_equals_oracle_with_prefix_hits():
     """Gate (a): block-paged greedy decode — including trie-matched
-    admissions that skip the shared prefill — is token-identical to the
-    legacy slot-paged cache AND to a full-recompute greedy decode; the
-    engine's paged cache leaks zero block references afterwards."""
+    admissions that skip the shared prefill — is token-identical to a
+    full-recompute greedy decode; the engine's paged cache leaks zero
+    block references afterwards."""
     cfg = _cfg()
     params = _params(cfg)
     rng = np.random.default_rng(5)
@@ -725,16 +703,11 @@ def test_parity_paged_equals_slot_equals_oracle_with_prefix_hits():
     # slots=1 serializes admission, so "follow" provably arrives after
     # "lead" registered the shared prefix -> a guaranteed trie hit
     paged, pstats, eng = _run_sessions(
-        params, D.DecodeSpec(cfg, slots=1, max_tokens=6, paged=True,
-                             block_size=4), jobs)
-    slotted, sstats, _ = _run_sessions(
-        params, D.DecodeSpec(cfg, slots=1, max_tokens=6, paged=False),
+        params, D.DecodeSpec(cfg, slots=1, max_tokens=6, block_size=4),
         jobs)
     for sid, p in prompts.items():
         ref = _oracle(params, p, cfg, max_tokens=6)
         assert paged[sid] == ref, (sid, paged[sid], ref)
-        assert slotted[sid] == ref, (sid, slotted[sid], ref)
-    assert pstats["paged"] is True and sstats["paged"] is False
     assert pstats["prefix_hits"] >= 1
     assert pstats["prefix_tokens_saved"] >= 8  # the whole system prompt
     # refcount lint: every retired session returned its blocks; only
@@ -768,20 +741,37 @@ def test_parity_seeded_sampling_replay_token_identical():
     assert other["r3"] != first["r1"]
 
 
+def _draft():
+    """A draft smaller than ``_cfg()``'s model, same vocabulary and
+    ``max_seq``."""
+    import jax
+
+    from tensorflowonspark_tpu.models import transformer as T
+    dcfg = _cfg(dim=16, n_layers=1)
+    return dcfg, T.init(jax.random.PRNGKey(7), dcfg)
+
+
+def _both_caches_clean(eng):
+    """Every session retired: neither cache holds a slot or fails the
+    refcount lint, and the draft's pool (no trie to keep a block for)
+    is empty."""
+    for cache in (eng._cache, eng._dcache):
+        assert cache.occupancy == 0
+        assert cache.leaked_blocks() == []
+    assert eng._dcache.trie is None and eng._dcache.blocks_in_use == 0
+
+
 def test_parity_speculative_equals_plain_same_seed():
     """Gate (c): speculative decoding (draft proposes, target verifies
     in one windowed step) returns token-identical output to the
     non-speculative engine for greedy AND seeded-sampled sessions; a
-    draft that IS the target is always accepted (the speedup path)."""
-    import jax
-
-    from tensorflowonspark_tpu.models import transformer as T
+    draft that IS the target is always accepted (the speedup path).
+    Either way no block of either cache is lost."""
     from tensorflowonspark_tpu.serving.decode import sampling
 
     cfg = _cfg()
     params = _params(cfg)
-    dcfg = _cfg(dim=16, n_layers=1)
-    dparams = T.init(jax.random.PRNGKey(7), dcfg)
+    dcfg, dparams = _draft()
     sp = sampling.make(temperature=0.9, top_k=16, seed=7)
     jobs = [("g", [3, 5, 7, 9, 11], {}),
             ("s", [4, 6, 8, 10], {"sampling": sp})]
@@ -790,25 +780,133 @@ def test_parity_speculative_equals_plain_same_seed():
         jobs)
     assert plain["g"] == _oracle(params, [3, 5, 7, 9, 11], cfg,
                                  max_tokens=7)
-    specd, st, _ = _run_sessions(
+    specd, st, eng = _run_sessions(
         params, D.DecodeSpec(cfg, slots=2, max_tokens=7, block_size=4,
                              draft_params=dparams, draft_cfg=dcfg,
                              spec_window=3), jobs)
     assert specd == plain
     assert st["spec_proposed"] > 0
+    _both_caches_clean(eng)
     # perfect draft (the target itself): every proposal accepted, output
     # still identical — multiple tokens really do land per fused step
-    perfect, pt, _ = _run_sessions(
+    perfect, pt, eng = _run_sessions(
         params, D.DecodeSpec(cfg, slots=2, max_tokens=7, block_size=4,
                              draft_params=params, draft_cfg=cfg,
                              spec_window=3), jobs)
     assert perfect == plain
     assert pt["spec_accepted"] == pt["spec_proposed"] > 0
+    _both_caches_clean(eng)
     with pytest.raises(ValueError):
         D.DecodeSpec(cfg, draft_params=dparams, draft_cfg=None)
-    with pytest.raises(ValueError):
-        D.DecodeSpec(cfg, paged=False, draft_params=dparams,
-                     draft_cfg=dcfg)
+    with pytest.raises(TypeError):
+        D.DecodeSpec(cfg, paged=False)  # one cache: nothing to choose
+
+
+def test_speculative_sessions_give_back_every_block_of_both_caches():
+    """Sessions of different lengths served speculatively — one retired
+    by its first token, one by eos, more sessions than slots — leave the
+    target's cache and the draft's without a slot held, a block leaked
+    or a refcount adrift, blocks grown for rejected windows included."""
+    cfg = _cfg()
+    params = _params(cfg)
+    dcfg, dparams = _draft()
+    rng = np.random.default_rng(11)
+    sizes = ((3, 9), (10, 4), (17, 12), (5, 1), (8, 20), (13, 6))
+    jobs = [(i, rng.integers(1, cfg.vocab_size, size=n).tolist(),
+             {"max_tokens": m}) for i, (n, m) in enumerate(sizes)]
+    eos = _oracle(params, jobs[0][1], cfg, max_tokens=9)[4]
+    jobs[0][2]["eos_id"] = eos
+    out, st, eng = _run_sessions(
+        params, D.DecodeSpec(cfg, slots=2, block_size=4,
+                             draft_params=dparams, draft_cfg=dcfg,
+                             spec_window=3), jobs)
+    assert out[0] == _oracle(params, jobs[0][1], cfg, max_tokens=9,
+                             eos_id=eos)
+    assert [len(out[i]) for i in range(1, 6)] == [4, 12, 1, 20, 6]
+    assert st["retired"] == 6 and st["spec_proposed"] > 0
+    _both_caches_clean(eng)
+
+
+def test_speculative_window_past_max_seq_spills_into_the_sentinels():
+    """A speculative session runs into ``max_seq``: its last window
+    overruns the last block, in the target's cache and in the draft's.
+    Both route the overflow to their sentinel block, so the session
+    reads as the plain engine's and its neighbour as if it had been
+    alone — with a small draft and with the target as its own draft."""
+    cfg = _cfg()
+    params = _params(cfg)
+    rng = np.random.default_rng(13)
+    edge = rng.integers(1, cfg.vocab_size, size=cfg.max_seq - 5).tolist()
+    near = rng.integers(1, cfg.vocab_size, size=6).tolist()
+    # the cap is max_seq - len(prompt) = 5 tokens: windows of 3 at 27, 30
+    jobs = [("edge", edge, {"max_tokens": 20}),
+            ("near", near, {"max_tokens": 12})]
+    plain, _, _ = _run_sessions(
+        params, D.DecodeSpec(cfg, slots=2, block_size=4), jobs)
+    alone, _, _ = _run_sessions(
+        params, D.DecodeSpec(cfg, slots=2, block_size=4), jobs[1:])
+    assert len(plain["edge"]) == 5 and plain["near"] == alone["near"]
+    for draft_cfg, draft_params in (_draft(), (cfg, params)):
+        specd, st, eng = _run_sessions(
+            params, D.DecodeSpec(cfg, slots=2, block_size=4,
+                                 draft_params=draft_params,
+                                 draft_cfg=draft_cfg, spec_window=3), jobs)
+        assert specd == plain
+        assert st["spec_proposed"] > 0
+        _both_caches_clean(eng)
+
+
+def test_speculative_engine_rebuilds_both_caches_after_a_failure(
+        monkeypatch):
+    """An injected failure between two windows of a live speculative
+    session (site ``decode.step``) fails the session, and the engine
+    goes on with a new cache AND a new draft cache: the next session is
+    served, token for token the plain engine's."""
+    from tensorflowonspark_tpu.utils import faults
+
+    cfg = _cfg()
+    params = _params(cfg)
+    dcfg, dparams = _draft()
+    prompt = [3, 5, 7, 9, 11]
+    plain, _, _ = _run_sessions(
+        params, D.DecodeSpec(cfg, slots=2, max_tokens=7, block_size=4),
+        [("next", prompt, {})])
+    events = {"victim": [], "next": []}
+
+    def emit(kind, sid, *rest):
+        events[sid].append((kind,) + rest)
+        if (kind, sid, rest[:1]) == ("token", "victim", (2,)):
+            # on the engine's thread, between two of its checks: the next
+            # one fires, with this session three tokens into twenty
+            monkeypatch.setenv(faults.PLAN_ENV, "decode.step:exc@1")
+
+    def wait_for(sid):
+        deadline = time.time() + 300
+        while time.time() < deadline:
+            if events[sid] and events[sid][-1][0] in ("done", "error"):
+                return events[sid][-1]
+            time.sleep(0.01)
+        raise AssertionError(f"session {sid!r} timed out")
+
+    eng = D.DecodeEngine(
+        params, D.DecodeSpec(cfg, slots=2, max_tokens=7, block_size=4,
+                             draft_params=dparams, draft_cfg=dcfg,
+                             spec_window=3), emit)
+    eng.start(timeout=300)
+    first = eng._cache, eng._dcache
+    try:
+        eng.submit("victim", [2, 4, 6, 8], max_tokens=20)
+        last = wait_for("victim")
+        assert last[0] == "error" and "injected fault" in last[1]
+        eng.submit("next", prompt)
+        assert wait_for("next")[:2] == ("done", plain["next"])
+    finally:
+        eng.stop()
+        monkeypatch.delenv(faults.PLAN_ENV, raising=False)
+        faults._reset_for_tests()
+    # the caches the victim's blocks were in are gone, both of them
+    assert eng._cache is not first[0] and eng._dcache is not first[1]
+    _both_caches_clean(eng)
 
 
 # --- Server / HTTP e2e ------------------------------------------------------

@@ -431,21 +431,12 @@ def test_the_engine_names_the_prefill_and_counts_for_an_interval(share):
     assert s1["moe"]["dropped"] == 0
 
 
-def test_the_unpaged_cache_and_a_latent_draft_refuse_clearly(share):
-    from tensorflowonspark_tpu import serving
-
+def test_the_classic_programs_and_a_gated_mha_config_refuse_clearly(share):
     _cfg, model, params = share
-    with pytest.raises(ValueError, match="no unpaged decode step"):
-        kvcache.SlotKVCache(model, slots=2)
     with pytest.raises(ValueError, match="classic block"):
         T.prefill(params, np.zeros((1, 8), np.int32), model)
     with pytest.raises(ValueError, match="attn_kind='latent'"):
         T.Config(ffn_kind="swiglu")
-    spec = serving.DecodeSpec(model, slots=2, paged=False)
-    from tensorflowonspark_tpu.serving.decode import scheduler
-    eng = scheduler.DecodeEngine(params, spec, lambda *a: None)
-    with pytest.raises(ValueError, match="no unpaged decode step"):
-        eng.start(timeout=120)
 
 
 def test_bfloat16_weights_survive_export_and_load(tmp_path):
