@@ -1008,6 +1008,10 @@ class DecodeFns:
     the totals into what ``stats()`` shows.
     ``donate``: the paged step may overwrite the pools it is given (the
     cache's insert always does).
+    ``resident(params) -> params``: the tree as the engine should HOLD it
+    (:func:`_resident`): the leaves these programs use only through a cast
+    to the compute type, cast once; every program above gives the same
+    bits on either tree.  Default: the tree as given.
     """
     rows: tuple
     prefill: object
@@ -1015,6 +1019,7 @@ class DecodeFns:
     decode_step_paged: object
     donate: bool = False
     summarize: object = None
+    resident: object = lambda params: params
 
 
 def _summarize_moe(totals):
@@ -1033,7 +1038,39 @@ def _summarize_moe(totals):
     }}
 
 
+# The leaves (by their own name in the tree) that the three serving
+# programs use ONLY as ``.astype(cfg.compute_dtype)``: the operands of
+# ``_matmul`` / ``ragged_dot`` / the absorbed up-projections, the router's
+# weight, the embedding table.  Not among them: every norm gain (multiplied
+# in float32, ``ops.rmsnorm_reference``) and the router's bias (added in
+# float32).  A leaf that gains another use leaves its set.
+_CLASSIC_CAST = frozenset({"embed", "head", "wqkv", "wo", "w1", "w2"})
+_LATENT_CAST = frozenset({
+    "embed", "head", "wq", "wkva", "wkvb", "wo", "wg", "wu", "wd",
+    "router", "shared_wg", "shared_wu", "shared_wd"})
+
+
+def _resident(params, names, dtype):
+    """``params`` with each leaf called one of ``names`` in ``dtype``:
+    what a program that casts those leaves on every use may be handed
+    instead, for the same bits.  A leaf that already has the type (and
+    every other leaf) is handed back AS IT IS, the same object — decided
+    here, outside any jit, because a jitted identity copies its input.
+    The others go up and are cast one at a time, by the device's own
+    convert (the one the programs made), so the device never holds more
+    of the wide tree than one leaf."""
+    def leaf(path, x):
+        if getattr(path[-1], "key", None) not in names or x.dtype == dtype:
+            return x
+        return jax.block_until_ready(jnp.asarray(x).astype(dtype))
+
+    return jax.tree_util.tree_map_with_path(leaf, params)
+
+
 def _decode_fns(cfg):
+    resident = functools.partial(
+        _resident, names=_CLASSIC_CAST if cfg.classic else _LATENT_CAST,
+        dtype=cfg.compute_dtype)
     if cfg.classic:
         per_head = (cfg.n_heads, None, cfg.head_dim)
 
@@ -1053,7 +1090,7 @@ def _decode_fns(cfg):
 
         return DecodeFns(rows=(("k", per_head), ("v", per_head)),
                          prefill=prefill_fn, prefill_extend=extend_fn,
-                         decode_step_paged=step_paged_fn)
+                         decode_step_paged=step_paged_fn, resident=resident)
 
     def prefill_fn(p, toks, lens):
         logits, rows = latent_prefill(p, toks, cfg, lengths=lens)
@@ -1072,7 +1109,8 @@ def _decode_fns(cfg):
     return DecodeFns(rows=(("kv", (None, cfg.latent_row)),),
                      prefill=prefill_fn, prefill_extend=extend_fn,
                      decode_step_paged=step_paged_fn, donate=True,
-                     summarize=_summarize_moe if cfg.n_experts else None)
+                     summarize=_summarize_moe if cfg.n_experts else None,
+                     resident=resident)
 
 
 @functools.lru_cache(maxsize=8)

@@ -282,6 +282,9 @@ class DecodeEngine:
                          "fetch": 0.0, "host": 0.0}
         self._t_mark = self._t_started = None
         self._fns = None            # the model's seam, engine thread
+        # what ``set_params`` last put on the device, and the draft's tree
+        self._params_stats = {"bytes": 0, "cast_bytes": 0, "cast_leaves": 0}
+        self._draft_params = None
         # the paged step's own counters (computed on the device, fetched
         # with the logits): summed over steps, a ``*_max`` kept as maximum
         self._step_counters = {}
@@ -318,12 +321,34 @@ class DecodeEngine:
         """Hot-reload hook: swap params between iterations.  In-flight
         sessions finish against their already-cached K/V (old params)
         plus new-param compute for the remaining tokens — same in-band,
-        no-drop semantics as the predict path's reload."""
+        no-drop semantics as the predict path's reload.
+
+        The engine keeps the tree RESIDENT, not as given: on the device,
+        and each leaf the model's programs only ever cast to the compute
+        type (``DecodeFns.resident`` names them: the matrices and the
+        embedding table, not the norm gains or a router's bias) already
+        in that type, so no step casts it again.  A leaf that has the
+        type stays the buffer it was; after this returns the engine
+        references no wider copy.  ``stats()["params"]`` says what was
+        cast."""
+        self._params, self._params_stats = self._resident(
+            self._spec.cfg, params)
+
+    @staticmethod
+    def _resident(cfg, params):
+        """``(the tree the jitted programs are handed, its stats)``: on
+        the device once, here — host arrays handed to a jitted step
+        would be uploaded again on every call."""
         import jax
 
-        # onto the device once, here — host arrays handed to the jitted
-        # steps would be uploaded again on every call
-        self._params = jax.device_put(params)
+        leaves = jax.tree_util.tree_leaves
+        held = jax.device_put(cfg.decode_fns().resident(params))
+        cast = [h for p, h in zip(leaves(params), leaves(held))
+                if h.dtype != p.dtype]
+        return held, {
+            "bytes": sum(int(h.nbytes) for h in leaves(held)),
+            "cast_bytes": sum(int(h.nbytes) for h in cast),
+            "cast_leaves": len(cast)}
 
     def submit(self, sid, prompt, max_tokens=None, eos_id=None,
                sampling=None, trace=None):
@@ -369,6 +394,7 @@ class DecodeEngine:
             "device": self._device,
             "prefix_hits": self.prefix_hits,
             "prefix_tokens_saved": self.prefix_tokens_saved,
+            "params": dict(self._params_stats),
         }
         cache = self._cache
         out["blocks_in_use"] = cache.blocks_in_use if cache is not None else 0
@@ -448,6 +474,9 @@ class DecodeEngine:
                 donate_argnums=(2,) if fns.donate else ())
             if spec.speculative:
                 dfns = spec.draft_cfg.decode_fns()
+                # the draft's weights take the target's road, once
+                self._draft_params, _ = self._resident(
+                    spec.draft_cfg, spec.draft_params)
 
                 def tfos_draft_prefill(p, toks, lens):
                     return dfns.prefill(p, toks, lens)
@@ -634,7 +663,7 @@ class DecodeEngine:
                 toks = _batcher.pad_rows(toks, rows)
                 lens = _batcher.pad_rows(lens, rows)
                 _lg, dkv = self._dprefill_jit(
-                    self._spec.draft_params, toks, lens)
+                    self._draft_params, toks, lens)
                 for i, req in enumerate(members):
                     draft_kv[req["sid"]] = (dkv, i)
 
@@ -808,7 +837,7 @@ class DecodeEngine:
         tables = dcache.block_tables.copy()
         for j in range(k_win):
             dlogits, dcache.pools, _ = self._dstep_jit(
-                self._spec.draft_params, window[:, j:j + 1], dcache.pools,
+                self._draft_params, window[:, j:j + 1], dcache.pools,
                 tables, dcache.lengths.copy())
             for slot in self._active:
                 dcache.lengths[slot] += 1

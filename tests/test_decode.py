@@ -909,6 +909,327 @@ def test_speculative_engine_rebuilds_both_caches_after_a_failure(
     _both_caches_clean(eng)
 
 
+# --- resident weights: the engine holds what the step multiplies ------------
+
+_GAINS = {"ln1", "ln2", "ln_f", "kv_norm", "q_norm", "router_bias"}
+
+
+def _mixed_cfg(block, **kw):
+    """float32 weights multiplied in bfloat16 (``param_dtype`` and
+    ``dtype`` apart), either block."""
+    if block == "classic":
+        return _cfg(**dict({"dtype": "bfloat16"}, **kw))
+    from tensorflowonspark_tpu.models import transformer as T
+    base = dict(
+        vocab_size=61, dim=32, n_layers=3, n_heads=2, max_seq=32,
+        dtype="bfloat16", attn_impl="reference", attn_kind="latent",
+        kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8,
+        qk_norm=True, ffn_kind="swiglu", ffn_dim=48, n_dense_layers=1,
+        n_experts=8, n_experts_held=4, experts_per_token=2, expert_dim=24,
+        n_shared_experts=1, routed_scale=2.5)
+    base.update(kw)
+    return T.Config(**base)
+
+
+def _host(tree):
+    """A tree as the replica hands it over: numpy, on the host (copies:
+    ``np.asarray`` of a CPU device array is a view that keeps it alive)."""
+    import jax
+    return jax.tree_util.tree_map(np.array, tree)
+
+
+def _leaf_names(tree):
+    import jax
+    return [(path[-1].key, leaf) for path, leaf
+            in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def _cpu_slots(cfg):
+    """The CPU backend has no bfloat16 product with a batch axis wider
+    than one (``DotThunk``: BF16 x BF16 = F32 unimplemented), which the
+    latent block's absorbed attention is per slot: one slot there."""
+    return 2 if cfg.classic else 1
+
+
+def _serving_programs(cfg, params):
+    """``{program: every output}`` of the three serving programs on
+    ``params``: a prefill, its rows into a paged cache, a tail over eight
+    cached tokens, one step of every slot."""
+    import jax
+
+    from tensorflowonspark_tpu.serving.decode import kvcache
+
+    fns = cfg.decode_fns()
+    n = _cpu_slots(cfg)
+    cache = kvcache.PagedKVCache(cfg, slots=n, block_size=4,
+                                 prefix_sharing=False)
+    rng = np.random.default_rng(17)
+    toks = rng.integers(1, cfg.vocab_size, (n, 8)).astype(np.int32)
+    lens = np.asarray([8, 6][:n], np.int32)
+    out = {"prefill": jax.jit(fns.prefill)(params, toks, lens)}
+    rows = out["prefill"][1]
+    for slot in range(n):
+        assert cache.alloc() == slot
+        cache.map_session(slot, [], cache.alloc_blocks(2), int(lens[slot]))
+        cache.insert_tail(slot, *rows, 0, int(lens[slot]), row=slot)
+    out["prefill_extend"] = jax.jit(fns.prefill_extend)(
+        params, toks[:1, :4], cache.pools, cache.block_tables[:1, :2],
+        np.asarray([8], np.int32), np.asarray([3], np.int32))
+    for slot in range(n):
+        cache.ensure_capacity(slot, int(lens[slot]) + 1)
+    out["decode_step_paged"] = jax.jit(fns.decode_step_paged)(
+        params, toks[:, -1:], cache.pools, cache.block_tables,
+        cache.lengths.copy())
+    return {name: jax.tree_util.tree_leaves(o) for name, o in out.items()}
+
+
+@functools.lru_cache(maxsize=None)
+def _programs_on_both_trees(block):
+    cfg = _mixed_cfg(block)
+    params = _params(cfg)
+    return (_serving_programs(cfg, params),
+            _serving_programs(cfg, cfg.decode_fns().resident(_host(params))))
+
+
+@pytest.mark.parametrize("program", ["prefill", "prefill_extend",
+                                     "decode_step_paged"])
+@pytest.mark.parametrize("block", ["classic", "latent"])
+def test_resident_tree_gives_the_float32_trees_bits(block, program):
+    """Casting the weights once changes no bit of a program's logits,
+    cache rows, pools or counters: the matrices entered every product in
+    the compute type before, and still do."""
+    wide, held = _programs_on_both_trees(block)
+    assert len(wide[program]) == len(held[program]) >= 2
+    for a, b in zip(wide[program], held[program]):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+@pytest.mark.parametrize("block", ["classic", "latent"])
+def test_resident_casts_the_matrices_and_keeps_the_gains(block):
+    """Every leaf the programs use only through a cast has the compute
+    type; norm gains and the router's bias (multiplied and added in
+    float32) keep float32."""
+    import jax.numpy as jnp
+
+    cfg = _mixed_cfg(block)
+    held = _leaf_names(cfg.decode_fns().resident(_host(_params(cfg))))
+    gains = {name for name, _ in held} & _GAINS
+    assert gains == ({"ln1", "ln2", "ln_f"} if block == "classic"
+                     else _GAINS)
+    for name, leaf in held:
+        want = jnp.float32 if name in _GAINS else jnp.bfloat16
+        assert leaf.dtype == want, (name, leaf.dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("block", ["classic", "latent"])
+def test_resident_hands_back_a_leaf_that_has_the_type(block, dtype):
+    """``dtype == param_dtype``: nothing is cast, nothing is copied —
+    each leaf the engine holds IS the buffer it was given."""
+    import jax
+
+    cfg = _mixed_cfg(block, dtype=dtype, param_dtype=dtype)
+    params = _params(cfg)
+    same = cfg.decode_fns().resident(params)
+    held, stats = D.DecodeEngine._resident(cfg, params)
+    for given, a, b in zip(*map(jax.tree_util.tree_leaves,
+                                (params, same, held))):
+        assert a is given
+        assert b.unsafe_buffer_pointer() == given.unsafe_buffer_pointer()
+    assert stats["cast_bytes"] == 0 and stats["cast_leaves"] == 0
+    assert stats["bytes"] == sum(
+        x.nbytes for x in jax.tree_util.tree_leaves(params))
+
+
+def _weight_converts(cfg, params):
+    """Conversions, in the lowered step, of an operand with the shape of
+    a weight or of the embedding table (whole, or one layer's slice)."""
+    import re
+
+    import jax
+
+    from tensorflowonspark_tpu.serving.decode import kvcache
+
+    shapes = set()
+    for name, leaf in _leaf_names(params):
+        if name not in _GAINS:
+            shapes.update(("x".join(map(str, s)) for s in
+                           (leaf.shape, leaf.shape[1:]) if len(s) >= 2))
+    cache = kvcache.PagedKVCache(cfg, slots=2, block_size=4,
+                                 prefix_sharing=False)
+    text = jax.jit(cfg.decode_fns().decode_step_paged).lower(
+        params, np.zeros((2, 1), np.int32), cache.pools,
+        cache.block_tables, cache.lengths).as_text()
+    return [m for m in re.findall(
+        r"stablehlo\.convert [^\n]*\(tensor<([0-9x]+)xf32>\) -> "
+        r"tensor<[0-9x]+xbf16>", text) if m in shapes]
+
+
+@pytest.mark.parametrize("block", ["classic", "latent"])
+def test_lowered_step_of_the_resident_tree_converts_no_weight(block):
+    """The float32 tree's step converts every matrix and the table (the
+    control); the resident tree's converts none."""
+    cfg = _mixed_cfg(block)
+    params = _params(cfg)
+    assert len(_weight_converts(cfg, params)) >= 6
+    held = cfg.decode_fns().resident(_host(params))
+    assert _weight_converts(cfg, held) == []
+
+
+def _as_given(cfg, params):
+    """The parent's ``set_params``: the tree on the device as it came."""
+    import jax
+    return jax.device_put(params), {"bytes": 0, "cast_bytes": 0,
+                                    "cast_leaves": 0}
+
+
+_JOBS = [("a", [3, 5, 7, 9, 11], {}), ("b", [4, 6, 8], {}),
+         ("c", [2, 3, 5, 7, 11, 13, 17], {})]
+
+
+@pytest.mark.parametrize("block", ["classic", "latent"])
+def test_engine_on_a_float32_tree_emits_the_float32_trees_tokens(
+        block, monkeypatch):
+    """An engine handed float32 host weights at bfloat16 compute casts
+    them (``stats()["params"]``) and emits, token for token, what the
+    same programs emit when they are handed the float32 tree itself."""
+    cfg = _mixed_cfg(block)
+    params = _host(_params(cfg))
+    spec = D.DecodeSpec(cfg, slots=_cpu_slots(cfg), max_tokens=9,
+                        block_size=4)
+    held, st, eng = _run_sessions(params, spec, _JOBS)
+    cast = [leaf for name, leaf in _leaf_names(params)
+            if name not in _GAINS]
+    assert st["params"]["cast_leaves"] == len(cast)
+    assert st["params"]["cast_bytes"] == sum(x.nbytes for x in cast) // 2
+    assert st["params"]["bytes"] == st["params"]["cast_bytes"] + sum(
+        leaf.nbytes for name, leaf in _leaf_names(params) if name in _GAINS)
+    monkeypatch.setattr(D.DecodeEngine, "_resident", staticmethod(_as_given))
+    wide, st, _ = _run_sessions(params, spec, _JOBS)
+    assert st["params"]["cast_leaves"] == 0
+    assert held == wide and all(len(t) == 9 for t in held.values())
+
+
+def test_set_params_while_serving_casts_the_new_tree():
+    """A hot reload is cast like the first tree and swapped in between
+    iterations: the next session's tokens are a fresh engine's on the new
+    tree, and ``stats()["params"]`` is the new tree's."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflowonspark_tpu.models import transformer as T
+
+    cfg = _mixed_cfg("classic")
+    old = _host(_params(cfg))
+    new = _host(T.init(jax.random.PRNGKey(5), cfg))
+    # one leaf of the new tree comes in the compute type already
+    new["head"] = np.asarray(jnp.asarray(new["head"]).astype(jnp.bfloat16))
+    spec = D.DecodeSpec(cfg, slots=2, max_tokens=8, block_size=4)
+    fresh, st_new, _ = _run_sessions(new, spec, _JOBS[:1])
+    stale, _, _ = _run_sessions(old, spec, _JOBS[:1])
+    done = {}
+
+    def emit(kind, sid, *rest):
+        if kind in ("done", "error"):
+            done[sid] = (kind, rest[0])
+
+    def result(sid):
+        deadline = time.time() + 300
+        while sid not in done and time.time() < deadline:
+            time.sleep(0.01)
+        assert done[sid][0] == "done", done.get(sid)
+        return done[sid][1]
+
+    eng = D.DecodeEngine(old, spec, emit)
+    eng.start(timeout=300)
+    try:
+        # another prompt than the one served after the swap: a prefix
+        # the trie kept would bring the old tree's K/V with it
+        eng.submit("old", _JOBS[1][1])
+        result("old")
+        st_old = eng.stats()
+        eng.set_params(new)
+        eng.submit("a", _JOBS[0][1])
+        after, st = result("a"), eng.stats()
+    finally:
+        eng.stop()
+    assert after == fresh["a"] != stale["a"]
+    assert st_old["params"]["cast_leaves"] == 6
+    assert st["params"] == st_new["params"]
+    assert st["params"]["cast_leaves"] == 5
+    assert st["params"]["bytes"] == st_old["params"]["bytes"]
+    assert st_old["params"]["cast_bytes"] - st["params"]["cast_bytes"] \
+        == new["head"].nbytes
+
+
+def test_speculative_draft_tree_is_resident_and_put_up_once(monkeypatch):
+    """The existing gate with a draft whose weights are float32 host
+    arrays multiplied in bfloat16: output token-identical to plain
+    decoding, the draft's tree cast like the target's and put on the
+    device once — not once a draft step."""
+    import jax
+
+    cfg = _mixed_cfg("classic")
+    params = _host(_params(cfg))
+    dcfg, dparams = _draft()
+    dcfg = _mixed_cfg("classic", dim=dcfg.dim, n_layers=dcfg.n_layers)
+    dparams = _host(dparams)
+    calls = []
+    plain_resident = D.DecodeEngine._resident
+
+    def counted(cfg_, tree):
+        calls.append(cfg_)
+        return plain_resident(cfg_, tree)
+
+    monkeypatch.setattr(D.DecodeEngine, "_resident", staticmethod(counted))
+    plain, _, _ = _run_sessions(
+        params, D.DecodeSpec(cfg, slots=2, max_tokens=9, block_size=4),
+        _JOBS)
+    assert calls == [cfg]
+    specd, st, eng = _run_sessions(
+        params, D.DecodeSpec(cfg, slots=2, max_tokens=9, block_size=4,
+                             draft_params=dparams, draft_cfg=dcfg,
+                             spec_window=3), _JOBS)
+    assert specd == plain
+    assert st["spec_proposed"] > 0 and st["iterations"] > 3
+    assert calls == [cfg, cfg, dcfg]
+    for name, leaf in _leaf_names(eng._draft_params):
+        assert isinstance(leaf, jax.Array)
+        assert leaf.dtype == ("float32" if name in _GAINS else "bfloat16")
+    _both_caches_clean(eng)
+
+
+def test_no_float32_copy_of_a_cast_leaf_outlives_set_params():
+    """After ``set_params`` the engine references the resident tree
+    only, and no float32 array with a cast leaf's shape is left on the
+    device (widths no other test uses)."""
+    import gc
+
+    import jax
+
+    cfg = _mixed_cfg("classic", vocab_size=67, dim=24, n_heads=2,
+                     mlp_ratio=3)
+    params = _host(_params(cfg))
+    eng = D.DecodeEngine(params, D.DecodeSpec(cfg, slots=2, block_size=4),
+                         lambda *a: None)
+    eng.start(timeout=300)
+    try:
+        eng.set_params(_host(_params(cfg)))
+        cast = [leaf.shape for name, leaf in _leaf_names(params)
+                if name not in _GAINS]
+        held = _leaf_names(eng._params)
+        assert all(leaf.dtype == "bfloat16" for name, leaf in held
+                   if name not in _GAINS)
+        assert eng._params_stats["cast_leaves"] == len(cast) == 6
+        gc.collect()
+        wide = [a for a in jax.live_arrays()
+                if a.dtype == "float32" and a.shape in cast]
+        assert wide == []
+    finally:
+        eng.stop()
+
+
 # --- Server / HTTP e2e ------------------------------------------------------
 
 def test_server_generate_and_http_roundtrip(tmp_path):

@@ -132,3 +132,29 @@ def test_compiles_for_described_v5e(v5e, name):
     resident = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 + mem.output_size_in_bytes)
     assert resident < 16 * 2 ** 30, f"{name} needs {resident} bytes"
+
+
+@pytest.mark.parametrize("tree", ["float32", "resident"])
+def test_step_of_the_resident_tree_converts_no_weight(v5e, tree):
+    """What the serving engine holds (``DecodeFns.resident``: the matrices
+    and the table in the compute type) leaves the compiled step's entry
+    computation without a conversion; the float32 tree's converts the
+    table and the four stacked matrices there, once a step (the
+    control)."""
+    import re
+
+    fn, args, _ = _programs(v5e)["decode_step_paged"]
+    params = args[0]
+    if tree == "resident":
+        held = jax.eval_shape(LM.decode_fns().resident, params)
+        params = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+            held)
+    text = jax.jit(fn).lower(params, *args[1:]).compile().as_text()
+    entry = re.search(r"^ENTRY .*?^}", text, re.S | re.M).group(0)
+    converted = re.findall(r"= (\S+?)\{[^ ]* convert\(", entry)
+    if tree == "resident":
+        assert converted == []
+    else:
+        assert len(converted) == 5 and all(
+            c.startswith("bf16[") for c in converted), converted
