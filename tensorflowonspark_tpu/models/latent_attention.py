@@ -15,6 +15,10 @@ No reference counterpart (pre-LLM design).  For a normed input ``h``:
     score_h      = (q_nope_h . k_nope_h + q_rope_h . k_rope) * s
     s            = (nope + rope)^-0.5 * m^2  (``ops.yarn_softmax_scale``)
 
+Without rotary (``cfg.qk_rotary`` False, the published ``mla_use_nope``)
+nothing is rotated: the "rope" part of q and of the shared key enter the
+score as they are projected, and no position enters the layer at all.
+
 Two paths compute the same attention:
 
 - :func:`attend_expanded` (prefill): expand ``k_nope_h`` / ``v_h`` for the
@@ -56,28 +60,35 @@ def init(key, cfg, dtype):
 
 
 def rope_tables(cfg, length):
+    """``(cos, sin)``, or ``(None, None)`` for a block without rotary
+    (``cfg.qk_rotary`` False): :func:`project` then rotates nothing."""
+    if not cfg.qk_rotary:
+        return None, None
     return ops.rope_angles(length, cfg.qk_rope_dim, cfg.rope_base,
                            scaling=cfg.rope_scaling)
 
 
 def project(p, y, cfg, cos, sin, positions=None):
     """``y`` [B, T, dim] (normed) -> ``(q [B, T, H, nope + rope], rows
-    [B, T, rank + rope])``, rotary applied to both rope parts."""
+    [B, T, rank + rope])``, rotary applied to both rope parts (to
+    neither where the tables are None: the "rope" part is then one more
+    slice of the key that all heads share)."""
     b, t, _ = y.shape
     nope, r = cfg.qk_nope_dim, cfg.kv_lora_rank
     with jax.named_scope("attn/latent_q"):
         q = _matmul(y, p["wq"]).reshape(b, t, cfg.n_heads, cfg.q_head_dim)
         if cfg.qk_norm:
-            q = ops.rmsnorm_reference(q, p["q_norm"])
-        q = jnp.concatenate(
-            [q[..., :nope],
-             ops.apply_rope(q[..., nope:], cos, sin, positions=positions)],
-            axis=-1)
+            q = ops.rmsnorm_reference(q, p["q_norm"], cfg.norm_eps)
+        if cos is not None:
+            q = jnp.concatenate(
+                [q[..., :nope],
+                 ops.apply_rope(q[..., nope:], cos, sin,
+                                positions=positions)], axis=-1)
     with jax.named_scope("attn/latent_kv"):
         kva = _matmul(y, p["wkva"])
-        c = ops.rmsnorm_reference(kva[..., :r], p["kv_norm"])
-        k_rope = ops.apply_rope(kva[..., None, r:], cos, sin,
-                                positions=positions)[:, :, 0]
+        c = ops.rmsnorm_reference(kva[..., :r], p["kv_norm"], cfg.norm_eps)
+        k_rope = kva[..., r:] if cos is None else ops.apply_rope(
+            kva[..., None, r:], cos, sin, positions=positions)[:, :, 0]
         return q, jnp.concatenate([c, k_rope], axis=-1)
 
 

@@ -21,16 +21,23 @@ and ``decode_step_paged``.  The LATENT block (``attn_kind="latent"``:
 latent attention, a gated SwiGLU MLP in the leading dense layers, an
 expert layer with a shared expert in the rest, weights in
 ``param_dtype``) is the section "The latent block", with the same three
-programs.  ``init`` and ``apply`` dispatch on the config, and the serving
-engine takes either block's incremental functions and cache layout from
-``Config.decode_fns()``: one paged cache, one step (ROADMAP.md Design 4
-has what still keeps the two sets of programs apart).
+programs.  Its HYBRID form (``linear_layers``: a mixer per layer, the
+layers named mixing by the gated delta rule of
+``models/linear_attention.py`` and keeping per-session state instead of
+rows, the others latent attention, here without rotary) is the section
+"The hybrid block", with ``prefill`` and the step (a tail over a cached
+prefix would need the state at the prefix's end).  ``init`` and ``apply``
+dispatch on the config, and the serving engine takes any block's
+incremental functions and cache layout from ``Config.decode_fns()``: one
+paged cache, one step (ROADMAP.md Design 4 has what still keeps the sets
+of programs apart).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +47,7 @@ from jax.sharding import PartitionSpec as P
 from tensorflowonspark_tpu import ops
 from tensorflowonspark_tpu.models import latent_attention as latent
 from tensorflowonspark_tpu.models import layers as L
+from tensorflowonspark_tpu.models import linear_attention as linear
 from tensorflowonspark_tpu.models import moe
 
 
@@ -52,7 +60,7 @@ class Config:
     max_seq: int = 2048
     mlp_ratio: int = 4
     rope_base: float = 10000.0
-    dtype: str = "bfloat16"  # compute dtype; params always float32
+    dtype: str = "bfloat16"  # compute dtype; weights: ``param_dtype``
     # 'flash' = pallas kernel (single-chip / shard_map contexts only:
     # GSPMD cannot auto-partition a pallas_call); 'reference' = pure XLA
     # einsum formulation, partitionable by GSPMD on any mesh.
@@ -77,6 +85,20 @@ class Config:
     expert_dim: int = 0
     n_shared_experts: int = 0
     routed_scale: float = 1.0
+    qk_rotary: bool = True           # latent: rotate the rope part of q and
+    #                                  of the shared key (False: no position
+    #                                  enters the score at all)
+    norm_eps: float = 1e-6           # the latent block's RMSNorms
+    # -- a mixer per layer: the layers named here (0-based) mix by the
+    # gated delta rule (models/linear_attention.py), the others by
+    # ``attn_kind``.  The default is one kind for all layers.
+    linear_layers: tuple = ()
+    linear_heads: int = 0
+    linear_head_dim: int = 0
+    linear_conv: int = 4             # length of the short convolution
+    linear_rank: int = 0             # width of the decay's and the output
+    #                                  gate's low-rank maps
+    state_dtype: str = "float32"     # a session's recurrent state
 
     def __post_init__(self):
         if self.attn_kind not in ("mha", "latent"):
@@ -99,6 +121,22 @@ class Config:
                 "classic block: GELU MLP, no experts, plain rotary.  A "
                 "gated MLP, experts or rope scaling need "
                 "attn_kind='latent'")
+        if self.linear_layers:
+            dense = {i in self.linear_layers
+                     for i in range(self.dense_layers)}
+            if (self.attn_kind != "latent"
+                    or tuple(sorted(set(self.linear_layers)))
+                    != tuple(self.linear_layers)
+                    or not 0 <= self.linear_layers[0]
+                    or self.linear_layers[-1] >= self.n_layers
+                    or min(self.linear_heads, self.linear_head_dim,
+                           self.linear_rank) < 1 or self.linear_conv < 2
+                    or len(dense) > 1):
+                raise ValueError(
+                    "linear_layers names, in order, layers of a latent "
+                    "block (attn_kind='latent') and needs linear_heads, "
+                    "linear_head_dim, linear_rank >= 1 and linear_conv >= "
+                    "2; the leading dense layers share one kind of mixer")
         if self.n_experts:
             held = self.n_experts_held or self.n_experts
             if not (0 < self.experts_per_token <= self.n_experts
@@ -139,6 +177,12 @@ class Config:
         expert model."""
         return self.n_dense_layers if self.n_experts else 0
 
+    @property
+    def mixers(self):
+        """Each layer's mixer, in order: ``"kda"`` | ``attn_kind``."""
+        return tuple("kda" if i in self.linear_layers else self.attn_kind
+                     for i in range(self.n_layers))
+
     def decode_fns(self):
         """The seam between this model and the serving engine
         (:class:`DecodeFns`): the incremental functions and the cache's
@@ -164,15 +208,26 @@ def _layer_init(key, cfg):
 def init(key, cfg: Config):
     """Params pytree in ``cfg.param_dtype``; per-layer trees stacked on a
     leading axis so apply() scans one compiled layer body.  The latent
-    block's leading dense layers are a second stack, ``dense_layers``."""
+    block's leading dense layers are a second stack, ``dense_layers``,
+    and the layers after them that mix by the gated delta rule a third,
+    ``kda_layers`` (``layers`` then holds the others, in order)."""
     k_embed, k_head, k_layers = jax.random.split(key, 3)
     dt = jnp.dtype(cfg.param_dtype)
     nd = cfg.dense_layers
     layer_init = _layer_init if cfg.classic else functools.partial(
         _latent_layer_init, expert=bool(cfg.n_experts))
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
+    mixers = cfg.mixers
+
+    def keys_of(kda):
+        """The keys of the layers after the dense ones that mix so."""
+        return layer_keys[jnp.asarray(
+            [i for i in range(nd, cfg.n_layers)
+             if (mixers[i] == "kda") == kda], jnp.int32)]
+
     layers = jax.vmap(lambda k: layer_init(k, cfg))(
-        layer_keys[nd:] if nd else layer_keys)
+        keys_of(False) if cfg.linear_layers
+        else layer_keys[nd:] if nd else layer_keys)
     params = {
         "embed": (jax.random.normal(
             k_embed, (cfg.vocab_size, cfg.dim), jnp.float32
@@ -183,8 +238,12 @@ def init(key, cfg: Config):
     }
     if nd:
         params["dense_layers"] = jax.vmap(
-            lambda k: _latent_layer_init(k, cfg, expert=False)
+            lambda k: _latent_layer_init(k, cfg, expert=False,
+                                         mixer=mixers[0])
         )(layer_keys[:nd])
+    if cfg.linear_layers:
+        params["kda_layers"] = jax.vmap(
+            lambda k: layer_init(k, cfg, mixer="kda"))(keys_of(True))
     return params
 
 
@@ -284,9 +343,9 @@ def _default_attn_fn(cfg, attn_fn):
     return functools.partial(base, causal=True)
 
 
-def _lm_head(params, x, logits_dtype, return_hidden):
+def _lm_head(params, x, logits_dtype, return_hidden, eps=1e-6):
     with jax.named_scope("lm_head"):
-        x = ops.rmsnorm_reference(x, params["ln_f"])
+        x = ops.rmsnorm_reference(x, params["ln_f"], eps)
         if return_hidden:
             return x
         logits = _matmul(x, params["head"])
@@ -518,10 +577,10 @@ def _classic_only(cfg, name):
             f"has its own (cfg.decode_fns() hands out either block's)")
 
 
-def _last_logits(params, x, lengths):
+def _last_logits(params, x, lengths, eps=1e-6):
     """Head over each row's final REAL position: [B, T, dim] -> [B, vocab]."""
     b, t, _ = x.shape
-    x = ops.rmsnorm_reference(x, params["ln_f"])
+    x = ops.rmsnorm_reference(x, params["ln_f"], eps)
     if lengths is None:
         last = jnp.full((b,), t - 1, jnp.int32)
     else:
@@ -766,11 +825,14 @@ def prefill_extend(params, tokens, cfg: Config, pool_k, pool_v,
 # engine donates it), so nothing pool-sized is copied.
 # ---------------------------------------------------------------------------
 
-def _latent_layer_init(key, cfg, expert):
+def _latent_layer_init(key, cfg, expert, mixer="latent"):
     ka, kf = jax.random.split(key)
     dt = jnp.dtype(cfg.param_dtype)
-    p = {"ln1": jnp.ones((cfg.dim,), dt), "ln2": jnp.ones((cfg.dim,), dt),
-         "attn": latent.init(ka, cfg, dt)}
+    p = {"ln1": jnp.ones((cfg.dim,), dt), "ln2": jnp.ones((cfg.dim,), dt)}
+    if mixer == "kda":
+        p["kda"] = linear.init(ka, cfg, dt)
+    else:
+        p["attn"] = latent.init(ka, cfg, dt)
     if expert:
         p["moe"] = moe.init(
             kf, cfg.dim, cfg.expert_dim, cfg.n_experts,
@@ -789,8 +851,9 @@ def _latent_ffn(p, x, cfg, index, live=None):
     """``(x + FFN(norm(x)), expert statistics or None)`` of layer
     ``index``; ``live`` marks the tokens whose routing the statistics
     count.  An expert layer's ``p["moe"]`` holds the routed experts of
-    ALL expert layers (``_latent_layers``)."""
-    y = ops.rmsnorm_reference(x, p["ln2"])
+    ALL the expert layers of its stack (``_latent_layers``), and layer
+    ``index`` is ``index - cfg.dense_layers`` of them."""
+    y = ops.rmsnorm_reference(x, p["ln2"], cfg.norm_eps)
     if "moe" in p:
         y, stats = moe.apply(
             p["moe"], y, top_k=cfg.experts_per_token,
@@ -852,7 +915,7 @@ def _latent_forward(params, tokens, cfg, attn_fn):
     cos, sin = latent.rope_tables(cfg, tokens.shape[1])
 
     def layer(p, x, index):
-        y = ops.rmsnorm_reference(x, p["ln1"])
+        y = ops.rmsnorm_reference(x, p["ln1"], cfg.norm_eps)
         q, rows = latent.project(p["attn"], y, cfg, cos, sin)
         a = latent.attend_expanded(p["attn"], q, rows, cfg, attn_fn)
         x, stats = _latent_ffn(p, x + _matmul(a, p["attn"]["wo"]), cfg,
@@ -865,8 +928,9 @@ def _latent_forward(params, tokens, cfg, attn_fn):
 
 def _latent_apply(params, tokens, cfg, *, attn_fn, logits_dtype,
                   return_hidden):
-    x, _rows = _latent_forward(params, tokens, cfg, attn_fn)
-    return _lm_head(params, x, logits_dtype, return_hidden)
+    forward = _hybrid_forward if cfg.linear_layers else _latent_forward
+    x, _rows = forward(params, tokens, cfg, attn_fn)
+    return _lm_head(params, x, logits_dtype, return_hidden, cfg.norm_eps)
 
 
 def latent_prefill(params, tokens, cfg: Config, *, lengths=None,
@@ -875,7 +939,8 @@ def latent_prefill(params, tokens, cfg: Config, *, lengths=None,
     at each row's last real position, rows [B, n_layers, T,
     latent_row])`` — the rows are all the cache keeps of a token."""
     x, rows = _latent_forward(params, tokens, cfg, attn_fn)
-    return _last_logits(params, x, lengths), rows.transpose(1, 0, 2, 3)
+    return (_last_logits(params, x, lengths, cfg.norm_eps),
+            rows.transpose(1, 0, 2, 3))
 
 
 def _paged_context(pool, layer, tables):
@@ -919,7 +984,7 @@ def latent_decode_step_paged(params, tokens, cfg: Config, pool,
 
     def layer(p, carry, index):
         x, pool = carry
-        y = ops.rmsnorm_reference(x, p["ln1"])
+        y = ops.rmsnorm_reference(x, p["ln1"], cfg.norm_eps)
         q, rows = latent.project(p["attn"], y, cfg, cos, sin, posc)
         with jax.named_scope("write_kv"):
             pool = pool.at[wblk, index, woff].set(
@@ -931,17 +996,26 @@ def latent_decode_step_paged(params, tokens, cfg: Config, pool,
         return (x, pool), None, stats
 
     (x, pool), _, stats = _latent_layers(params, cfg, (x, pool), layer)
-    x = ops.rmsnorm_reference(x, params["ln_f"])
-    logits = _matmul(x, params["head"]).astype(jnp.float32)
-    counters = {}
-    if stats is not None:
-        counters = {f"moe_{k}": jnp.sum(v) for k, v in stats.items()
-                    if k != "tokens_per_expert_max"}
-        counters["moe_tokens_per_expert_max"] = jnp.max(
-            stats["tokens_per_expert_max"])
-        counters["moe_layers"] = jnp.asarray(
-            cfg.n_layers - cfg.dense_layers, jnp.int32)
-    return logits, pool, counters
+    return _step_logits(params, x, cfg), pool, _step_counters(cfg, stats)
+
+
+def _step_logits(params, x, cfg):
+    x = ops.rmsnorm_reference(x, params["ln_f"], cfg.norm_eps)
+    return _matmul(x, params["head"]).astype(jnp.float32)
+
+
+def _step_counters(cfg, stats):
+    """A step's dispatch counts summed over its expert layers (``stats``:
+    each counter stacked over them; None for a model without experts)."""
+    if stats is None:
+        return {}
+    counters = {f"moe_{k}": jnp.sum(v) for k, v in stats.items()
+                if k != "tokens_per_expert_max"}
+    counters["moe_tokens_per_expert_max"] = jnp.max(
+        stats["tokens_per_expert_max"])
+    counters["moe_layers"] = jnp.asarray(
+        cfg.n_layers - cfg.dense_layers, jnp.int32)
+    return counters
 
 
 def latent_prefill_extend(params, tokens, cfg: Config, pool, prefix_tables,
@@ -971,7 +1045,7 @@ def latent_prefill_extend(params, tokens, cfg: Config, pool, prefix_tables,
     x = params["embed"].astype(dtype)[tokens]
 
     def layer(p, x, index):
-        y = ops.rmsnorm_reference(x, p["ln1"])
+        y = ops.rmsnorm_reference(x, p["ln1"], cfg.norm_eps)
         q, rows = latent.project(p["attn"], y, cfg, cos, sin, pos)
         ctx = jnp.concatenate(
             [_paged_context(pool, index, ptab).astype(dtype), rows], axis=1)
@@ -981,31 +1055,227 @@ def latent_prefill_extend(params, tokens, cfg: Config, pool, prefix_tables,
         return x, rows, stats
 
     x, rows, _ = _latent_layers(params, cfg, x, layer)
-    return _last_logits(params, x, lengths), rows.transpose(1, 0, 2, 3)
+    return (_last_logits(params, x, lengths, cfg.norm_eps),
+            rows.transpose(1, 0, 2, 3))
+
+
+# ---------------------------------------------------------------------------
+# The hybrid block: the latent block with a mixer PER LAYER
+# (``cfg.linear_layers``): a layer named there mixes by the gated delta
+# rule (models/linear_attention.py) and keeps a recurrent state and a short
+# convolution history per SESSION; the others are latent attention as above
+# and keep rows per token.  The FFN side (``_latent_ffn``) is the latent
+# block's.  Weights are three stacks: ``dense_layers``, ``kda_layers`` (the
+# expert layers that mix by the delta rule) and ``layers`` (the others).
+# Consecutive layers of one stack run under one scan, a lone layer unrolled;
+# a stack is reached by index inside the scan and never sliced (a slice of
+# stacked weights in front of a loop is copied).
+#
+# Its cache has three entries (``DecodeFns.rows``): the latent pool over the
+# latent layers only, and the state and the history over the delta-rule
+# layers, ``[those layers, slots, ...]``, indexed by the step's row = slot.
+# ---------------------------------------------------------------------------
+
+def _hybrid_runs(cfg):
+    """``[(stack, mixer, first index in the stack, first index among the
+    layers of that mixer, count), ...]``: runs of consecutive layers that
+    share a weight stack."""
+    runs, in_stack, of_mixer = [], {}, {}
+    for i, mixer in enumerate(cfg.mixers):
+        stack = ("dense_layers" if i < cfg.dense_layers
+                 else "kda_layers" if mixer == "kda" else "layers")
+        if runs and runs[-1][0] == stack:
+            runs[-1][-1] += 1
+        else:
+            runs.append([stack, mixer, in_stack.get(stack, 0),
+                         of_mixer.get(mixer, 0), 1])
+        in_stack[stack] = in_stack.get(stack, 0) + 1
+        of_mixer[mixer] = of_mixer.get(mixer, 0) + 1
+    return runs
+
+
+def _hybrid_layers(params, cfg, carry, layer_fn):
+    """Run ``layer_fn(p, carry, mixer, cache_index, index) -> (carry,
+    out, stats)`` over every layer in order.  ``cache_index`` counts the
+    layers of that mixer (its layer of the cache's entries); ``index`` is
+    what ``_latent_ffn`` takes: the layer's place in its OWN stack, as if
+    that stack followed the dense layers.  Returns ``(carry, {mixer: outs
+    stacked over its layers}, expert statistics stacked over the expert
+    layers or None)``."""
+    outs, stats = {}, []
+    for stack, mixer, first, cache_first, count in _hybrid_runs(cfg):
+        layers, bank = params[stack], {}
+        if "moe" in layers:
+            bank = {k: layers["moe"][k].reshape(
+                (-1,) + layers["moe"][k].shape[2:])
+                for k in ("wg", "wu", "wd")}
+            layers = dict(layers, moe={k: v for k, v in layers["moe"].items()
+                                       if k not in bank})
+
+        def body(carry, i, layers=layers, bank=bank, mixer=mixer,
+                 first=first, cache_first=cache_first):
+            p = jax.tree_util.tree_map(lambda a: a[first + i], layers)
+            if bank:
+                p = dict(p, moe=dict(p["moe"], **bank))
+            carry, out, st = layer_fn(p, carry, mixer, cache_first + i,
+                                      cfg.dense_layers + first + i)
+            return carry, (out, st)
+
+        if count == 1:
+            carry, (out, st) = body(carry, 0)
+            out, st = jax.tree_util.tree_map(lambda a: a[None], (out, st))
+        else:
+            carry, (out, st) = lax.scan(body, carry, jnp.arange(count))
+        outs.setdefault(mixer, []).append(out)
+        if st is not None:
+            stats.append(st)
+    cat = lambda xs: jax.tree_util.tree_map(
+        lambda *a: jnp.concatenate(a), *xs)
+    return (carry, {m: cat(o) for m, o in outs.items() if o[0] is not None},
+            cat(stats) if stats else None)
+
+
+def _hybrid_forward(params, tokens, cfg, attn_fn, lengths=None):
+    """Whole-sequence pass (chunked delta rule, expanded latent path):
+    ``(hidden [B, T, dim], (rows [latent layers, B, T, latent_row], state
+    [kda layers, B, H, d, d], history [kda layers, B, K - 1, 3*H*d]))``,
+    state and history as they stand after ``lengths`` tokens."""
+    attn_fn = _default_attn_fn(cfg, attn_fn)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    cos, sin = latent.rope_tables(cfg, tokens.shape[1])
+
+    def layer(p, x, mixer, _cache_index, index):
+        y = ops.rmsnorm_reference(x, p["ln1"], cfg.norm_eps)
+        if mixer == "kda":
+            a, state, conv = linear.mix_prefill(p["kda"], y, cfg, lengths)
+            out = (state, conv)
+        else:
+            q, out = latent.project(p["attn"], y, cfg, cos, sin)
+            a = _matmul(latent.attend_expanded(p["attn"], q, out, cfg,
+                                               attn_fn), p["attn"]["wo"])
+        x, stats = _latent_ffn(p, x + a, cfg, index)
+        return x, out, stats
+
+    x, outs, _ = _hybrid_layers(params, cfg, x, layer)
+    return x, (outs["latent"],) + outs["kda"]
+
+
+def hybrid_prefill(params, tokens, cfg: Config, *, lengths=None,
+                   attn_fn=None):
+    """The hybrid block's :func:`prefill`: ``(logits [B, vocab] float32
+    at each row's last real position, (rows [B, latent layers, T,
+    latent_row], state [B, kda layers, H, d, d], history [B, kda layers,
+    K - 1, 3*H*d]))``: all the cache keeps of a sequence."""
+    x, kept = _hybrid_forward(params, tokens, cfg, attn_fn, lengths)
+    return (_last_logits(params, x, lengths, cfg.norm_eps),
+            tuple(jnp.swapaxes(a, 0, 1) for a in kept))
+
+
+def hybrid_decode_step_paged(params, tokens, cfg: Config, pools,
+                             block_tables, lengths):
+    """The hybrid block's :func:`decode_step_paged`: ``pools`` = (latent
+    pool [num_blocks, latent layers, block_size, latent_row], state
+    [kda layers, slots, H, d, d], history [kda layers, slots, K - 1,
+    3*H*d]), all three loop carries written in place (the engine donates
+    them).  One token a slot: a recurrent state cannot take back a window
+    whose tail is rejected.  A free slot's state is stepped like any
+    other (finite, and overwritten whole when the slot is next given
+    out).  Returns ``(logits [S, 1, vocab] float32, pools, counters)``."""
+    dtype = cfg.compute_dtype
+    s_slots, w = tokens.shape
+    if w != 1:
+        raise ValueError(
+            f"a layout with per-session state steps one token a slot, "
+            f"not a window of {w}")
+    pool = pools[0]
+    bs = pool.shape[2]
+    cap = block_tables.shape[1] * bs
+    lengths = jnp.asarray(lengths, jnp.int32)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    pos = lengths[:, None]
+    posc = jnp.clip(pos, 0, cap - 1)
+    wblk = jnp.where(pos < cap,
+                     jnp.take_along_axis(tables, posc // bs, axis=1),
+                     0).reshape(-1)
+    woff = (posc % bs).reshape(-1)
+    kv_mask = (jnp.arange(cap)[None, None, None, :]
+               <= pos[:, None, :, None])                 # [S, 1, 1, cap]
+    x = params["embed"].astype(dtype)[tokens]            # [S, 1, dim]
+    cos, sin = latent.rope_tables(cfg, cap)
+    live = (lengths > 0)[:, None]
+
+    def layer(p, carry, mixer, at, index):
+        x, pool, state, conv = carry
+        y = ops.rmsnorm_reference(x, p["ln1"], cfg.norm_eps)
+        if mixer == "kda":
+            a, s_new, c_new = linear.mix_step(p["kda"], y, cfg,
+                                              state[at], conv[at])
+            with jax.named_scope("write_state"):
+                state = state.at[at].set(s_new)
+                conv = conv.at[at].set(c_new)
+        else:
+            q, rows = latent.project(p["attn"], y, cfg, cos, sin, posc)
+            with jax.named_scope("write_kv"):
+                pool = pool.at[wblk, at, woff].set(
+                    rows.reshape(-1, rows.shape[-1]).astype(pool.dtype))
+            ctx = _paged_context(pool, at, tables)
+            a = _matmul(latent.attend_absorbed(p["attn"], q, ctx, kv_mask,
+                                               cfg), p["attn"]["wo"])
+        x, stats = _latent_ffn(p, x + a, cfg, index, live)
+        return (x, pool, state, conv), None, stats
+
+    (x, *pools), _, stats = _hybrid_layers(params, cfg, (x, *pools), layer)
+    return (_step_logits(params, x, cfg), tuple(pools),
+            _step_counters(cfg, stats))
 
 
 # ---------------------------------------------------------------------------
 # The seam between a model and the serving engine.
 # ---------------------------------------------------------------------------
 
+class CacheEntry(typing.NamedTuple):
+    """One entry of a model's cache layout (``DecodeFns.rows``).
+
+    ``shape`` is what ONE layer keeps of one sequence.  With a ``None``
+    in it the entry is ROWS PER TOKEN, the ``None`` standing for the token
+    axis: a paged pool ``[num_blocks, layers, *shape]`` with ``block_size``
+    for the None, a prefill handing back ``[B, layers, *shape]`` with T
+    for it.  Without one it is PER-SESSION STATE of a fixed size,
+    ``[layers, slots, *shape]``, a prefill handing back ``[B, layers,
+    *shape]``: written whole when a session is admitted, owned with the
+    slot, indexed by the step's row, never paged and never shared.
+    ``layers`` counts the layers that HAVE such an entry (a model's mixers
+    may differ by layer); ``dtype`` None means the cache's own."""
+    name: str
+    shape: tuple
+    layers: int
+    dtype: object = None
+
+    @property
+    def paged(self):
+        return None in self.shape
+
+
 @dataclasses.dataclass(frozen=True)
 class DecodeFns:
     """What ``serving/decode`` needs of a model, and all it knows of it.
 
-    ``rows``: the cache's layout, ``((pool name, per-layer shape of one
-    sequence with None for the token axis), ...)`` — a paged pool is
-    ``[num_blocks, n_layers, *shape]`` with ``block_size`` for None, a
-    prefill hands back ``[B, n_layers, *shape]`` with T for None.
-    ``pools`` below is the tuple of pools in that order.
+    ``rows``: the cache's layout, a tuple of :class:`CacheEntry` — rows
+    per token (paged pools) and per-session state, each over the layers
+    that have it.  ``pools`` below is the tuple of the cache's arrays in
+    that order, ``rows`` of a prefill the tuple of what it hands back.
 
     ``prefill(params, tokens, lengths) -> (logits, rows)``
     ``prefill_extend(params, tokens, pools, prefix_tables, prefix_lens,
-    lengths) -> (logits, rows)``
+    lengths) -> (logits, rows)``, or None: a layout with state has none
+    (a prefix's rows can be mapped, the state at its end is not kept)
     ``decode_step_paged(params, tokens [S, W], pools, block_tables,
-    lengths) -> (logits, pools, counters)``; ``counters`` is a (possibly
-    empty) dict of scalars: the engine sums each over steps (keeps the
-    maximum of a name ending in ``_max``) and ``summarize(totals)`` turns
-    the totals into what ``stats()`` shows.
+    lengths) -> (logits, pools, counters)``; row s of a state entry is
+    slot s's; ``counters`` is a (possibly empty) dict of scalars: the
+    engine sums each over steps (keeps the maximum of a name ending in
+    ``_max``) and ``summarize(totals)`` turns the totals into what
+    ``stats()`` shows.
     ``donate``: the paged step may overwrite the pools it is given (the
     cache's insert always does).
     ``resident(params) -> params``: the tree as the engine should HOLD it
@@ -1020,6 +1290,10 @@ class DecodeFns:
     donate: bool = False
     summarize: object = None
     resident: object = lambda params: params
+
+    @property
+    def has_state(self):
+        return any(not entry.paged for entry in self.rows)
 
 
 def _summarize_moe(totals):
@@ -1047,7 +1321,10 @@ def _summarize_moe(totals):
 _CLASSIC_CAST = frozenset({"embed", "head", "wqkv", "wo", "w1", "w2"})
 _LATENT_CAST = frozenset({
     "embed", "head", "wq", "wkva", "wkvb", "wo", "wg", "wu", "wd",
-    "router", "shared_wg", "shared_wu", "shared_wd"})
+    "router", "shared_wg", "shared_wu", "shared_wd",
+    # the delta-rule mixer's matrices (its ``conv``, ``a_log``, ``dt_bias``
+    # and ``o_norm`` enter float32 sums)
+    "wqkv", "wfa", "wfb", "wga", "wgb", "wbeta"})
 
 
 def _resident(params, names, dtype):
@@ -1072,7 +1349,8 @@ def _decode_fns(cfg):
         _resident, names=_CLASSIC_CAST if cfg.classic else _LATENT_CAST,
         dtype=cfg.compute_dtype)
     if cfg.classic:
-        per_head = (cfg.n_heads, None, cfg.head_dim)
+        per_head = CacheEntry("k", (cfg.n_heads, None, cfg.head_dim),
+                              cfg.n_layers)
 
         def prefill_fn(p, toks, lens):
             logits, k, v = prefill(p, toks, cfg, lengths=lens)
@@ -1088,9 +1366,31 @@ def _decode_fns(cfg):
                                              lens)
             return logits, (k, v), {}
 
-        return DecodeFns(rows=(("k", per_head), ("v", per_head)),
+        return DecodeFns(rows=(per_head, per_head._replace(name="v")),
                          prefill=prefill_fn, prefill_extend=extend_fn,
                          decode_step_paged=step_paged_fn, resident=resident)
+
+    summarize = _summarize_moe if cfg.n_experts else None
+    if cfg.linear_layers:
+        n_kda = len(cfg.linear_layers)
+        state, conv = linear.state_shapes(cfg)
+
+        def prefill_fn(p, toks, lens):
+            return hybrid_prefill(p, toks, cfg, lengths=lens)
+
+        def step_paged_fn(p, toks, pools, tables, lens):
+            return hybrid_decode_step_paged(p, toks, cfg, pools, tables,
+                                            lens)
+
+        return DecodeFns(
+            rows=(CacheEntry("kv", (None, cfg.latent_row),
+                             cfg.n_layers - n_kda),
+                  CacheEntry("state", state, n_kda,
+                             jnp.dtype(cfg.state_dtype)),
+                  CacheEntry("conv", conv, n_kda)),
+            prefill=prefill_fn, prefill_extend=None,
+            decode_step_paged=step_paged_fn, donate=True,
+            summarize=summarize, resident=resident)
 
     def prefill_fn(p, toks, lens):
         logits, rows = latent_prefill(p, toks, cfg, lengths=lens)
@@ -1106,11 +1406,11 @@ def _decode_fns(cfg):
             p, toks, cfg, pools[0], tables, lens)
         return logits, (pool,), counters
 
-    return DecodeFns(rows=(("kv", (None, cfg.latent_row)),),
+    return DecodeFns(rows=(CacheEntry("kv", (None, cfg.latent_row),
+                                      cfg.n_layers),),
                      prefill=prefill_fn, prefill_extend=extend_fn,
                      decode_step_paged=step_paged_fn, donate=True,
-                     summarize=_summarize_moe if cfg.n_experts else None,
-                     resident=resident)
+                     summarize=summarize, resident=resident)
 
 
 @functools.lru_cache(maxsize=8)

@@ -4,15 +4,26 @@ No reference counterpart (the reference delegates all inference to TF
 Serving, SURVEY.md §2.2; reference Inference.scala:27-79 is offline
 batch only).
 
-What a cached token IS belongs to the model: ``cfg.decode_fns().rows``
-names the pools and gives each one's per-layer shape for one sequence,
-with ``None`` where the token axis goes (per-head keys and values:
-``("k", (heads, None, head_dim)), ("v", ...)``; a latent cache: one pool
-``("kv", (None, row_width))``).  This module allocates ``[lead,
-n_layers, *shape]`` arrays from that, reaches them as ``cache.pools`` (a
-tuple in the layout's order, also ``cache.<name>``), and moves rows in
-and out along the token axis; everything else — slots, block tables,
-refcounts, the trie — never looks inside a row.
+What is cached belongs to the model: ``cfg.decode_fns().rows`` is a tuple
+of entries (``models/transformer.CacheEntry``), each with a name, the
+shape one layer keeps of one sequence, the number of layers that keep
+such a thing, and a dtype (None: the cache's).  An entry whose shape has
+a ``None`` is ROWS PER TOKEN, the None standing for the token axis
+(per-head keys and values: ``("k", (heads, None, head_dim), n_layers)``
+and ``"v"``; a latent cache: ``("kv", (None, row_width), layers)``): a
+paged pool ``[num_blocks, layers, *shape]`` with ``block_size`` for the
+None.  An entry without one is PER-SESSION STATE of a fixed size (a
+linear-attention layer's recurrent state): ``[layers, slots, *shape]``,
+row s of every layer the state of the session in slot s — written whole
+when the session is admitted, owned with the slot, never paged, never in
+the trie.  (Layers lead because a step rewrites one layer's rows of ALL
+slots at a time: with the slots leading XLA re-lays the whole array out at
+the step's entry and again at its exit, two copies of all state a step.)
+This module allocates the arrays from that, reaches them as
+``cache.pools`` (a tuple in the layout's order, also ``cache.<name>``),
+and moves a prefill's output in: rows along the token axis by block, state
+by slot; everything else — slots, block tables, refcounts, the trie —
+never looks inside either.
 
 :class:`PagedKVCache` — block paging with ref-counted prefix sharing,
 the one cache there is (a speculative draft model has a second instance
@@ -55,24 +66,30 @@ from tensorflowonspark_tpu.utils import telemetry
 
 @functools.lru_cache(maxsize=4)
 def _kv_insert(token_axes, block_size):
-    """The paged insert as ONE named program (``jit_tfos_kv_insert``,
-    scope ``kv_insert``), on the device from end to end: row ``row`` of a
-    prefill's ``[B, n_layers, ...T...]`` outputs is cut into blocks and
-    scattered into the pools at ``blocks``.  ``blocks`` has one entry per
-    block of the PADDED length T (the prefill's bucket), the entries past
-    the prompt's own blocks naming the sentinel, so there is one program
-    per prefill shape and not one per prompt length, and no row travels
-    through the host.  The pools are donated: the cache keeps the new
-    ones, and a second copy of every pool never exists."""
+    """The insert as ONE named program (``jit_tfos_kv_insert``, scope
+    ``kv_insert``), on the device from end to end: row ``row`` of a
+    prefill's ``[B, layers, ...]`` outputs goes into the pools.  An entry
+    with a token axis (``token_axes``: its place in the entry's shape,
+    None for per-session state) is cut into blocks and scattered at
+    ``blocks``.  ``blocks`` has one entry per block of the PADDED length T
+    (the prefill's bucket), the entries past the prompt's own blocks
+    naming the sentinel, so there is one program per prefill shape and
+    not one per prompt length, and no row travels through the host.  A
+    state entry overwrites row ``slot`` of every layer of its pool.  The
+    pools are donated: the cache keeps the new ones, and a second copy of
+    a pool never exists."""
     import jax
     import jax.numpy as jnp
 
-    def tfos_kv_insert(pools, blocks, rows, row):
+    def tfos_kv_insert(pools, blocks, rows, row, slot=0):
         nb = blocks.shape[0]
         out = []
         with jax.named_scope("kv_insert"):
             for pool, r, ax in zip(pools, rows, token_axes):
                 r = jax.lax.dynamic_index_in_dim(r, row, 0, keepdims=False)
+                if ax is None:
+                    out.append(pool.at[:, slot].set(r.astype(pool.dtype)))
+                    continue
                 ax += 1                          # after the layer axis
                 pad = [(0, 0)] * r.ndim
                 pad[ax] = (0, nb * block_size - r.shape[ax])
@@ -88,6 +105,23 @@ def _kv_insert(token_axes, block_size):
 
 class CacheOOM(RuntimeError):
     """Block allocation failed even after trie reclamation."""
+
+
+def resolve_prefix_sharing(layout, asked):
+    """Whether a cache of ``layout`` keeps a prefix trie: ``asked`` (None:
+    wherever the layout allows).  A layout with per-session state does
+    not allow it: a matched prefix's rows can be mapped, but the state at
+    the end of the prefix is not kept anywhere (nothing snapshots state
+    at block boundaries yet), so the tail could not be computed."""
+    has_state = any(not entry.paged for entry in layout)
+    if asked and has_state:
+        raise ValueError(
+            "prefix_sharing=True with a cache layout that has per-session "
+            "state (" + ", ".join(e.name for e in layout if not e.paged)
+            + "): a matched prefix's rows could be mapped, but the state at "
+            "its end is not kept, so its tail cannot be prefilled; leave "
+            "prefix_sharing unset")
+    return not has_state if asked is None else bool(asked)
 
 
 class _TrieNode:
@@ -256,9 +290,12 @@ class PrefixTrie:
 class PagedKVCache:
     """Block-paged pools + per-slot block tables + prefix trie.
 
-    Device side: ``pools`` in the order of the model's row layout, each
-    ``[num_blocks, n_layers, ...block_size...]`` and also an attribute
-    under its layout name.  Host side: ``block_tables`` [slots,
+    Device side: ``pools`` in the order of the model's layout and also an
+    attribute each under its entry's name: rows per token ``[num_blocks,
+    the entry's layers, ...block_size...]``, per-session state ``[the
+    entry's layers, slots, ...]`` (a slot's row is its session's: given
+    out with the slot, overwritten whole by the session's insert).  Host
+    side: ``block_tables`` [slots,
     blocks_per_slot] int32 (unused entries point at sentinel block 0),
     ``lengths`` [slots], ``refcount`` [num_blocks], a block free list and
     a slot free list.  The model's paged step and tail prefill consume
@@ -266,7 +303,7 @@ class PagedKVCache:
     """
 
     def __init__(self, cfg, slots, block_size=None, num_blocks=None,
-                 max_seq=None, dtype=None, prefix_sharing=True):
+                 max_seq=None, dtype=None, prefix_sharing=None):
         import jax.numpy as jnp
 
         self.slots = int(slots)
@@ -289,13 +326,16 @@ class PagedKVCache:
                 "could starve")
         self.layout = cfg.decode_fns().rows
         self.dtype = dtype or cfg.compute_dtype
-        self._token_axes = tuple(shape.index(None)
-                                 for _name, shape in self.layout)
+        self._token_axes = tuple(
+            entry.shape.index(None) if entry.paged else None
+            for entry in self.layout)
         self.pools = tuple(
-            jnp.zeros((self.num_blocks, cfg.n_layers)
+            jnp.zeros(((self.num_blocks, entry.layers) if entry.paged
+                       else (entry.layers, self.slots))
                       + tuple(self.block_size if d is None else d
-                              for d in shape), self.dtype)
-            for _name, shape in self.layout)
+                              for d in entry.shape),
+                      entry.dtype or self.dtype)
+            for entry in self.layout)
         self.block_tables = np.zeros((self.slots, self.blocks_per_slot),
                                      np.int32)
         self.lengths = np.zeros((self.slots,), np.int32)
@@ -304,24 +344,35 @@ class PagedKVCache:
         self._nblocks = np.zeros((self.slots,), np.int32)
         self._free_blocks = list(range(self.num_blocks - 1, 0, -1))
         self._free = list(range(self.slots - 1, -1, -1))
-        self.trie = PrefixTrie(self.block_size) if prefix_sharing else None
+        self.trie = PrefixTrie(self.block_size) if resolve_prefix_sharing(
+            self.layout, prefix_sharing) else None
 
     def __getattr__(self, name):
         # only reached for names not found the normal way
         layout = self.__dict__.get("layout", ())
-        for i, (pool_name, _shape) in enumerate(layout):
-            if pool_name == name:
+        for i, entry in enumerate(layout):
+            if entry.name == name:
                 return self.pools[i]
         raise AttributeError(name)
 
+    def _entry_bytes(self, paged):
+        return sum(
+            int(np.prod([d for d in entry.shape if d is not None]))
+            * entry.layers * pool.dtype.itemsize
+            for entry, pool in zip(self.layout, self.pools)
+            if entry.paged == paged)
+
     @property
     def row_bytes(self):
-        """Bytes one cached token takes, all layers and pools."""
-        per_layer = sum(
-            int(np.prod([d for d in shape if d is not None]))
-            for _name, shape in self.layout)
-        return per_layer * self.pools[0].shape[1] \
-            * np.dtype(self.dtype).itemsize
+        """Bytes one cached TOKEN takes: the paged entries, each over the
+        layers that have it."""
+        return self._entry_bytes(True)
+
+    @property
+    def state_row_bytes(self):
+        """Bytes one SESSION's state takes, whatever its length: the
+        entries without a token axis (0 for a layout of rows only)."""
+        return self._entry_bytes(False)
 
     # -- block accounting ---------------------------------------------------
     def _incref(self, block):
@@ -428,12 +479,14 @@ class PagedKVCache:
 
     # -- device writes ------------------------------------------------------
     def insert_tail(self, slot, *rows_start_length, row=None):
-        """``insert_tail(slot, *rows, start, length)``: install prefill
-        rows, one ``[n_layers, ...T...]`` array per pool, into the slot's
-        blocks covering positions ``[start, start + length)``.  With
-        ``row=i`` the arrays are a whole prefill's ``[B, n_layers,
-        ...T...]`` outputs, still on the device, and row ``i`` of them is
-        meant.  ``start`` must be block-aligned (trie matches are
+        """``insert_tail(slot, *rows, start, length)``: install a
+        prefill's output, one ``[layers, ...]`` array per pool: rows per
+        token into the slot's blocks covering positions ``[start, start +
+        length)``, per-session state over row ``slot`` of every layer of
+        its pool (what the slot's last session left there is gone).  With
+        ``row=i`` the arrays are a whole prefill's ``[B, layers, ...]``
+        outputs, still on the device, and row ``i`` of them is meant.
+        ``start`` must be block-aligned (trie matches are
         whole-block); what the arrays hold past ``length`` (a bucket's
         padding) lands in the session-private remainder of the last
         block, which decode overwrites in order, and in the sentinel."""
@@ -447,17 +500,21 @@ class PagedKVCache:
                 f"prefill end {start + t} > max_seq {self.max_seq}")
         if row is None:
             rows, row = [r[None] for r in rows], 0
-        padded = rows[0].shape[self._token_axes[0] + 2]
+        paged = next(i for i, ax in enumerate(self._token_axes)
+                     if ax is not None)
+        padded = rows[paged].shape[self._token_axes[paged] + 2]
         first = start // bs
         nch = -(-t // bs)
         blocks = np.zeros((-(-padded // bs),), np.int32)
         blocks[:nch] = self.block_tables[slot, first:first + nch]
         self.pools = _kv_insert(self._token_axes, bs)(
-            self.pools, blocks, tuple(rows), np.int32(row))
+            self.pools, blocks, tuple(rows), np.int32(row), np.int32(slot))
 
     # -- introspection ------------------------------------------------------
     @property
     def occupancy(self):
+        """Slots a session holds, and with each its row of every state
+        entry."""
         return self.slots - len(self._free)
 
     @property
@@ -473,7 +530,8 @@ class PagedKVCache:
     def leaked_blocks(self):
         """Refcount lint: block ids that are neither free, sentinel,
         session-referenced, nor trie-referenced — must always be
-        empty."""
+        empty.  Blocks only: per-session state has no count to drift, a
+        slot's row goes back with the slot (``occupancy``)."""
         refs = np.zeros((self.num_blocks,), np.int64)
         refs[0] = 1
         for slot in range(self.slots):

@@ -23,7 +23,11 @@ full-recompute oracle):
   :class:`~.kvcache.PagedKVCache`, the only kind there is; admission
   matches each prompt against the resident prefix trie and maps the
   shared blocks (refcount bump) instead of re-prefilling them — only
-  the unmatched tail runs the model's tail prefill.
+  the unmatched tail runs the model's tail prefill.  A model whose
+  layout also has per-session state (a recurrent layer's) gets its
+  state row with its slot, written whole by the admission's insert;
+  such a layout runs without the trie and without a draft
+  (:class:`DecodeSpec`).
 - **Seeded sampling** (per-session temperature/top-k/top-p/seed,
   ``serving/decode/sampling.py``): logits come back to the host and
   the token is a pure function of ``(logits, params, index)``, so a
@@ -92,12 +96,20 @@ class DecodeSpec:
 
     ``block_size``/``num_blocks`` size the
     :class:`~.kvcache.PagedKVCache` (``num_blocks`` None: twice the live
-    set); ``prefix_sharing`` arms the prefix trie.  Speculative decoding
+    set); ``prefix_sharing`` arms the prefix trie (None, the default:
+    wherever the model's cache layout allows it).  Speculative decoding
     arms when BOTH ``draft_params`` (a transformer params pytree) and
     ``draft_cfg`` are given: the draft proposes ``spec_window - 1``
     tokens per iteration and one windowed verify step scores them.  The
     draft's cache is derived: the same slots and block size, the
     sentinel plus the live set of ITS ``max_seq``, no trie.
+
+    A model whose layout has PER-SESSION STATE (``DecodeFns.has_state``: a
+    linear-attention layer's recurrent state) runs without either, and
+    asking for one is refused here, with the reason: prefix matching
+    needs the state at the matched boundary, which nothing snapshots yet,
+    and a rejected draft window cannot be taken back out of a state that
+    has absorbed it.
 
     ``prefill_tokens`` bounds the padded tokens of ONE prefill program
     (rows x sequence bucket): admission cuts a wave that would exceed it
@@ -106,7 +118,7 @@ class DecodeSpec:
     """
 
     def __init__(self, cfg, slots=8, eos_id=None, max_tokens=64,
-                 block_size=16, num_blocks=None, prefix_sharing=True,
+                 block_size=16, num_blocks=None, prefix_sharing=None,
                  draft_params=None, draft_cfg=None, spec_window=4,
                  prefill_tokens=None):
         self.cfg = cfg
@@ -115,7 +127,15 @@ class DecodeSpec:
         self.max_tokens = int(max_tokens)
         self.block_size = int(block_size)
         self.num_blocks = num_blocks
-        self.prefix_sharing = bool(prefix_sharing)
+        fns = cfg.decode_fns()
+        self.prefix_sharing = _kvcache.resolve_prefix_sharing(
+            fns.rows, prefix_sharing)
+        if fns.has_state and draft_cfg is not None:
+            raise ValueError(
+                "a draft model beside a cache layout with per-session "
+                "state: the verify step would advance the state over the "
+                "whole draft window, and a rejected tail cannot be taken "
+                "back out of it (nothing snapshots state yet)")
         self.draft_params = draft_params
         self.draft_cfg = draft_cfg
         self.spec_window = int(spec_window)
@@ -292,6 +312,8 @@ class DecodeEngine:
         # between two ``stats()`` its difference over that of
         # ``iterations`` is the mean a step gathered
         self._live_token_steps = 0
+        # the same for sessions (each holds one row of every state entry)
+        self._state_session_steps = 0
 
     def _mark(self, phase):
         """Everything since the last mark was ``phase``."""
@@ -399,9 +421,18 @@ class DecodeEngine:
         cache = self._cache
         out["blocks_in_use"] = cache.blocks_in_use if cache is not None else 0
         if cache is not None:
+            # rows per token (the layers that have them), and per-session
+            # state (0 bytes for a layout without; a session holds its
+            # slot's row of it from admission to retirement)
             out["cache"] = {"row_bytes": cache.row_bytes,
                             "live_tokens": int(cache.lengths.sum()),
-                            "live_token_steps": self._live_token_steps}
+                            "live_token_steps": self._live_token_steps,
+                            "state_row_bytes": cache.state_row_bytes,
+                            "state_bytes": cache.state_row_bytes
+                            * cache.slots,
+                            "state_sessions": cache.occupancy,
+                            "state_session_steps":
+                                self._state_session_steps}
             trie = cache.trie
             if trie is not None:
                 # totals since the engine started, like ``phase_s``
@@ -680,7 +711,8 @@ class DecodeEngine:
             own = cache.alloc_blocks(-(-(plen - mlen) // bs))
             cache.map_session(slot, shared, own, plen)
             with telemetry.span(telemetry.DECODE_KV_INSERT,
-                                tokens=plen - mlen):
+                                tokens=plen - mlen,
+                                state_bytes=cache.state_row_bytes):
                 cache.insert_tail(slot, *kv, mlen, plen - mlen, row=row)
             cache.register_prompt(slot, req["prompt"])
             if dcache is not None:
@@ -747,6 +779,7 @@ class DecodeEngine:
                     window[slot, 0] = st.last
                 n0 = cache.lengths.copy()
                 self._live_token_steps += int(n0.sum())
+                self._state_session_steps += len(self._active)
                 if dcache is not None:
                     self._propose(dcache, window, n0)
                 for slot in self._active:

@@ -589,11 +589,18 @@ class _Handler(BaseHTTPRequestHandler):
         self._reply(200, out)
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    # the listen backlog: http.server's 5 resets connections when a few
+    # dozen clients (a decode tier's slots) connect within the same
+    # millisecond, each request being a connection of its own
+    request_queue_size = 256
+
+
 def serve_http(server, host="127.0.0.1", port=8500, block=True):
     """Expose ``server`` over HTTP.  ``block=False`` runs the listener on
     a daemon thread and returns the ``ThreadingHTTPServer`` (tests use
     its ``.server_address`` for the ephemeral port)."""
-    httpd = ThreadingHTTPServer((host, port), _Handler)
+    httpd = _HTTPServer((host, port), _Handler)
     httpd.daemon_threads = True
     httpd.tfos_server = server
     if block:
