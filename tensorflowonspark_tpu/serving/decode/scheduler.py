@@ -29,9 +29,12 @@ full-recompute oracle):
   such a layout runs without the trie and without a draft
   (:class:`DecodeSpec`).
 - **Seeded sampling** (per-session temperature/top-k/top-p/seed,
-  ``serving/decode/sampling.py``): logits come back to the host and
-  the token is a pure function of ``(logits, params, index)``, so a
-  failover replay re-draws the identical stream.
+  ``serving/decode/sampling.py``): the token is a pure function of
+  ``(logits, params, index)``, so a failover replay re-draws the
+  identical stream.  A greedy session's token is picked ON THE DEVICE
+  (``jit_tfos_pick``: the argmax of its logits row) and only the ids
+  come back; the logits come back too in an iteration in which some
+  active session samples, and those sessions draw on the host.
 - **Speculative decoding** (``spec_window`` + a draft model): the
   draft, on a paged cache of its own with the same slots, proposes K-1
   tokens, the verify step is ONE windowed paged step over the K-token
@@ -156,6 +159,17 @@ class DecodeSpec:
         return self.draft_params is not None
 
 
+def tfos_pick(logits):
+    """The greedy token of every row of ``logits`` ``[..., vocab]``, int32
+    ``[...]``: what ``sampling.sample_token`` gives a greedy row — the
+    FIRST index of the maximum, so exact ties agree too.  The engine jits
+    it (``jit_tfos_pick`` in a device trace) and fetches its ids in place
+    of the logits."""
+    import jax.numpy as jnp
+
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 class PendingSession(ResolveOnce):
     """One decode session's future: a streaming token ledger plus the
     resolve-once result, mirroring ``batcher.PendingResult``.  Both
@@ -169,7 +183,7 @@ class PendingSession(ResolveOnce):
     included — rides the dispatch blob, so the replay draws the same
     variates) — the first arrival of an index wins (its timestamp
     included, so TTFT/per-token stats survive failover), and a
-    duplicate ``gen_done`` is swallowed by the resolve-once gate.
+    duplicate ``done`` is swallowed by the resolve-once gate.
     """
 
     __slots__ = ("id", "prompt", "max_tokens", "eos_id", "sampling",
@@ -256,11 +270,18 @@ class _Slot:
 class DecodeEngine:
     """The replica-side continuous-batching loop.
 
-    ``emit(kind, sid, *payload)`` is the wire back to the pool
-    (replicas._make_replica_task routes it onto the manager out-queue):
-    ``("token", sid, index, token)`` per generated token,
-    ``("done", sid, tokens, meta)`` at retirement,
-    ``("error", sid, message)`` on a per-session failure.
+    ``emit(events)`` is the wire back to the pool
+    (replicas._make_replica_task puts each call on the manager out-queue
+    as ONE message): a list of ``("token", sid, index, token)`` per
+    generated token, ``("done", sid, tokens, meta)`` at retirement and
+    ``("error", sid, message)`` on a per-session failure, in the order
+    the engine produced them.  The engine thread hands its events over
+    once at the end of an iteration and once at the end of an admission
+    (a first token does not wait for the step behind it); a session's
+    tokens precede its ``done`` in the same or an earlier hand-over.
+    ``submit``'s rejections and a failed cohort's errors go at once, as a
+    hand-over of their own; an iteration that raises hands over what it
+    had gathered before them.
 
     jax, the transformer model and the KV cache are imported/built on
     the engine thread — constructing a DecodeEngine never touches jax,
@@ -271,6 +292,8 @@ class DecodeEngine:
         self._params = params
         self._spec = spec
         self._emit = emit
+        self._events = []           # the engine thread's, since its last
+        # hand-over
         self._replica = replica
         self._q = collections.deque()
         self._qlock = threading.Lock()
@@ -298,6 +321,13 @@ class DecodeEngine:
         # sum to the thread's wall time by construction.
         self.tokens = 0
         self.prompt_tokens = 0
+        # where each emitted token was chosen (the device's argmax / drawn
+        # on the host from fetched logits), how often logits came to the
+        # host at all, and the hand-overs with what they carried
+        self.picks = {"device": 0, "host": 0}
+        self.logits_fetches = 0
+        self.messages = 0
+        self.events = 0
         self._phase_s = {"idle": 0.0, "admit": 0.0, "step": 0.0,
                          "fetch": 0.0, "host": 0.0}
         self._t_mark = self._t_started = None
@@ -320,6 +350,48 @@ class DecodeEngine:
         now = time.perf_counter()
         self._phase_s[phase] += now - self._t_mark
         self._t_mark = now
+
+    # -- the wire ------------------------------------------------------------
+    def _hand_over(self, events):
+        """One call of ``emit``: one message to the pool."""
+        with self._qlock:       # ``submit`` hands over off the engine thread
+            self.messages += 1
+            self.events += len(events)
+        self._emit(events)
+
+    def _flush(self):
+        """Hand over what the engine thread has gathered, if anything."""
+        if self._events:
+            events, self._events = self._events, []
+            self._hand_over(events)
+
+    def _say_token(self, st, token):
+        """``st``'s newest token, and where it was chosen."""
+        greedy = _sampling.is_greedy(st.sampling)
+        self.picks["device" if greedy else "host"] += 1
+        self._events.append(("token", st.sid, len(st.generated) - 1, token))
+
+    def _fetch(self, logits, samplers, counters=None):
+        """``(ids, logits or None, counters)`` on the host, in ONE transfer:
+        ``ids`` is the device's pick of every row of ``logits`` (argmax over
+        the last axis, int32: what ``sample_token`` gives a greedy row,
+        ties included).  The logits themselves stay on the device unless
+        one of ``samplers`` (the ``sampling`` of the sessions these rows
+        belong to) draws on the host."""
+        ids = self._pick_jit(logits)
+        if all(_sampling.is_greedy(p) for p in samplers):
+            ids, counters = self._device_get((ids, counters))
+            return ids, None, counters
+        self.logits_fetches += 1
+        return self._device_get((ids, logits, counters))
+
+    @staticmethod
+    def _choose(ids, logits, at, sampling, index):
+        """The token of row ``at``: the device's pick, or the session's
+        seeded draw at ``index`` from the fetched logits."""
+        if _sampling.is_greedy(sampling):
+            return int(ids[at])
+        return _sampling.sample_token(logits[at], sampling, index)
 
     # -- lifecycle ----------------------------------------------------------
     def start(self, timeout=120.0):
@@ -383,9 +455,10 @@ class DecodeEngine:
         cfg = self._spec.cfg
         prompt = [int(t) for t in prompt]
         if not prompt or len(prompt) > cfg.max_seq - 1:
-            self._emit("error", sid,
-                       f"prompt length {len(prompt)} not in [1, "
-                       f"{cfg.max_seq - 1}] (max_seq {cfg.max_seq})")
+            self._hand_over([(
+                "error", sid,
+                f"prompt length {len(prompt)} not in [1, "
+                f"{cfg.max_seq - 1}] (max_seq {cfg.max_seq})")])
             return
         with self._qlock:
             if sid in self._sids:
@@ -410,6 +483,10 @@ class DecodeEngine:
             "tokens": self.tokens,
             "prompt_tokens": self.prompt_tokens,
             "phase_s": {k: round(v, 6) for k, v in self._phase_s.items()},
+            "picks": dict(self.picks),
+            "logits_fetches": self.logits_fetches,
+            "messages": self.messages,
+            "events": self.events,
             "active": len(self._active),
             "queued": queued,
             "slots": self._spec.slots,
@@ -498,6 +575,9 @@ class DecodeEngine:
             def tfos_decode_step_paged(p, toks, pools, tables, lens):
                 return fns.decode_step_paged(p, toks, pools, tables, lens)
 
+            # a program of its own, not an output of the step: the step
+            # programs and ``DecodeFns`` stay what they were
+            self._pick_jit = jax.jit(tfos_pick)
             self._prefill_jit = jax.jit(tfos_prefill)
             self._extend_jit = jax.jit(tfos_prefill_extend)
             self._pstep_jit = jax.jit(
@@ -541,6 +621,7 @@ class DecodeEngine:
             except BaseException as e:  # noqa: BLE001 - fail the cohort,
                 # rebuild the caches, keep the replica serving
                 logger.exception("decode engine iteration failed")
+                self._flush()   # what it had gathered, before its errors
                 self._fail_all(repr(e))
                 cache, dcache = self._build_caches()
 
@@ -556,7 +637,7 @@ class DecodeEngine:
         for every prompt.  Every admitted prompt's whole-block prefix is then
         offered to the trie, so the FIRST request of a prefix populates
         it for all followers.  The first token comes from the prefill
-        logits either way (sampled at index 0).
+        logits either way (picked on the device, or sampled at index 0).
         """
         batch = []
         with self._qlock:
@@ -578,8 +659,10 @@ class DecodeEngine:
                     if req["sid"] not in seated:
                         with self._qlock:
                             self._sids.discard(req["sid"])
-                        self._emit("error", req["sid"], repr(e))
+                        self._events.append(("error", req["sid"], repr(e)))
                 raise
+            # first tokens do not wait for the step behind them
+            self._flush()
         self.prompt_tokens += n_prompt
         self._mark("admit")
 
@@ -609,8 +692,8 @@ class DecodeEngine:
                 else:
                     plain.append(req)
 
-        # (req, logits_row [vocab], the prefill's rows (still on the
-        # device, whole batch), row index, shared, mlen)
+        # (req, first token, the prefill's rows (still on the device,
+        # whole batch), row index, shared, mlen)
         admitted = []
         # -- plain bucketed prefill (whole prompt) --------------------------
         groups = {}
@@ -628,15 +711,17 @@ class DecodeEngine:
                                   np.int32)
                 toks = _batcher.pad_rows(toks, rows)
                 lens = _batcher.pad_rows(lens, rows)
-                # dispatch to first-token logits on the host
+                # dispatch to first tokens on the host
                 with telemetry.span(telemetry.DECODE_PREFILL, bucket=t,
                                     rows=rows, tokens=rows * t,
                                     split=len(waves)):
                     logits, kv = self._prefill_jit(self._params, toks, lens)
-                    logits = np.asarray(logits)
+                    ids, logits, _ = self._fetch(
+                        logits, [m["sampling"] for m in members])
                 self.prefills += 1
                 for i, req in enumerate(members):
-                    admitted.append((req, logits[i], kv, i, [], 0))
+                    first = self._choose(ids, logits, i, req["sampling"], 0)
+                    admitted.append((req, first, kv, i, [], 0))
         # -- prefix-hit tail prefill ----------------------------------------
         groups = {}
         for req, shared, mlen in matched:
@@ -668,10 +753,12 @@ class DecodeEngine:
                                     tokens=rows * t, split=len(waves)):
                     logits, kv = self._extend_jit(
                         self._params, toks, cache.pools, ptab, plens, lens)
-                    logits = np.asarray(logits)
+                    ids, logits, _ = self._fetch(
+                        logits, [m[0]["sampling"] for m in members])
                 self.prefills += 1
                 for i, (req, shared, mlen) in enumerate(members):
-                    admitted.append((req, logits[i], kv, i, shared, mlen))
+                    first = self._choose(ids, logits, i, req["sampling"], 0)
+                    admitted.append((req, first, kv, i, shared, mlen))
                     self.prefix_hits += 1
                     self.prefix_tokens_saved += mlen
                     metrics_registry.inc("tfos_decode_prefix_hits")
@@ -702,7 +789,7 @@ class DecodeEngine:
         for i in range(len(admitted)):
             # drop each prefill's rows with its last session: several waves'
             # outputs need not stay on the device until all are seated
-            req, logits_row, kv, row, shared, mlen = admitted[i]
+            req, first, kv, row, shared, mlen = admitted[i]
             admitted[i] = None
             plen = len(req["prompt"])
             slot = cache.alloc()
@@ -724,7 +811,6 @@ class DecodeEngine:
                 dcache.map_session(slot, [],
                                    dcache.alloc_blocks(-(-plen // bs)), plen)
                 dcache.insert_tail(slot, *dkv, 0, plen, row=drow)
-            first = _sampling.sample_token(logits_row, req["sampling"], 0)
             mt = min(req["max_tokens"], cache.max_seq - plen)
             st = _Slot(req["sid"], plen, max(1, mt), req["eos_id"], first,
                        req["sampling"], trace=req.get("trace"),
@@ -737,7 +823,7 @@ class DecodeEngine:
                     queue_ms=round((time.perf_counter()
                                     - req.get("t_queued", time.perf_counter()))
                                    * 1e3, 3))
-            self._emit("token", st.sid, 0, first)
+            self._say_token(st, first)
             if (st.eos_id is not None and first == st.eos_id) \
                     or st.max_tokens <= 1:
                 self._retire(cache, dcache, slot)
@@ -766,8 +852,9 @@ class DecodeEngine:
 
         The phases each run under their span and are closed by a
         ``_mark``: build the window (the draft's proposals included),
-        dispatch the step, fetch the logits (device wait + D2H), sample
-        every slot, then emit and retire.
+        dispatch the step, fetch its tokens (device wait, the pick, a few
+        ints D2H; the logits too while a session samples), choose every
+        slot's, then gather the events, retire, and hand them over.
         """
         spec = self._spec
         k_win = spec.spec_window if dcache is not None else 1
@@ -791,10 +878,12 @@ class DecodeEngine:
                     n0)
             self._mark("step")
             with telemetry.span(telemetry.DECODE_LOGITS_FETCH):
-                logits = np.asarray(logits)           # [slots, K, vocab]
-                # a few ints computed by the same program: one more fetch,
-                # nothing more to wait for
-                for name, value in self._device_get(counters).items():
+                # ids [slots, K], logits [slots, K, vocab] or None, and a
+                # few ints computed by the step's program
+                ids, logits, counters = self._fetch(
+                    logits, [st.sampling for st in self._active.values()],
+                    counters)
+                for name, value in counters.items():
                     total = self._step_counters.get(name, 0)
                     self._step_counters[name] = (
                         max(total, int(value)) if name.endswith("_max")
@@ -815,8 +904,8 @@ class DecodeEngine:
                             break       # draft diverged; later rows stale
                         if j > 0:
                             self.spec_accepted += 1
-                        emitted.append(_sampling.sample_token(
-                            logits[slot, j], st.sampling, base + j))
+                        emitted.append(self._choose(
+                            ids, logits, (slot, j), st.sampling, base + j))
                     if dcache is not None:
                         self.spec_proposed += k_win - 1
                     sampled[slot] = emitted
@@ -830,8 +919,7 @@ class DecodeEngine:
                         st.last = tok
                         cache.lengths[slot] += 1
                         n_emitted += 1
-                        self._emit("token", st.sid, len(st.generated) - 1,
-                                   tok)
+                        self._say_token(st, tok)
                         if (st.eos_id is not None and tok == st.eos_id) \
                                 or len(st.generated) >= st.max_tokens:
                             done = True
@@ -842,6 +930,7 @@ class DecodeEngine:
                         dcache.lengths[slot] = cache.lengths[slot]
                     if done or cache.lengths[slot] >= cache.max_seq:
                         self._retire(cache, dcache, slot)
+                self._flush()
             self.tokens += n_emitted
             span.add(tokens=n_emitted)
         metrics_registry.set_gauge("tfos_decode_slot_occupancy",
@@ -875,10 +964,11 @@ class DecodeEngine:
             for slot in self._active:
                 dcache.lengths[slot] += 1
             if j < k_win - 1:
-                dlogits = np.asarray(dlogits)
+                ids, dlogits, _ = self._fetch(
+                    dlogits, [st.sampling for st in self._active.values()])
                 for slot, st in self._active.items():
-                    window[slot, j + 1] = _sampling.sample_token(
-                        dlogits[slot, 0], st.sampling,
+                    window[slot, j + 1] = self._choose(
+                        ids, dlogits, (slot, 0), st.sampling,
                         len(st.generated) + j)
 
     def _retire(self, cache, dcache, slot):
@@ -896,20 +986,20 @@ class DecodeEngine:
                 telemetry.DECODE_RETIRE, gen_ms / 1e3, sid=st.sid,
                 tokens=len(st.generated), prompt_len=st.prompt_len,
                 replica=self._replica)
-        self._emit("done", st.sid, list(st.generated), {
+        self._events.append(("done", st.sid, list(st.generated), {
             "replica": self._replica,
             "prompt_len": st.prompt_len,
             "prefill_rows": st.prefill_rows,
             "gen_ms": gen_ms,
-        })
+        }))
 
     def _fail_all(self, message):
         with self._qlock:
             queued = list(self._q)
             self._q.clear()
             self._sids.clear()
-        for req in queued:
-            self._emit("error", req["sid"], message)
-        for st in self._active.values():
-            self._emit("error", st.sid, message)
+        failed = [req["sid"] for req in queued] \
+            + [st.sid for st in self._active.values()]
         self._active.clear()
+        if failed:
+            self._hand_over([("error", sid, message) for sid in failed])

@@ -27,10 +27,13 @@ a host-local detail; the driver's dispatch table is keyed by host):
   ``("stats",)``, ``("stop",)``.
 - host->driver (``fabric_out``): ``("up", h, pid, version, workers)``,
   ``("down", h)``, ``("done", h, bid, blob, meta)``,
-  ``("batch_error", h, bid, tb)``, ``("gen_token", h, sid, i, tok)``,
-  ``("gen_done", h, sid, tokens, meta)``, ``("gen_error", h, sid,
-  err)``, ``("reloaded", h, version)``, ``("scaled", h, gen, n)``,
-  ``("stats", h, st)``, ``("init_error", h, err)``.
+  ``("batch_error", h, bid, tb)``, ``("gen_batch", h, events)`` (one
+  hand-over of a worker's decode engine — an iteration's or an
+  admission's events, in its order: ``("token", sid, i, tok)``,
+  ``("done", sid, tokens, meta)``, ``("error", sid, err)``; a session's
+  tokens precede its ``done``), ``("reloaded", h, version)``,
+  ``("scaled", h, gen, n)``, ``("stats", h, st)``,
+  ``("init_error", h, err)``.
 
 Scale-down retires the HIGHEST worker ids first (LIFO): a retiring
 worker stops admitting, drains its inbox in order, waits out its live
@@ -129,11 +132,12 @@ class _Worker:
         st["accepting"] = self.accepting
         return st
 
-    def _emit(self, kind, sid, *rest):
-        if kind in ("done", "error"):
+    def _emit(self, events):
+        ended = sum(1 for event in events if event[0] in ("done", "error"))
+        if ended:
             with self._lock:
-                self._sessions = max(0, self._sessions - 1)
-        self.outq.put(("gen_" + kind, self.host, sid) + tuple(rest))
+                self._sessions = max(0, self._sessions - ended)
+        self.outq.put(("gen_batch", self.host, events))
 
     def _run(self):
         try:
@@ -179,8 +183,9 @@ class _Worker:
                     elif kind == "gen":
                         _, sid, blob = msg
                         if engine is None:
-                            self.outq.put(("gen_error", self.host, sid,
-                                           "spec has no decode engine"))
+                            self.outq.put(("gen_batch", self.host, [(
+                                "error", sid,
+                                "spec has no decode engine")]))
                         else:
                             req = cloudpickle.loads(blob)
                             with self._lock:
@@ -209,8 +214,8 @@ class _Worker:
                     elif kind == "gen":
                         with self._lock:
                             self._sessions = max(0, self._sessions - 1)
-                        self.outq.put(("gen_error", self.host, msg[1],
-                                       repr(e)))
+                        self.outq.put(("gen_batch", self.host,
+                                       [("error", msg[1], repr(e))]))
                     else:
                         logger.exception("worker %d/%d failed a %s",
                                          self.host, self.rid, kind)
@@ -273,9 +278,10 @@ class _Host:
             # the predictor resolves (admission gates live driver-side)
             cands = self._active()
         if not cands:
-            mid = msg[1]
-            err = "batch_error" if kind == "batch" else "gen_error"
-            self.outq.put((err, self.h, mid, "host has no live workers"))
+            mid, err = msg[1], "host has no live workers"
+            self.outq.put(("batch_error", self.h, mid, err)
+                          if kind == "batch" else
+                          ("gen_batch", self.h, [("error", mid, err)]))
             return
         if kind == "gen":
             _, sid, rid, blob = msg

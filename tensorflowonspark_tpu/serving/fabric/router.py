@@ -468,28 +468,12 @@ class FabricRouter:
                 if entry is not None:
                     entry["batch"].fail(RuntimeError(
                         f"fabric host {h} failed the batch:\n{tb}"))
-            elif kind == "gen_token":
-                _, h, sid, tindex, tok = msg
-                entry = self._table.touch(("gen", sid))
-                if entry is not None:
-                    entry["session"]._token(tindex, tok)
-            elif kind == "gen_done":
-                _, h, sid, tokens, meta = msg
-                entry = self._table.pop(("gen", sid))
-                if entry is None:
-                    continue  # duplicate answer after a re-dispatch
-                meta = dict(meta or {})
-                meta["host"] = h
-                if entry.get("affinity") is not None:
-                    meta["affinity"] = entry["affinity"]
-                entry["session"]._set(tokens, meta)
-            elif kind == "gen_error":
-                _, h, sid, err = msg
-                entry = self._table.pop(("gen", sid))
-                if entry is not None:
-                    entry["session"]._fail(RuntimeError(
-                        f"fabric host {h} failed the decode session: "
-                        f"{err}"))
+            elif kind == "gen_batch":
+                # one hand-over of a worker's decode engine: an
+                # iteration's (or an admission's) events, in its order
+                _, h, events = msg
+                for event in events:
+                    self._gen_event(h, *event)
             elif kind == "reloaded":
                 with self._lock:
                     self._versions[msg[1]] = msg[2]
@@ -503,6 +487,28 @@ class FabricRouter:
             elif kind == "init_error":
                 logger.warning("fabric host %s reported init_error: %s",
                                msg[1], msg[2])
+
+    def _gen_event(self, h, kind, sid, *rest):
+        """One decode-session event of host ``h``'s ``gen_batch`` — same
+        contract as ``ReplicaPool._gen_event``."""
+        if kind == "token":
+            entry = self._table.touch(("gen", sid))
+            if entry is not None:
+                entry["session"]._token(*rest)
+            return
+        entry = self._table.pop(("gen", sid))
+        if entry is None:
+            return  # duplicate answer after a re-dispatch
+        if kind == "done":
+            tokens, meta = rest
+            meta = dict(meta or {})
+            meta["host"] = h
+            if entry.get("affinity") is not None:
+                meta["affinity"] = entry["affinity"]
+            entry["session"]._set(tokens, meta)
+        else:
+            entry["session"]._fail(RuntimeError(
+                f"fabric host {h} failed the decode session: {rest[0]}"))
 
     def _monitor(self):
         """Death/stale detection + plan actuation + load publishing."""

@@ -361,8 +361,9 @@ def _make_replica_task(payload_blob, mgr_addr, mgr_authkey):
                     DecodeEngine,
                 )
 
-                def _gen_emit(kind, sid, *rest):
-                    outq.put(("gen_" + kind, idx, sid) + tuple(rest))
+                def _gen_emit(events):
+                    # one hand-over of the engine, one message
+                    outq.put(("gen_batch", idx, events))
 
                 engine = DecodeEngine(
                     pred.params, payload["decode"], _gen_emit,
@@ -437,8 +438,8 @@ def _make_replica_task(payload_blob, mgr_addr, mgr_authkey):
                 elif kind == "gen":
                     _, sid, blob = msg
                     if engine is None:
-                        outq.put(("gen_error", idx, sid,
-                                  "spec has no decode engine"))
+                        outq.put(("gen_batch", idx, [
+                            ("error", sid, "spec has no decode engine")]))
                         continue
                     try:
                         req = cloudpickle.loads(blob)
@@ -449,7 +450,8 @@ def _make_replica_task(payload_blob, mgr_addr, mgr_authkey):
                                       trace=req.get("trace"))
                     except BaseException as e:  # noqa: BLE001 - one bad
                         # session must not take the replica down
-                        outq.put(("gen_error", idx, sid, repr(e)))
+                        outq.put(("gen_batch", idx,
+                                  [("error", sid, repr(e))]))
                 elif kind == "batch":
                     _, batch_id, blob = msg
                     try:
@@ -926,26 +928,12 @@ class ReplicaPool:
                     entry["batch"].fail(RuntimeError(
                         f"replica {idx} failed the batch:\n{tb}"))
                     self._account(entry, ok=False)
-            elif kind == "gen_token":
-                _, idx, sid, tindex, tok = msg
-                # touch: a streamed token proves the stream is alive
-                entry = self._table.touch(("gen", sid))
-                if entry is not None:
-                    entry["session"]._token(tindex, tok)
-            elif kind == "gen_done":
-                _, idx, sid, tokens, meta = msg
-                entry = self._table.pop(("gen", sid))
-                if entry is None:
-                    continue  # duplicate answer after a re-dispatch
-                entry["session"]._set(tokens, meta)
-                self._account(entry, ok=True)
-            elif kind == "gen_error":
-                _, idx, sid, err = msg
-                entry = self._table.pop(("gen", sid))
-                if entry is not None:
-                    entry["session"]._fail(RuntimeError(
-                        f"replica {idx} failed the decode session: {err}"))
-                    self._account(entry, ok=False)
+            elif kind == "gen_batch":
+                # one hand-over of a replica's decode engine: an
+                # iteration's (or an admission's) events, in its order
+                _, idx, events = msg
+                for event in events:
+                    self._gen_event(idx, *event)
             elif kind == "reloaded":
                 with self._lock:
                     self._versions[msg[1]] = msg[2]
@@ -960,6 +948,24 @@ class ReplicaPool:
             elif kind in ("init_error", "reload_error"):
                 logger.warning("replica %s reported %s: %s",
                                msg[1], kind, msg[2])
+
+    def _gen_event(self, idx, kind, sid, *rest):
+        """One decode-session event of replica ``idx``'s ``gen_batch``."""
+        if kind == "token":
+            # touch: a streamed token proves the stream is alive
+            entry = self._table.touch(("gen", sid))
+            if entry is not None:
+                entry["session"]._token(*rest)
+            return
+        entry = self._table.pop(("gen", sid))
+        if entry is None:
+            return  # duplicate answer after a re-dispatch
+        if kind == "done":
+            entry["session"]._set(*rest)
+        else:
+            entry["session"]._fail(RuntimeError(
+                f"replica {idx} failed the decode session: {rest[0]}"))
+        self._account(entry, ok=kind == "done")
 
     def _handle_extra(self, msg):
         """Subclass hook, called before the base message chain: consume

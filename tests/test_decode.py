@@ -41,6 +41,15 @@ def _params(cfg):
     return T.init(jax.random.PRNGKey(0), cfg)
 
 
+def _each(handler):
+    """An engine's ``emit`` (one list of events a hand-over) that calls
+    ``handler(kind, sid, *payload)`` for every event, in order."""
+    def emit(events):
+        for event in events:
+            handler(*event)
+    return emit
+
+
 def _oracle(params, prompt, cfg, **kw):
     from tensorflowonspark_tpu import ops
     from tensorflowonspark_tpu.models import transformer as T
@@ -487,8 +496,8 @@ def test_engine_stats_count_the_trie_and_what_it_gave_back():
     done = []
     eng = D.DecodeEngine(
         params, spec,
-        lambda kind, sid, *rest: kind in ("done", "error")
-        and done.append((kind, sid)))
+        _each(lambda kind, sid, *rest: kind in ("done", "error")
+              and done.append((kind, sid))))
     eng.start(timeout=300)
     snaps = []
     try:
@@ -533,8 +542,7 @@ def test_engine_submit_rejects_bad_prompts_via_emit():
     events = []
     cfg = _cfg()
     eng = D.DecodeEngine(params=None, spec=D.DecodeSpec(cfg, slots=2),
-                         emit=lambda kind, sid, *rest: events.append(
-                             (kind, sid) + rest))
+                         emit=events.extend)
     eng.submit("s-empty", [])
     eng.submit("s-long", list(range(cfg.max_seq)))
     kinds = [(k, sid) for k, sid, *_ in events]
@@ -568,7 +576,8 @@ def test_parity_staggered_mixed_length_token_identical():
                 events[sid]["error"] = rest[0]
 
     eng = D.DecodeEngine(_params(cfg), D.DecodeSpec(cfg, slots=2,
-                                                    max_tokens=6), emit)
+                                                    max_tokens=6),
+                         _each(emit))
     eng.start(timeout=300)
     try:
         # staggered admission: s0 decodes alone first, then the rest
@@ -610,8 +619,8 @@ def test_parity_eos_stops_early():
 
     events = {}
     eng = D.DecodeEngine(params, D.DecodeSpec(cfg, slots=2, max_tokens=8),
-                         lambda kind, sid, *rest: events.setdefault(
-                             kind, []).append(rest))
+                         _each(lambda kind, sid, *rest: events.setdefault(
+                             kind, []).append(rest)))
     eng.start(timeout=300)
     try:
         eng.submit("s", prompt, eos_id=eos)
@@ -639,7 +648,7 @@ def _run_sessions(params, spec, jobs, timeout=300):
             elif kind == "error":
                 events[sid]["error"] = rest[0]
 
-    eng = D.DecodeEngine(params, spec, emit)
+    eng = D.DecodeEngine(params, spec, _each(emit))
     eng.start(timeout=timeout)
     try:
         for sid, prompt, kw in jobs:
@@ -891,7 +900,7 @@ def test_speculative_engine_rebuilds_both_caches_after_a_failure(
     eng = D.DecodeEngine(
         params, D.DecodeSpec(cfg, slots=2, max_tokens=7, block_size=4,
                              draft_params=dparams, draft_cfg=dcfg,
-                             spec_window=3), emit)
+                             spec_window=3), _each(emit))
     eng.start(timeout=300)
     first = eng._cache, eng._dcache
     try:
@@ -1141,7 +1150,7 @@ def test_set_params_while_serving_casts_the_new_tree():
         assert done[sid][0] == "done", done.get(sid)
         return done[sid][1]
 
-    eng = D.DecodeEngine(old, spec, emit)
+    eng = D.DecodeEngine(old, spec, _each(emit))
     eng.start(timeout=300)
     try:
         # another prompt than the one served after the swap: a prefix

@@ -401,7 +401,7 @@ def test_server_serves_the_hybrid_model_and_frees_blocks_and_state(
 
     # the same engine, in process: nothing is held once all have retired
     out = []
-    eng = scheduler.DecodeEngine(params, spec, lambda *a: out.append(a))
+    eng = scheduler.DecodeEngine(params, spec, out.extend)
     eng.start(timeout=300)
     try:
         for i, p in enumerate(prompts):

@@ -367,7 +367,7 @@ def test_server_serves_the_latent_model_and_leaks_no_block(share, tmp_path):
 
     # the same engine, in process: every block is accounted for at the end
     out = []
-    eng = scheduler.DecodeEngine(params, spec, lambda *a: out.append(a))
+    eng = scheduler.DecodeEngine(params, spec, out.extend)
     eng.start(timeout=300)
     try:
         for i, p in enumerate(prompts):
@@ -394,9 +394,10 @@ def test_the_engine_names_the_prefill_and_counts_for_an_interval(share):
     spec = serving.DecodeSpec(model, slots=4, block_size=4, max_tokens=8)
     done = {}
 
-    def emit(kind, sid, *payload):
-        if kind == "done":
-            done[sid] = payload[1]
+    def emit(events):
+        for kind, sid, *payload in events:
+            if kind == "done":
+                done[sid] = payload[1]
     eng = scheduler.DecodeEngine(params, spec, emit)
 
     def wait_for(sids):
