@@ -75,11 +75,15 @@ from tensorflowonspark_tpu.actors.ledger import IndexLedger, ResolveOnce
 from tensorflowonspark_tpu.serving import batcher as _batcher
 from tensorflowonspark_tpu.serving.decode import kvcache as _kvcache
 from tensorflowonspark_tpu.serving.decode import sampling as _sampling
-from tensorflowonspark_tpu.utils import faults, metrics_registry, telemetry
+from tensorflowonspark_tpu.utils import (
+    faults, metrics_registry, profiler, telemetry)
 
 logger = logging.getLogger(__name__)
 
 QUEUE_MAX_ENV = "TFOS_DECODE_QUEUE_MAX"
+# while a capture records, the engine pins its device clock to the host's
+# this often (``DecodeEngine._probe_clock``)
+PROBE_EVERY_S = 0.5
 
 
 def queue_max_default():
@@ -331,6 +335,7 @@ class DecodeEngine:
         self._phase_s = {"idle": 0.0, "admit": 0.0, "step": 0.0,
                          "fetch": 0.0, "host": 0.0}
         self._t_mark = self._t_started = None
+        self._t_probe = float("-inf")   # the last clock probe
         self._fns = None            # the model's seam, engine thread
         # what ``set_params`` last put on the device, and the draft's tree
         self._params_stats = {"bytes": 0, "cast_bytes": 0, "cast_leaves": 0}
@@ -599,6 +604,8 @@ class DecodeEngine:
                 self._dprefill_jit = jax.jit(tfos_draft_prefill)
                 self._dstep_jit = jax.jit(tfos_draft_step,
                                           donate_argnums=(2,))
+            # compiled now: a capture may start inside a timed window
+            profiler.probe_program()
             cache, dcache = self._build_caches()
         except BaseException as e:  # noqa: BLE001 - surface via start()
             self._init_error = e
@@ -617,6 +624,7 @@ class DecodeEngine:
                     self._mark("idle")
                     continue
                 self._iterate(cache, dcache)
+                self._probe_clock()
             except BaseException as e:  # noqa: BLE001 - fail the cohort,
                 # rebuild the caches, keep the replica serving
                 logger.exception("decode engine iteration failed")
@@ -941,6 +949,21 @@ class DecodeEngine:
                 "tfos_decode_spec_accept",
                 round(self.spec_accepted / max(1, self.spec_proposed), 4))
         self._mark("host")
+
+    def _probe_clock(self):
+        """While a profiler capture records, at most every
+        ``PROBE_EVERY_S``: ``profiler.clock_probe`` between two iterations,
+        where the device is idle (the ids' fetch waited for the step and
+        the pick), so its round trips pin the capture's device clock to
+        its host clock.  Outside ``tfos/decode/iterate``: ``iter_host_ms``
+        and ``admit_frac`` read what they read.  Without a capture: one
+        ``telemetry.capturing()`` call."""
+        if not telemetry.capturing():
+            return
+        now = time.perf_counter()
+        if now - self._t_probe >= PROBE_EVERY_S:
+            self._t_probe = now
+            profiler.clock_probe()
 
     def _propose(self, dcache, window, n0):
         """Fill ``window[:, 1:]`` with the draft's proposals: K steps of

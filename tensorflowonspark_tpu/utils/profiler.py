@@ -9,11 +9,15 @@ executable / on PATH / via PYTHONPATH module fallback, child killed at
 node shutdown.  ``trace``/``start_trace``/``stop_trace`` wrap
 ``jax.profiler`` so each worker can drop a device trace (HLO timelines,
 MXU utilization) into the same log_dir TensorBoard serves.
+``clock_probe`` puts reference points into a running capture that pin
+its device clock to its host clock.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
+import itertools
 import logging
 import os
 import socket
@@ -148,6 +152,39 @@ def stop_trace():
     except Exception as e:  # noqa: BLE001 - capture is best-effort
         _warn_unavailable(e)
         return False
+
+
+@functools.lru_cache(maxsize=None)
+def probe_program():
+    """``(run, x)``: the clock probe's program, compiled once (AOT, so a
+    later call compiles nothing), and the ``int32[1]`` it runs on, placed
+    once.  ``jit_tfos_clock_probe`` on a capture's ``XLA Modules`` line.
+    A caller that probes inside a timed window builds it before."""
+    import jax
+    import numpy as np
+
+    def tfos_clock_probe(x):
+        return x + 1
+
+    x = jax.device_put(np.zeros(1, np.int32))
+    return jax.jit(tfos_clock_probe).lower(x).compile(), x
+
+
+_PROBE_SEQ = itertools.count(1)
+
+
+def clock_probe(rounds=4):
+    """``rounds`` round trips of one trivial program, each inside a
+    ``tfos/clock/probe`` span (``seq`` counts up through the process's
+    life) that blocks until the result is back.  With the device idle
+    before it, each round pins the capture's device clock to its host
+    clock: the program ran inside the span (host = device + offset, so
+    span begin - program start <= offset <= span end - program end),
+    which ``benchmark/lib/aligned_trace.py`` intersects over the probes."""
+    run, x = probe_program()
+    for _ in range(rounds):
+        with telemetry.span(telemetry.CLOCK_PROBE, seq=next(_PROBE_SEQ)):
+            run(x).block_until_ready()
 
 
 @contextlib.contextmanager

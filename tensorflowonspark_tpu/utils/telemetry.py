@@ -17,11 +17,12 @@ Design constraints (all load-bearing):
   tasks, forked trainers and the driver; must never pull jax/numpy.
 - **Two sinks, one call** — ``span`` / ``record_span`` write to the
   JSONL spool iff ``TFOS_TELEMETRY_DIR`` is set, and to a
-  ``jax.profiler.TraceAnnotation`` iff jax is ALREADY imported in this
-  process (``sys.modules``; this module never imports it).  Outside a
-  profiler session an annotation is a no-op in the runtime, so no
-  caller needs to know whether a capture is running.  With both sinks
-  off every call is a cached no-op: no clock read, no files.
+  ``jax.profiler.TraceAnnotation`` iff a profiler capture records in
+  this process (``capturing()``: jax found in ``sys.modules``, never
+  imported here) and the name is one a capture reader keeps
+  (``tfos/*``, ``bench/*``).  No caller needs to know whether a
+  capture is running.  With both sinks off every call is a cached
+  no-op: no clock read, no files.
 - **Never per record, never per token** — spans sit at chunk, batch,
   iteration and admission boundaries (docs/telemetry.md).
 - **Monotonic durations** — ``dur_ms`` comes from ``perf_counter``
@@ -110,6 +111,7 @@ DEPLOY_ROLLBACK = "deploy/rollback"   # candidate rejected, blessed re-pinned
 # -- hot-path spans: ``tfos/<layer>/<phase>`` (docs/telemetry.md) ------------
 # The names benchmark/lib/program_trace.py and scripts/trace_merge.py read.
 CLOCK = "tfos/clock"                        # opens a capture: time_ns arg
+CLOCK_PROBE = "tfos/clock/probe"            # one device round trip: seq arg
 FEED_RING_WAIT = "tfos/feed/ring_wait"      # DataFeed: until a chunk is there
 FEED_RING_READ = "tfos/feed/ring_read"      # copy out of the ring + decode
 FEED_TO_COLUMNS = "tfos/feed/to_columns"    # next_batch(_columns) assembly
@@ -406,6 +408,27 @@ def _annotation():
     return _ANNOTATION
 
 
+def capturing():
+    """True while a profiler capture records in this process, whoever
+    started it (``utils/profiler.start_trace`` or any other caller of
+    jax's profiler); False where jax is not imported."""
+    ann = _annotation()
+    return ann is not None and ann.is_enabled()
+
+
+# the only names a capture reader keeps (benchmark/lib/program_trace.py,
+# scripts/trace_merge.py --xplane); the rest reach the spool alone
+PROFILED_PREFIXES = ("tfos/", "bench/")
+
+
+def _profiled(name):
+    """The annotation class when ``name`` belongs in a running capture,
+    else None: outside a capture an annotation records nothing."""
+    if not name.startswith(PROFILED_PREFIXES) or not capturing():
+        return None
+    return _annotation()
+
+
 def _scalars(attrs):
     """The attrs an annotation can carry as the event's stats."""
     return {k: v for k, v in attrs.items()
@@ -413,9 +436,9 @@ def _scalars(attrs):
 
 
 def active():
-    """True when a span would reach a sink (spool or profiler): what a
+    """True when a span would reach a sink (spool or capture): what a
     call site asks before it reads a clock of its own."""
-    return _get() is not None or _annotation() is not None
+    return _get() is not None or capturing()
 
 
 class _NullSpan:
@@ -506,7 +529,7 @@ def span(name, **attrs):
     span on exit (exceptions annotate ``attrs.error`` and propagate).
     One call, two sinks: the spool and the profiler (module docstring);
     scalar attrs travel with the annotation as the event's stats."""
-    rec, ann = _get(), _annotation()
+    rec, ann = _get(), _profiled(name)
     if rec is None and ann is None:
         return _NULL
     return Span(rec, name, attrs, ann=ann)
@@ -518,7 +541,7 @@ def trace_span(name, header=None, **attrs):
     TraceContext) when given, else the thread's active context, else
     mints a fresh root.  Returns the no-op span when telemetry is
     disabled (the overhead contract)."""
-    rec, ann = _get(), _annotation()
+    rec, ann = _get(), _profiled(name)
     if rec is None:
         return _NULL if ann is None else Span(None, name, attrs, ann=ann)
     parent = TraceContext.from_header(header) if header else current()
@@ -544,7 +567,7 @@ def record_span(name, dur_s, **attrs):
     feed wait path, TrainMetrics.step) so telemetry and the counters
     report the SAME number.  The profiler cannot back-date: there the
     span is an instant annotation at its END carrying ``dur_ms``."""
-    ann = _annotation()
+    ann = _profiled(name)
     if ann is not None:
         with ann(name, dur_ms=dur_s * 1000.0, **_scalars(attrs)):
             pass

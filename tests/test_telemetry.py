@@ -78,9 +78,15 @@ def no_profiler_sink(monkeypatch):
 
 class _FakeAnnotation:
     """Stands in for ``jax.profiler.TraceAnnotation``: records what one
-    span call hands the profiler."""
+    span call hands the profiler; ``recording`` is whether a capture
+    records (``is_enabled``)."""
 
     seen = []
+    recording = True
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.recording
 
     def __init__(self, name, **kwargs):
         self.name, self.kwargs, self.state = name, dict(kwargs), "new"
@@ -103,11 +109,65 @@ def fake_profiler(monkeypatch):
     import types
 
     _FakeAnnotation.seen = []
+    monkeypatch.setattr(_FakeAnnotation, "recording", True)
     fake_jax = types.SimpleNamespace(profiler=types.SimpleNamespace(
         TraceAnnotation=_FakeAnnotation))
     monkeypatch.setitem(sys.modules, "jax", fake_jax)
     monkeypatch.setattr(telemetry, "_ANNOTATION", None)
     return _FakeAnnotation.seen
+
+
+def test_no_annotation_is_made_outside_a_capture(fake_profiler, tmp_path):
+    """jax imported, no capture recording: the profiler sink is off (the
+    runtime would record nothing), so the span costs no annotation; the
+    spool still gets every record."""
+    _FakeAnnotation.recording = False
+    assert not telemetry.capturing() and not telemetry.active()
+    assert telemetry.span("tfos/decode/iterate", active=2) is telemetry._NULL
+    assert telemetry.trace_span("tfos/decode/admit") is telemetry._NULL
+    telemetry.record_span("tfos/feeder/chunk", 0.1)
+    os.environ[telemetry.DIR_ENV] = str(tmp_path)
+    telemetry.configure(node_id="t-0", role="test")
+    with telemetry.span("tfos/decode/emit", n=1):
+        pass
+    telemetry.record_span("tfos/feed/ring_wait", 0.2)
+    telemetry.flush()
+    assert fake_profiler == []
+    assert [r["name"] for r in _records(telemetry.sink_path())] == [
+        "tfos/decode/emit", "tfos/feed/ring_wait"]
+    _FakeAnnotation.recording = True
+    assert telemetry.capturing() and telemetry.active()
+    with telemetry.span("tfos/decode/emit"):
+        pass
+    assert [a.name for a in fake_profiler] == ["tfos/decode/emit"]
+
+
+def test_only_names_a_capture_reader_keeps_reach_the_capture(
+        fake_profiler, tmp_path):
+    """While a capture records, ``tfos/*`` and ``bench/*`` names become
+    annotations; every other name (``decode/retire``, ``train/step``,
+    ``rendezvous/*``) goes to the spool alone."""
+    os.environ[telemetry.DIR_ENV] = str(tmp_path)
+    telemetry.configure(node_id="t-0", role="test")
+    with telemetry.span("train/step", step=1):
+        pass
+    with telemetry.trace_span("rendezvous/register"):
+        pass
+    telemetry.record_span("decode/retire", 0.5, sid="a")
+    with telemetry.span("bench/dispatch_step"):
+        pass
+    telemetry.record_span("tfos/feeder/chunk", 0.25, records=7)
+    with telemetry.trace_span("tfos/decode/admit"):
+        pass
+    telemetry.flush()
+    assert [a.name for a in fake_profiler] == [
+        "bench/dispatch_step", "tfos/feeder/chunk", "tfos/decode/admit"]
+    assert [r["name"] for r in _records(telemetry.sink_path())] == [
+        "train/step", "rendezvous/register", "decode/retire",
+        "bench/dispatch_step", "tfos/feeder/chunk", "tfos/decode/admit"]
+    # with the spool off, a name the capture does not keep costs nothing
+    os.environ.pop(telemetry.DIR_ENV)
+    assert telemetry.span("decode/retire") is telemetry._NULL
 
 
 def test_span_reaches_the_profiler_with_its_scalar_args(fake_profiler):
