@@ -17,7 +17,11 @@ No reference counterpart (pre-LLM design).  For a normed input ``h``:
 
 Without rotary (``cfg.qk_rotary`` False, the published ``mla_use_nope``)
 nothing is rotated: the "rope" part of q and of the shared key enter the
-score as they are projected, and no position enters the layer at all.
+score as they are projected (a part that may be absent, ``qk_rope_dim``
+0), and no position enters the layer at all.  With ``cfg.q_lora_rank`` the
+query goes through a low-rank latent of its own, ``c^Q = RMSNorm(h W_qa)``
+and ``q = c^Q W_qb`` (:func:`query_latent`; a sparse selection's indexer
+reads ``c^Q`` too, ``models/sparse_attention.py``).
 
 Two paths compute the same attention:
 
@@ -47,13 +51,19 @@ def init(key, cfg, dtype):
     qd, rope = cfg.q_head_dim, cfg.qk_rope_dim
     ks = jax.random.split(key, 4)
     dense = lambda k, i, o: L._he_init(k, (i, o), i, dtype)
-    p = {
-        "wq": dense(ks[0], cfg.dim, h * qd),
+    if cfg.q_lora_rank:
+        p = {"wqa": dense(jax.random.fold_in(key, 4), cfg.dim,
+                          cfg.q_lora_rank),
+             "q_a_norm": jnp.ones((cfg.q_lora_rank,), dtype),
+             "wqb": dense(ks[0], cfg.q_lora_rank, h * qd)}
+    else:
+        p = {"wq": dense(ks[0], cfg.dim, h * qd)}
+    p.update({
         "wkva": dense(ks[1], cfg.dim, r + rope),
         "kv_norm": jnp.ones((r,), dtype),
         "wkvb": dense(ks[2], r, h * (cfg.qk_nope_dim + cfg.v_head_dim)),
         "wo": dense(ks[3], h * cfg.v_head_dim, cfg.dim),
-    }
+    })
     if cfg.qk_norm:
         p["q_norm"] = jnp.ones((qd,), dtype)
     return p
@@ -68,15 +78,27 @@ def rope_tables(cfg, length):
                            scaling=cfg.rope_scaling)
 
 
-def project(p, y, cfg, cos, sin, positions=None):
+def query_latent(p, y, cfg):
+    """``c^Q = RMSNorm(y W_qa)`` [B, T, q_lora_rank] of a low-rank query."""
+    with jax.named_scope("attn/latent_qa"):
+        return ops.rmsnorm_reference(_matmul(y, p["wqa"]), p["q_a_norm"],
+                                     cfg.norm_eps)
+
+
+def project(p, y, cfg, cos, sin, positions=None, cq=None):
     """``y`` [B, T, dim] (normed) -> ``(q [B, T, H, nope + rope], rows
     [B, T, rank + rope])``, rotary applied to both rope parts (to
     neither where the tables are None: the "rope" part is then one more
-    slice of the key that all heads share)."""
+    slice of the key that all heads share).  A low-rank query starts
+    from ``cq`` where the caller has made it (:func:`query_latent`)."""
     b, t, _ = y.shape
     nope, r = cfg.qk_nope_dim, cfg.kv_lora_rank
+    if cfg.q_lora_rank and cq is None:
+        cq = query_latent(p, y, cfg)
     with jax.named_scope("attn/latent_q"):
-        q = _matmul(y, p["wq"]).reshape(b, t, cfg.n_heads, cfg.q_head_dim)
+        q = (_matmul(cq, p["wqb"]) if cfg.q_lora_rank
+             else _matmul(y, p["wq"])).reshape(b, t, cfg.n_heads,
+                                               cfg.q_head_dim)
         if cfg.qk_norm:
             q = ops.rmsnorm_reference(q, p["q_norm"], cfg.norm_eps)
         if cos is not None:

@@ -11,7 +11,10 @@ of one sequence, H heads of width d:
                      channel, inputs before the sequence's start zero
     q_t <- q_t / |q_t| d^-1/2,   k_t <- k_t / |k_t|      per head
     g_t    = -exp(A_log[head]) softplus((h_t W_fa) W_fb + dt_bias)
-             the log-decay, per head AND key channel: alpha_t = e^{g_t}
+             the log-decay, per head AND key channel: alpha_t = e^{g_t};
+             with a lower bound b < 0 (``cfg.linear_gate_lower_bound``,
+             the bounded gate) g_t = b sigmoid(exp(A_log[head])
+             ((h_t W_fa) W_fb + dt_bias)), so that alpha_t >= e^b
     beta_t = sigmoid(h_t W_beta)                      per head
     o_t    = the gated delta rule over (q, k, v, g, beta)
     y_t    = RMSNorm_d(o_t; gain) * sigmoid((h_t W_ga) W_gb)   per head
@@ -46,14 +49,16 @@ def init(key, cfg, dtype):
     ``exp(a_log)`` uniform in [1, 16], ``softplus(dt_bias)`` log-uniform
     in [0.001, 0.1]), so that most channels keep 0.9-0.999 of the state
     a token and some forget half of it (tests/test_linear_attention.py
-    states the range)."""
+    states the range).  Under a lower bound ``b`` the same range comes
+    from ``dt_bias`` such that ``sigmoid(exp(a_log) dt_bias)`` is
+    log-uniform in [2e-4, 0.14] (``alpha`` 0.5-0.999 at ``b = -5``)."""
     h, d, r = cfg.linear_heads, cfg.linear_head_dim, cfg.linear_rank
     wide = h * d
     ks = jax.random.split(key, 10)
     dense = lambda k, i, o: L._he_init(k, (i, o), i, dtype)
     dt = jnp.exp(jax.random.uniform(ks[8], (wide,), jnp.float32,
                                     math.log(1e-3), math.log(1e-1)))
-    return {
+    p = {
         "wqkv": dense(ks[0], cfg.dim, 3 * wide),
         "conv": L._he_init(ks[1], (cfg.linear_conv, 3 * wide),
                            cfg.linear_conv, dtype),
@@ -68,6 +73,12 @@ def init(key, cfg, dtype):
         "o_norm": jnp.ones((d,), jnp.float32),
         "wo": dense(ks[9], wide, cfg.dim),
     }
+    if cfg.linear_gate_lower_bound:
+        share = jnp.exp(jax.random.uniform(
+            ks[8], (wide,), jnp.float32, math.log(2e-4), math.log(0.14)))
+        p["dt_bias"] = (jnp.log(share) - jnp.log1p(-share)) \
+            / jnp.repeat(jnp.exp(p["a_log"]), d)
+    return p
 
 
 def state_shapes(cfg):
@@ -97,8 +108,13 @@ def _inputs(p, y, window, cfg):
         k = k * lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-12)
     with jax.named_scope("attn/kda_gate"):
         a = _matmul(_matmul(y, p["wfa"]), p["wfb"]).astype(f32)
-        g = -jnp.exp(p["a_log"])[:, None] * _heads(
-            jax.nn.softplus(a + p["dt_bias"]), cfg)
+        if cfg.linear_gate_lower_bound:
+            g = cfg.linear_gate_lower_bound * jax.nn.sigmoid(
+                jnp.exp(p["a_log"])[:, None]
+                * _heads(a + p["dt_bias"], cfg))
+        else:
+            g = -jnp.exp(p["a_log"])[:, None] * _heads(
+                jax.nn.softplus(a + p["dt_bias"]), cfg)
         beta = jax.nn.sigmoid(_matmul(y, p["wbeta"]).astype(f32))
     return q, k, v, g, beta
 
@@ -113,6 +129,12 @@ def _output(p, y, o, cfg):
                        p["wo"])
 
 
+# a prefill longer than this many tokens (and a multiple of it) runs the
+# mixer a segment at a time, state and history carried: its float32
+# temporaries grow with the segment, not with the prompt
+SEGMENT = 4096
+
+
 def mix_prefill(p, y, cfg, lengths=None):
     """A whole sequence from an empty state: ``y`` [B, T, dim] (normed),
     ``lengths`` [B] true lengths (default: all T) -> ``(out [B, T, dim],
@@ -122,6 +144,8 @@ def mix_prefill(p, y, cfg, lengths=None):
     and is not part of the history."""
     b, t, _ = y.shape
     hist = cfg.linear_conv - 1
+    if t > SEGMENT and t % SEGMENT == 0:
+        return _mix_prefill_segments(p, y, cfg, lengths)
     with jax.named_scope("attn/kda_proj"):
         window = jnp.pad(_matmul(y, p["wqkv"]), ((0, 0), (hist, 0), (0, 0)))
     q, k, v, g, beta = _inputs(p, y, window, cfg)
@@ -138,6 +162,45 @@ def mix_prefill(p, y, cfg, lengths=None):
             window, lengths)
     return (_output(p, y, o, cfg), state.astype(jnp.dtype(cfg.state_dtype)),
             conv)
+
+
+def _mix_prefill_segments(p, y, cfg, lengths):
+    """:func:`mix_prefill` ``SEGMENT`` tokens at a time: each segment's
+    projections behind the last ``K - 1`` of the segment before, the
+    chunked rule from the state the segment before left."""
+    b, t, _ = y.shape
+    hist, wide = cfg.linear_conv - 1, 3 * cfg.linear_heads \
+        * cfg.linear_head_dim
+    lengths = jnp.full((b,), t, jnp.int32) if lengths is None \
+        else jnp.asarray(lengths, jnp.int32)
+
+    def segment(carry, inp):
+        state, before = carry
+        ys, first = inp
+        with jax.named_scope("attn/kda_proj"):
+            window = jnp.concatenate([before, _matmul(ys, p["wqkv"])], axis=1)
+        q, k, v, g, beta = _inputs(p, ys, window, cfg)
+        real = first + jnp.arange(SEGMENT)[None, :] < lengths[:, None]
+        g = jnp.where(real[..., None, None], g, 0.0)
+        beta = jnp.where(real[..., None], beta, 0.0)
+        with jax.named_scope("attn/kda_chunk"):
+            o, state = delta_rule.gated_delta_chunked(q, k, v, g, beta, state)
+        return (state, window[:, -hist:]), _output(p, ys, o, cfg)
+
+    h, d = cfg.linear_heads, cfg.linear_head_dim
+    carry = (jnp.zeros((b, h, d, d), jnp.float32),
+             jnp.zeros((b, hist, wide), y.dtype))
+    segments = jnp.swapaxes(y.reshape(b, t // SEGMENT, SEGMENT, -1), 0, 1)
+    (state, _), out = lax.scan(
+        segment, carry,
+        (segments, SEGMENT * jnp.arange(t // SEGMENT, dtype=jnp.int32)))
+    out = jnp.swapaxes(out, 0, 1).reshape(y.shape[:2] + out.shape[-1:])
+    with jax.named_scope("attn/kda_proj"):
+        # the K - 1 projections before each row's length (zeros before 0)
+        at = lengths[:, None] - hist + jnp.arange(hist)
+        rows = jnp.take_along_axis(y, jnp.maximum(at, 0)[..., None], axis=1)
+        conv = jnp.where((at >= 0)[..., None], _matmul(rows, p["wqkv"]), 0)
+    return out, state.astype(jnp.dtype(cfg.state_dtype)), conv.astype(y.dtype)
 
 
 def mix_step(p, y, cfg, state, conv):

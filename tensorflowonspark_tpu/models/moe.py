@@ -89,10 +89,23 @@ def _matmul(x, w):
                    preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def swiglu(x, wg, wu, wd):
+def _gated(g, up, limit):
+    """``silu(g) * up()``, with ``limit`` (> 0) the gate clamped above and
+    the up projection to [-limit, limit] first (the clamped SwiGLU).
+    ``up`` is called after ``silu(g)`` where nothing is clamped: the
+    program an unclamped layer traces stays what it was."""
+    if limit:
+        return jax.nn.silu(jnp.minimum(g, limit)) \
+            * jnp.clip(up(), -limit, limit)
+    return jax.nn.silu(g) * up()
+
+
+def swiglu(x, wg, wu, wd, limit=None):
     """``(silu(x wg) * x wu) wd``: the gated MLP of dense layers, shared
-    experts and (grouped) routed experts alike."""
-    return _matmul(jax.nn.silu(_matmul(x, wg)) * _matmul(x, wu), wd)
+    experts and (grouped) routed experts alike; ``limit`` as
+    :func:`_gated` has it."""
+    return _matmul(_gated(_matmul(x, wg), lambda: _matmul(x, wu), limit),
+                   wd)
 
 
 def route(params, h, top_k, routed_scale=1.0):
@@ -107,7 +120,8 @@ def route(params, h, top_k, routed_scale=1.0):
     return idx.astype(jnp.int32), gates, scores
 
 
-def _routed(params, h, idx, gates, expert_offset, held_n, bank_index):
+def _routed(params, h, idx, gates, expert_offset, held_n, bank_index,
+            limit=None):
     """The held experts' part for tokens ``h`` [N, D]: sort the (token,
     pick) pairs by expert, three grouped products, weight, un-sort, sum
     each token's picks."""
@@ -130,8 +144,9 @@ def _routed(params, h, idx, gates, expert_offset, held_n, bank_index):
         xs = h[order // k]
     with jax.named_scope("moe/experts"):
         dt = h.dtype
-        act = jax.nn.silu(lax.ragged_dot(xs, params["wg"].astype(dt), sizes)) \
-            * lax.ragged_dot(xs, params["wu"].astype(dt), sizes)
+        act = _gated(lax.ragged_dot(xs, params["wg"].astype(dt), sizes),
+                     lambda: lax.ragged_dot(xs, params["wu"].astype(dt),
+                                            sizes), limit)
         ys = lax.ragged_dot(act, params["wd"].astype(dt), sizes)
     with jax.named_scope("moe/combine"):
         w = jnp.where(held, gates, 0.0).reshape(-1)[order]
@@ -142,8 +157,9 @@ def _routed(params, h, idx, gates, expert_offset, held_n, bank_index):
 
 
 def apply(params, x, *, top_k, routed_scale=1.0, expert_offset=0,
-          chunk=DISPATCH_CHUNK, live=None, bank=None):
-    """``x`` [..., D] -> ``(y [..., D], stats)``.
+          chunk=DISPATCH_CHUNK, live=None, bank=None, limit=None):
+    """``x`` [..., D] -> ``(y [..., D], stats)``; ``limit`` clamps every
+    expert's SwiGLU, the shared one's too (:func:`_gated`).
 
     ``bank=(index, held)``: ``wg``/``wu``/``wd`` are the experts of
     SEVERAL layers stacked on the group axis, ``held`` a layer, and this
@@ -171,7 +187,8 @@ def apply(params, x, *, top_k, routed_scale=1.0, expert_offset=0,
     with jax.named_scope("moe/route"):
         idx, gates, scores = route(params, h, top_k, routed_scale)
     if n <= chunk:
-        y = _routed(params, h, idx, gates, expert_offset, held_n, bank_index)
+        y = _routed(params, h, idx, gates, expert_offset, held_n, bank_index,
+                    limit)
     else:
         pad = (-n) % chunk
 
@@ -182,13 +199,14 @@ def apply(params, x, *, top_k, routed_scale=1.0, expert_offset=0,
 
         # a padded token picks expert -1, held nowhere: it sorts last
         y = lax.map(
-            lambda c: _routed(params, *c, expert_offset, held_n, bank_index),
+            lambda c: _routed(params, *c, expert_offset, held_n, bank_index,
+                              limit),
             (chunks(h), chunks(idx, -1), chunks(gates)))
         y = y.reshape(-1, shape[-1])[:n]
     if "shared_wg" in params:
         with jax.named_scope("moe/shared"):
             y = y + swiglu(h, params["shared_wg"], params["shared_wu"],
-                           params["shared_wd"])
+                           params["shared_wd"], limit)
     local = idx - expert_offset
     held = (local >= 0) & (local < held_n)
     counts = jnp.bincount(jnp.where(held, local, held_n).reshape(-1),

@@ -26,8 +26,12 @@ layers named mixing by the gated delta rule of
 ``models/linear_attention.py`` and keeping per-session state instead of
 rows, the others latent attention, here without rotary) is the section
 "The hybrid block", with ``prefill`` and the step (a tail over a cached
-prefix would need the state at the prefix's end).  ``init`` and ``apply``
-dispatch on the config, and the serving engine takes any block's
+prefix would need the state at the prefix's end); its latent layers may
+read only what a learned sparse selection picks (``index_topk``,
+``models/sparse_attention.py``) and its residual may be several
+hyper-connected streams (``hc_mult``, ``models/hyper_connections.py``).
+``init`` and ``apply`` dispatch on the config, and the serving engine
+takes any block's
 incremental functions and cache layout from ``Config.decode_fns()``: one
 paged cache, one step (ROADMAP.md Design 4 has what still keeps the sets
 of programs apart).
@@ -45,10 +49,12 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from tensorflowonspark_tpu import ops
+from tensorflowonspark_tpu.models import hyper_connections as hc
 from tensorflowonspark_tpu.models import latent_attention as latent
 from tensorflowonspark_tpu.models import layers as L
 from tensorflowonspark_tpu.models import linear_attention as linear
 from tensorflowonspark_tpu.models import moe
+from tensorflowonspark_tpu.models import sparse_attention as sparse
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,6 +105,25 @@ class Config:
     linear_rank: int = 0             # width of the decay's and the output
     #                                  gate's low-rank maps
     state_dtype: str = "float32"     # a session's recurrent state
+    linear_gate_lower_bound: float = 0.0  # < 0: the delta rule's log-decay
+    #                                  is bounded below by it (the "safe"
+    #                                  gate); 0: softplus, unbounded
+    # -- latent attention's query through a low-rank latent, and a learned
+    # sparse selection of the positions it reads (models/sparse_attention.py)
+    q_lora_rank: int = 0             # > 0: q = RMSNorm(h W_qa) W_qb
+    index_heads: int = 0             # the indexer's heads
+    index_head_dim: int = 0
+    index_rope_dim: int = 0          # the indexer's rotary width
+    index_topk: int = 0              # positions a query reads; 0: all
+    index_kpool: int = 1             # positions pooled into one index key;
+    #                                  a query's own group is always read
+    # -- hyper-connections (models/hyper_connections.py): hc_mult residual
+    # streams that every sublayer reads and writes; 0: one plain residual
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    swiglu_limit: float = 0.0        # > 0: the gate clamped above and the
+    #                                  up projection to +-limit
 
     def __post_init__(self):
         if self.attn_kind not in ("mha", "latent"):
@@ -106,11 +131,14 @@ class Config:
         if self.ffn_kind not in ("gelu", "swiglu"):
             raise ValueError(f"unknown ffn_kind {self.ffn_kind!r}")
         if self.attn_kind == "latent":
-            if min(self.kv_lora_rank, self.qk_nope_dim, self.qk_rope_dim,
-                   self.v_head_dim) < 1 or self.qk_rope_dim % 2:
+            # without rotary the rope part may be absent altogether
+            if min(self.kv_lora_rank, self.qk_nope_dim, self.v_head_dim) < 1 \
+                    or self.qk_rope_dim % 2 \
+                    or self.qk_rope_dim < (2 if self.qk_rotary else 0):
                 raise ValueError(
                     "latent attention needs kv_lora_rank, qk_nope_dim, "
-                    "v_head_dim >= 1 and an even qk_rope_dim >= 2")
+                    "v_head_dim >= 1 and an even qk_rope_dim >= 2 (>= 0 "
+                    "without rotary)")
             if self.ffn_kind != "swiglu":
                 raise ValueError("the latent block's MLP is ffn_kind="
                                  "'swiglu'")
@@ -137,6 +165,29 @@ class Config:
                     "block (attn_kind='latent') and needs linear_heads, "
                     "linear_head_dim, linear_rank >= 1 and linear_conv >= "
                     "2; the leading dense layers share one kind of mixer")
+        if (self.index_topk or self.hc_mult) and not self.linear_layers:
+            raise ValueError(
+                "a sparse selection (index_topk) and hyper-connections "
+                "(hc_mult) are written for the hybrid block: name its "
+                "delta-rule layers in linear_layers")
+        if self.index_topk and not (
+                min(self.index_heads, self.index_head_dim, self.q_lora_rank,
+                    self.index_kpool) >= 1
+                and self.index_topk % self.index_kpool == 0
+                and 0 <= self.index_rope_dim <= self.index_head_dim
+                and self.index_rope_dim % 2 == 0):
+            raise ValueError(
+                "a sparse selection needs index_heads, index_head_dim, "
+                "q_lora_rank (its queries read the query latent) >= 1, "
+                "index_topk a multiple of index_kpool and an even "
+                "index_rope_dim <= index_head_dim")
+        if (self.q_lora_rank and self.classic) or self.hc_mult < 0 \
+                or (self.hc_mult and self.hc_sinkhorn_iters < 1) \
+                or self.linear_gate_lower_bound > 0 or self.swiglu_limit < 0:
+            raise ValueError(
+                "q_lora_rank is latent attention's; hc_mult >= 0 with "
+                "hc_sinkhorn_iters >= 1; linear_gate_lower_bound <= 0; "
+                "swiglu_limit >= 0")
         if self.n_experts:
             held = self.n_experts_held or self.n_experts
             if not (0 < self.experts_per_token <= self.n_experts
@@ -831,6 +882,11 @@ def _latent_layer_init(key, cfg, expert, mixer="latent"):
         p["kda"] = linear.init(ka, cfg, dt)
     else:
         p["attn"] = latent.init(ka, cfg, dt)
+        if cfg.index_topk:
+            p["index"] = sparse.init(jax.random.fold_in(key, 7), cfg, dt)
+    if cfg.hc_mult:
+        p["hc_attn"] = hc.init(jax.random.fold_in(key, 8), cfg, dt)
+        p["hc_ffn"] = hc.init(jax.random.fold_in(key, 9), cfg, dt)
     if expert:
         p["moe"] = moe.init(
             kf, cfg.dim, cfg.expert_dim, cfg.n_experts,
@@ -851,16 +907,35 @@ def _latent_ffn(p, x, cfg, index, live=None):
     count.  An expert layer's ``p["moe"]`` holds the routed experts of
     ALL the expert layers of its stack (``_latent_layers``), and layer
     ``index`` is ``index - cfg.dense_layers`` of them."""
+    y, stats = _ffn_out(p, x, cfg, index, live)
+    return x + y, stats
+
+
+def _ffn_out(p, x, cfg, index, live=None):
+    """``(FFN(norm(x)), expert statistics or None)``: :func:`_latent_ffn`
+    without the residual."""
     y = ops.rmsnorm_reference(x, p["ln2"], cfg.norm_eps)
+    limit = cfg.swiglu_limit or None
     if "moe" in p:
         y, stats = moe.apply(
             p["moe"], y, top_k=cfg.experts_per_token,
             routed_scale=cfg.routed_scale, expert_offset=cfg.expert_offset,
             live=live, bank=(index - cfg.dense_layers,
-                             cfg.n_experts_held or cfg.n_experts))
-        return x + y, {k: stats[k] for k in _MOE_COUNTERS}
+                             cfg.n_experts_held or cfg.n_experts),
+            limit=limit)
+        return y, {k: stats[k] for k in _MOE_COUNTERS}
     with jax.named_scope("mlp"):
-        return x + moe.swiglu(y, p["wg"], p["wu"], p["wd"]), None
+        return moe.swiglu(y, p["wg"], p["wu"], p["wd"], limit), None
+
+
+def _residual(p, name, x, cfg, f):
+    """One sublayer ``f(x) -> (y, aux)`` on the residual: ``(x + y,
+    aux)``, or under hyper-connections (``cfg.hc_mult``) the streams ``x``
+    [..., n, C] through ``p[name]``'s maps (``hyper_connections.sublayer``)."""
+    if cfg.hc_mult:
+        return hc.sublayer(p[name], x, cfg, f)
+    y, aux = f(x)
+    return x + y, aux
 
 
 _MOE_COUNTERS = ("picks", "picks_held", "rows_held", "experts_touched",
@@ -1125,37 +1200,57 @@ def _hybrid_layers(params, cfg, carry, layer_fn):
 
 
 def _hybrid_forward(params, tokens, cfg, attn_fn, lengths=None):
-    """Whole-sequence pass (chunked delta rule, expanded latent path):
-    ``(hidden [B, T, dim], (rows [latent layers, B, T, latent_row], state
-    [kda layers, B, H, d, d], history [kda layers, B, K - 1, 3*H*d]))``,
-    state and history as they stand after ``lengths`` tokens."""
+    """Whole-sequence pass (chunked delta rule, expanded latent path or,
+    under a sparse selection, the absorbed path over each query's selected
+    positions): ``(hidden [B, T, dim], (rows [latent layers, B, T,
+    latent_row], (index keys [latent layers, B, T, index_head_dim],)
+    state [kda layers, B, H, d, d], history [kda layers, B, K - 1,
+    3*H*d]))``, state and history as they stand after ``lengths``
+    tokens."""
     attn_fn = _default_attn_fn(cfg, attn_fn)
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.compute_dtype)[tokens]
+        if cfg.hc_mult:
+            x = hc.widen(x, cfg.hc_mult)
     cos, sin = latent.rope_tables(cfg, tokens.shape[1])
 
     def layer(p, x, mixer, _cache_index, index):
-        y = ops.rmsnorm_reference(x, p["ln1"], cfg.norm_eps)
-        if mixer == "kda":
-            a, state, conv = linear.mix_prefill(p["kda"], y, cfg, lengths)
-            out = (state, conv)
-        else:
+        def mix(x):
+            y = ops.rmsnorm_reference(x, p["ln1"], cfg.norm_eps)
+            if mixer == "kda":
+                a, state, conv = linear.mix_prefill(p["kda"], y, cfg,
+                                                    lengths)
+                return a, (state, conv)
+            if cfg.index_topk:
+                cq = latent.query_latent(p["attn"], y, cfg)
+                q, rows = latent.project(p["attn"], y, cfg, cos, sin, cq=cq)
+                a, keys = sparse.prefill_attention(
+                    p["attn"], p["index"], q, rows, cq, y, cfg)
+                return _matmul(a, p["attn"]["wo"]), (rows, keys)
             q, out = latent.project(p["attn"], y, cfg, cos, sin)
-            a = _matmul(latent.attend_expanded(p["attn"], q, out, cfg,
-                                               attn_fn), p["attn"]["wo"])
-        x, stats = _latent_ffn(p, x + a, cfg, index)
+            return _matmul(latent.attend_expanded(p["attn"], q, out, cfg,
+                                                  attn_fn),
+                           p["attn"]["wo"]), out
+
+        x, out = _residual(p, "hc_attn", x, cfg, mix)
+        x, stats = _residual(p, "hc_ffn", x, cfg,
+                             lambda h: _ffn_out(p, h, cfg, index))
         return x, out, stats
 
     x, outs, _ = _hybrid_layers(params, cfg, x, layer)
-    return x, (outs["latent"],) + outs["kda"]
+    if cfg.hc_mult:
+        x = hc.narrow(x)
+    kept = outs["latent"] if cfg.index_topk else (outs["latent"],)
+    return x, tuple(kept) + outs["kda"]
 
 
 def hybrid_prefill(params, tokens, cfg: Config, *, lengths=None,
                    attn_fn=None):
     """The hybrid block's :func:`prefill`: ``(logits [B, vocab] float32
     at each row's last real position, (rows [B, latent layers, T,
-    latent_row], state [B, kda layers, H, d, d], history [B, kda layers,
-    K - 1, 3*H*d]))``: all the cache keeps of a sequence."""
+    latent_row], (index keys [B, latent layers, T, index_head_dim],)
+    state [B, kda layers, H, d, d], history [B, kda layers, K - 1,
+    3*H*d]))``: all the cache keeps of a sequence."""
     x, kept = _hybrid_forward(params, tokens, cfg, attn_fn, lengths)
     return (_last_logits(params, x, lengths, cfg.norm_eps),
             tuple(jnp.swapaxes(a, 0, 1) for a in kept))
@@ -1164,21 +1259,24 @@ def hybrid_prefill(params, tokens, cfg: Config, *, lengths=None,
 def hybrid_decode_step_paged(params, tokens, cfg: Config, pools,
                              block_tables, lengths):
     """The hybrid block's :func:`decode_step_paged`: ``pools`` = (latent
-    pool [num_blocks, latent layers, block_size, latent_row], state
-    [kda layers, slots, H, d, d], history [kda layers, slots, K - 1,
-    3*H*d]), all three loop carries written in place (the engine donates
-    them).  One token a slot: a recurrent state cannot take back a window
-    whose tail is rejected.  A free slot's state is stepped like any
-    other (finite, and overwritten whole when the slot is next given
-    out).  Returns ``(logits [S, 1, vocab] float32, pools, counters)``."""
+    pool [num_blocks, latent layers, block_size, latent_row], (under a
+    sparse selection the index keys [num_blocks, latent layers,
+    block_size, index_head_dim],) state [kda layers, slots, H, d, d],
+    history [kda layers, slots, K - 1, 3*H*d]), all loop carries written
+    in place (the engine donates them).  One token a slot: a recurrent
+    state cannot take back a window whose tail is rejected.  A free
+    slot's state is stepped like any other (finite, and overwritten whole
+    when the slot is next given out).  Returns ``(logits [S, 1, vocab]
+    float32, pools, counters)``; under a sparse selection the counters
+    add ``dsa_*`` (``sparse_attention.step_context``) summed over the
+    latent layers."""
     dtype = cfg.compute_dtype
     s_slots, w = tokens.shape
     if w != 1:
         raise ValueError(
             f"a layout with per-session state steps one token a slot, "
             f"not a window of {w}")
-    pool = pools[0]
-    bs = pool.shape[2]
+    bs = pools[0].shape[2]
     cap = block_tables.shape[1] * bs
     lengths = jnp.asarray(lengths, jnp.int32)
     tables = jnp.asarray(block_tables, jnp.int32)
@@ -1191,32 +1289,65 @@ def hybrid_decode_step_paged(params, tokens, cfg: Config, pools,
     kv_mask = (jnp.arange(cap)[None, None, None, :]
                <= pos[:, None, :, None])                 # [S, 1, 1, cap]
     x = params["embed"].astype(dtype)[tokens]            # [S, 1, dim]
+    if cfg.hc_mult:
+        x = hc.widen(x, cfg.hc_mult)
     cos, sin = latent.rope_tables(cfg, cap)
     live = (lengths > 0)[:, None]
 
-    def layer(p, carry, mixer, at, index):
-        x, pool, state, conv = carry
-        y = ops.rmsnorm_reference(x, p["ln1"], cfg.norm_eps)
+    def mix(p, y, mixer, at, pools, dsa):
+        """The mixer of one layer: ``(out, (pools, dsa))``."""
         if mixer == "kda":
+            state, conv = pools[-2:]
             a, s_new, c_new = linear.mix_step(p["kda"], y, cfg,
                                               state[at], conv[at])
             with jax.named_scope("write_state"):
                 state = state.at[at].set(s_new)
                 conv = conv.at[at].set(c_new)
-        else:
-            q, rows = latent.project(p["attn"], y, cfg, cos, sin, posc)
+            return a, (pools[:-2] + (state, conv), dsa)
+        pool = pools[0]
+        cq = latent.query_latent(p["attn"], y, cfg) if cfg.index_topk \
+            else None
+        q, rows = latent.project(p["attn"], y, cfg, cos, sin, posc, cq=cq)
+        with jax.named_scope("write_kv"):
+            pool = pool.at[wblk, at, woff].set(
+                rows.reshape(-1, rows.shape[-1]).astype(pool.dtype))
+        if cfg.index_topk:
+            ipool = pools[1]
+            keys = sparse.index_keys(p["index"], y, cfg, posc)
             with jax.named_scope("write_kv"):
-                pool = pool.at[wblk, at, woff].set(
-                    rows.reshape(-1, rows.shape[-1]).astype(pool.dtype))
-            ctx = _paged_context(pool, at, tables)
-            a = _matmul(latent.attend_absorbed(p["attn"], q, ctx, kv_mask,
-                                               cfg), p["attn"]["wo"])
-        x, stats = _latent_ffn(p, x + a, cfg, index, live)
-        return (x, pool, state, conv), None, stats
+                ipool = ipool.at[wblk, at, woff].set(
+                    keys.reshape(-1, keys.shape[-1]).astype(ipool.dtype))
+            ctx, mask, counts = sparse.step_context(
+                p["index"], cq, y, cfg, pool, ipool, at, tables, posc,
+                live[:, 0])
+            dsa = {k: dsa[k] + v for k, v in counts.items()}
+            pools = (pool, ipool) + pools[2:]
+        else:
+            ctx, mask = _paged_context(pool, at, tables), kv_mask
+            pools = (pool,) + pools[1:]
+        a = _matmul(latent.attend_absorbed(p["attn"], q, ctx, mask, cfg),
+                    p["attn"]["wo"])
+        return a, (pools, dsa)
 
-    (x, *pools), _, stats = _hybrid_layers(params, cfg, (x, *pools), layer)
-    return (_step_logits(params, x, cfg), tuple(pools),
-            _step_counters(cfg, stats))
+    def layer(p, carry, mixer, at, index):
+        x, pools, dsa = carry
+        x, (pools, dsa) = _residual(
+            p, "hc_attn", x, cfg,
+            lambda x: mix(p, ops.rmsnorm_reference(x, p["ln1"],
+                                                   cfg.norm_eps),
+                          mixer, at, pools, dsa))
+        x, stats = _residual(p, "hc_ffn", x, cfg,
+                             lambda h: _ffn_out(p, h, cfg, index, live))
+        return (x, pools, dsa), None, stats
+
+    dsa = {name: jnp.zeros((), jnp.int32) for name in sparse.COUNTERS} \
+        if cfg.index_topk else {}
+    (x, pools, dsa), _, stats = _hybrid_layers(
+        params, cfg, (x, tuple(pools), dsa), layer)
+    if cfg.hc_mult:
+        x = hc.narrow(x)
+    return (_step_logits(params, x, cfg), pools,
+            dict(_step_counters(cfg, stats), **dsa))
 
 
 # ---------------------------------------------------------------------------
@@ -1272,6 +1403,11 @@ class DecodeFns:
     (:func:`_resident`): the leaves these programs use only through a cast
     to the compute type, cast once; every program above gives the same
     bits on either tree.  Default: the tree as given.
+    ``span_counters``: the step counters the engine also writes onto its
+    iteration's span (a capture then has them step by step).
+    ``prefill_selected(lengths) -> int`` or None: the positions a prefill
+    of prompts of ``lengths`` reads, summed over its queries and layers
+    (a sparse selection's; written onto the prefill's span).
     """
     rows: tuple
     prefill: object
@@ -1279,6 +1415,8 @@ class DecodeFns:
     decode_step_paged: object
     summarize: object = None
     resident: object = lambda params: params
+    span_counters: tuple = ()
+    prefill_selected: object = None
 
     @property
     def has_state(self):
@@ -1308,6 +1446,15 @@ def _summarize_moe(totals):
     }}
 
 
+def _summarize_sparse(totals):
+    """``stats()["moe"]`` and ``stats()["dsa"]``: a sparse selection's
+    step counters as their totals (positions read, positions up to the
+    queries, whole groups scored; summed over the latent layers)."""
+    return dict(_summarize_moe(totals), dsa={
+        name[len("dsa_"):]: int(totals.get(name, 0))
+        for name in sparse.COUNTERS})
+
+
 # The leaves (by their own name in the tree) that the three serving
 # programs use ONLY as ``.astype(cfg.compute_dtype)``: the operands of
 # ``_matmul`` / ``ragged_dot`` / the absorbed up-projections, the router's
@@ -1320,7 +1467,10 @@ _LATENT_CAST = frozenset({
     "router", "shared_wg", "shared_wu", "shared_wd",
     # the delta-rule mixer's matrices (its ``conv``, ``a_log``, ``dt_bias``
     # and ``o_norm`` enter float32 sums)
-    "wqkv", "wfa", "wfb", "wga", "wgb", "wbeta"})
+    "wqkv", "wfa", "wfb", "wga", "wgb", "wbeta",
+    # a low-rank query, the sparse selection's indexer and the
+    # hyper-connections' maps (their gains, biases and ``alpha`` do not)
+    "wqa", "wqb", "wiq", "wik", "wiw", "phi"})
 
 
 def _resident(params, names, dtype):
@@ -1380,15 +1530,25 @@ def _decode_fns(cfg):
             return hybrid_decode_step_paged(p, toks, cfg, pools, tables,
                                             lens)
 
+        n_latent = cfg.n_layers - n_kda
+        rows = (CacheEntry("kv", (None, cfg.latent_row), n_latent),)
+        sparse_kw = {}
+        if cfg.index_topk:
+            # the indexer's key of every token, beside its latent row
+            rows += (CacheEntry("index", (None, cfg.index_head_dim),
+                                n_latent),)
+            sparse_kw = dict(
+                summarize=_summarize_sparse, span_counters=sparse.COUNTERS,
+                prefill_selected=lambda lens: n_latent
+                * sparse.selected_positions(lens, cfg))
         return DecodeFns(
-            rows=(CacheEntry("kv", (None, cfg.latent_row),
-                             cfg.n_layers - n_kda),
-                  CacheEntry("state", state, n_kda,
-                             jnp.dtype(cfg.state_dtype)),
-                  CacheEntry("conv", conv, n_kda)),
+            rows=rows + (CacheEntry("state", state, n_kda,
+                                    jnp.dtype(cfg.state_dtype)),
+                         CacheEntry("conv", conv, n_kda)),
             prefill=prefill_fn, prefill_extend=None,
-            decode_step_paged=step_paged_fn, summarize=summarize,
-            resident=resident)
+            decode_step_paged=step_paged_fn,
+            **dict(dict(summarize=summarize, resident=resident),
+                   **sparse_kw))
 
     def prefill_fn(p, toks, lens):
         logits, rows = latent_prefill(p, toks, cfg, lengths=lens)

@@ -356,11 +356,16 @@ class PagedKVCache:
         raise AttributeError(name)
 
     def _entry_bytes(self, paged):
-        return sum(
-            int(np.prod([d for d in entry.shape if d is not None]))
+        return sum(self.entry_row_bytes(entry.name) for entry in self.layout
+                   if entry.paged == paged)
+
+    def entry_row_bytes(self, name):
+        """Bytes one token (or one session) takes in the entry ``name``,
+        over the layers that have it."""
+        entry, pool = next((e, p) for e, p in zip(self.layout, self.pools)
+                           if e.name == name)
+        return int(np.prod([d for d in entry.shape if d is not None])) \
             * entry.layers * pool.dtype.itemsize
-            for entry, pool in zip(self.layout, self.pools)
-            if entry.paged == paged)
 
     @property
     def row_bytes(self):

@@ -515,6 +515,10 @@ class DecodeEngine:
                             "state_sessions": cache.occupancy,
                             "state_session_steps":
                                 self._state_session_steps}
+            if any(entry.name == "index" for entry in cache.layout):
+                # a sparse selection's index keys, beside the latent rows
+                out["cache"]["index_row_bytes"] = cache.entry_row_bytes(
+                    "index")
             trie = cache.trie
             if trie is not None:
                 # totals since the engine started, like ``phase_s``
@@ -721,7 +725,10 @@ class DecodeEngine:
                 # dispatch to first tokens on the host
                 with telemetry.span(telemetry.DECODE_PREFILL, bucket=t,
                                     rows=rows, tokens=rows * t,
-                                    split=len(waves)):
+                                    split=len(waves)) as sp:
+                    if self._fns.prefill_selected is not None:
+                        sp.add(selected=self._fns.prefill_selected(
+                            lens[:len(members)]))
                     logits, kv = self._prefill_jit(self._params, toks, lens)
                     ids, logits, _ = self._fetch(
                         logits, [m["sampling"] for m in members])
@@ -895,6 +902,8 @@ class DecodeEngine:
                     self._step_counters[name] = (
                         max(total, int(value)) if name.endswith("_max")
                         else total + int(value))
+                for name in self._fns.span_counters:
+                    span.add(**{name: int(counters[name])})
             self._mark("fetch")
             self.iterations += 1
             sampled = {}
